@@ -77,6 +77,8 @@ def main():
     ap.add_argument("--disp-batches", type=int, default=20)
     ap.add_argument("--model-prefix", default=None)
     ap.add_argument("--synthetic-n", type=int, default=512)
+    ap.add_argument("--ctx", default="tpu", choices=["tpu", "cpu"],
+                    help="device context; tpu fails without a chip")
     args = ap.parse_args()
 
     shape = tuple(int(x) for x in args.image_shape.split(","))
@@ -89,7 +91,7 @@ def main():
         train, val = synthetic_iters(args.batch_size, args.image_shape,
                                      args.num_classes, args.synthetic_n)
 
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.Context(args.ctx)
     mod = mx.mod.Module(net, context=ctx)
     checkpoint = (mx.callback.do_checkpoint(args.model_prefix)
                   if args.model_prefix else None)

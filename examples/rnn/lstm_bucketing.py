@@ -58,6 +58,8 @@ def main():
     ap.add_argument("--n-sentences", type=int, default=2000)
     ap.add_argument("--fused", action="store_true",
                     help="use FusedRNNCell (one lax.scan per bucket)")
+    ap.add_argument("--ctx", default="tpu", choices=["tpu", "cpu"],
+                    help="device context; tpu fails without a chip")
     args = ap.parse_args()
 
     rng = np.random.RandomState(0)
@@ -96,7 +98,7 @@ def main():
         pred = mx.sym.SoftmaxOutput(pred, lab, name="softmax")
         return pred, ("data",), ("softmax_label",)
 
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.Context(args.ctx)
     model = mx.mod.BucketingModule(
         sym_gen=sym_gen,
         default_bucket_key=train_iter.default_bucket_key,

@@ -148,12 +148,14 @@ def main():
     ap.add_argument("--n", type=int, default=256)
     ap.add_argument("--small", action="store_true",
                     help="quarter-width backbone for smoke runs")
+    ap.add_argument("--ctx", default="tpu", choices=["tpu", "cpu"],
+                    help="device context; tpu fails without a chip")
     args = ap.parse_args()
 
     net = ssd_symbol(args.num_classes, small=args.small)
     train = SyntheticDetIter(args.n, args.batch_size, args.image,
                              args.num_classes)
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.Context(args.ctx)
     mod = mx.mod.Module(net, context=ctx, data_names=("data",),
                         label_names=("label",))
 

@@ -71,3 +71,5 @@ rnd = random
 
 # env-var knobs that act at import time (config.py documents the full table)
 config.apply_startup_knobs()
+from .compile import place_compilation_cache as _place  # noqa: E402
+_place()

@@ -1,11 +1,11 @@
 """mxcost — static graph cost & communication analysis.
 
 The runtime only reveals cost problems after the fact: BENCH_OPS showed
-the int8 convnet 1.8x *slower* than fp32, BENCH_r05 pinned h2d at
-13.8 MB/s, and the pod fast path's whole value is its O(buckets)
-collective economy — yet none of those numbers could be predicted (or
-guarded) before a run.  mxcost is the predictive half: it walks Symbol
-graphs and traced jaxprs and derives, per program,
+the int8 convnet 1.8x *slower* than fp32 (on CPU), a blocking h2d copy
+caps the input path, and the pod fast path's whole value is its
+O(buckets) collective economy — yet none of those numbers could be
+predicted (or guarded) before a run.  mxcost is the predictive half: it
+walks Symbol graphs and traced jaxprs and derives, per program,
 
 * **per-op FLOPs / bytes-moved / arithmetic intensity** with a roofline
   classification against a device profile (TVM's per-op cost-model

@@ -51,6 +51,24 @@ _enabled = None   # tri-state: None = read MXNET_PROGRAM_CACHE lazily
 _atexit_armed = False
 
 
+def place_compilation_cache():
+    """Place JAX's persistent compilation cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` says when it is set (JAX reads the
+    variable itself — nothing is overridden), else at the fixed
+    ``<checkout>/.jax_cache``.  The path is part of what makes a cache
+    findable again, so it is never a temporary name, a pid or a time.
+    Called once, when the package is imported — before anything can
+    compile, whichever of the library's `jax.jit` sites compiles first
+    (a config update; no backend is touched)."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))
+
+
 def enabled():
     """Master switch (MXNET_PROGRAM_CACHE): off -> every wrapper is a
     plain jax.jit, the pre-unification behavior."""

@@ -10,8 +10,8 @@ output of ``jax.jit(...).lower().compile()`` run through
     x device/mesh fingerprint x jax version x format version
 
 so a second process that builds the same program loads the compiled
-executable from disk instead of paying the multi-second XLA compile
-(BENCH_r03–r05: 28–105 s per cold start on the fused train graphs).
+executable from disk instead of paying the XLA compile of the fused
+train graphs again.
 
 Entry files are corruption-safe by construction:
 
@@ -224,8 +224,10 @@ class ProgramCache:
         for src in self.sources:
             yield os.path.join(src, fname)
 
-    def load(self, key, expect_fingerprint=None):
-        """Deserialize the entry for `key` -> loaded executable, or None.
+    def load(self, key, devices, expect_fingerprint=None):
+        """Deserialize the entry for `key` onto `devices` (the device
+        assignment it was compiled for — part of the key) -> loaded
+        executable, or None.
 
         Corrupt entries are deleted (primary dir only); entries whose
         header disagrees with the current format/jax/device fingerprint
@@ -255,7 +257,9 @@ class ProgramCache:
                 continue
             try:
                 ser, in_tree, out_tree = pickle.loads(payload)
-                exe = _se.deserialize_and_load(ser, in_tree, out_tree)
+                exe = _se.deserialize_and_load(
+                    ser, in_tree, out_tree,
+                    execution_devices=list(devices))
             except Exception as e:
                 _log.warning("program cache entry %s failed to "
                              "deserialize (%s); recompiling", path,

@@ -86,6 +86,23 @@ def _leaf_sig(leaf):
 _PLAIN = object()   # sentinel: this signature dispatches via plain jit
 
 
+def _arg_devices(args):
+    """The device assignment a call's arguments commit the program to:
+    the widest sharding among the array leaves, in mesh order (what
+    `jit` lowers against); the default device when no leaf is a device
+    array."""
+    import jax
+    from jax.sharding import NamedSharding
+    best = ()
+    for leaf in jax.tree_util.tree_leaves(args):
+        sh = getattr(leaf, "sharding", None)
+        if sh is None or len(sh.device_set) <= len(best):
+            continue
+        best = tuple(sh.mesh.devices.flat) if isinstance(sh, NamedSharding) \
+            else tuple(sorted(sh.device_set, key=lambda d: d.id))
+    return best or (jax.devices()[0],)
+
+
 class CachedProgram:
     """One logical program; one executable per input signature."""
 
@@ -130,9 +147,9 @@ class CachedProgram:
         return len(self._programs)
 
     # -- acquire -------------------------------------------------------------
-    def _entry_key(self, sig):
+    def _entry_key(self, sig, devices):
         from . import cache as _cache
-        sig_repr = (str(sig[0]), sig[1])
+        sig_repr = (str(sig[0]), sig[1], tuple(d.id for d in devices))
         return _cache.entry_key(self.graph_key, sig_repr, self._donate)
 
     def _acquire(self, sig, args):
@@ -142,7 +159,11 @@ class CachedProgram:
             return _PLAIN
         key = None
         if self.graph_key is not None:
-            key = self._entry_key(sig)
+            # an executable is specialized to its device assignment: the
+            # devices key the entry, and a disk hit loads onto them
+            devices = _arg_devices(args)
+            key = self._entry_key(sig, devices)
+            self._entry_keys[sig] = key
             # live tier first: an in-process restart (fit failover,
             # guardian rollback, supervisor shrink-and-resume) rebuilds
             # its wrappers around executables this process ALREADY holds
@@ -152,13 +173,11 @@ class CachedProgram:
             # runtime state on teardown (see ProgramCache._live).
             exe = cache.live_get(key)
             if exe is not None:
-                self._entry_keys[sig] = key
                 return exe
             if cache.enabled():
-                exe = cache.load(key)
+                exe = cache.load(key, devices)
                 if exe is not None:
                     self.disk_hits += 1
-                    self._entry_keys[sig] = key
                     cache.live_put(key, exe)
                     return exe
                 self.disk_misses += 1
@@ -179,11 +198,10 @@ class CachedProgram:
                            compile_s=t2 - t1)
         if key is not None:
             cache.live_put(key, exe)
-            if cache.enabled() and \
-                    cache.store(key, exe, meta={"label": self.label,
-                                                "graph": self.graph_key,
-                                                "donate": list(self._donate)}):
-                self._entry_keys[sig] = key
+            if cache.enabled():
+                cache.store(key, exe, meta={"label": self.label,
+                                            "graph": self.graph_key,
+                                            "donate": list(self._donate)})
         return exe
 
     # -- dispatch ------------------------------------------------------------
@@ -246,7 +264,7 @@ class CachedProgram:
         for sig, exe in items:
             if exe is _PLAIN or exe is None:
                 continue
-            key = self._entry_keys.get(sig) or self._entry_key(sig)
+            key = self._entry_keys[sig]
             path = os.path.join(target, key + ".xprog")
             if os.path.exists(path) and \
                     key not in self._cache.corrupt_keys:

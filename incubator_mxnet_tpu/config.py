@@ -419,14 +419,13 @@ KNOBS = {
                                    "(ops/layout.py; measured ~parity on "
                                    "v5e, default off)"),
     "MXNET_FLASH_INTERPRET": (_BOOL, False, "honored",
-                              "run the Pallas flash-attention kernel in "
-                              "interpreter mode (CPU testing)"),
+                              "run the Pallas kernels (flash attention, "
+                              "fused FC+ReLU) in interpreter mode — the "
+                              "only way interpret mode is ever selected "
+                              "(CPU testing)"),
     "MXNET_FLASH_VMEM_MB": (float, 10.0, "honored",
                             "VMEM budget steering the whole-KV kernel vs "
                             "the KV-streaming grid (long-context) variant"),
-    "MXNET_COMPILATION_CACHE_DIR": (str, "", "honored",
-                                    "persistent XLA compilation cache "
-                                    "directory (bench.py)"),
     # -- unified program cache (compile/) ------------------------------------
     "MXNET_PROGRAM_CACHE": (_BOOL, True, "honored",
                             "unified program cache (compile/): fused "

@@ -37,9 +37,11 @@ import threading as _threading
 
 import numpy as _np
 
+from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from .. import autograd as _autograd
-from ..fused import (_apply_traced, _no_rng, _state_data,
+from .parameter import DeferredInitializationError
+from ..fused import (_apply_traced, _no_rng, _untraceable, _state_data,
                      _state_write_back, _raise_if_unrecoverable,
                      _TracedCore, _one_step_jit, _scan_block_jit,
                      _BlockMetricView)
@@ -299,9 +301,9 @@ class GluonFusedStep:
         opt.rescale_grad = trainer._scale / batch_size
         try:
             self._ensure_states()
-        except Exception:
-            # deferred-init parameters: the eager loop's first forward
-            # materializes them; retry fusing from the next batch
+        except DeferredInitializationError:
+            # the eager loop's first forward materializes the parameters;
+            # retry fusing from the next batch
             return False
 
         # eligibility BEFORE any transfer: a rejected block must not cost
@@ -420,11 +422,20 @@ class GluonFusedStep:
             self._t_vec = None
             self._block_view = None
             self.broken = True
+            if isinstance(e, _untraceable()):
+                # selection: the net's, loss's or optimizer's Python
+                # cannot run under a trace (a dropout net draws host RNG)
+                _log.warning("gluon fused step not traceable (%s); "
+                             "Estimator uses the eager loop", str(e)[:300])
+                return False
+            # the selected step failed on its device: raised with its
+            # cause (naming the donated buffers it consumed, if any),
+            # never replaced by the eager loop
             _raise_if_unrecoverable("gluon fused step", e,
                                     self._donation_groups(ws, ss, auxs))
-            _log.warning("gluon fused step unavailable (%s); Estimator "
-                         "uses the eager loop", str(e)[:300])
-            return False
+            raise MXNetError(
+                f"gluon fused step failed to trace, lower, compile or run "
+                f"({type(e).__name__}: {str(e)[:300]})") from e
 
         new_ws, new_aux, new_ss, new_mcarry, new_t = new_inner
         # write back (params/aux/optimizer state are shared with the eager

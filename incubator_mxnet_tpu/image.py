@@ -809,8 +809,7 @@ class ImageRecordIterImpl(DataIter):
         # consumed by an async transfer (accelerator) — never recycled, so
         # no defensive copy is needed anywhere on the path
         u8 = self._device_augment
-        native_ok = nat is not None and \
-            (not u8 or hasattr(nat, "mxtpu_crop_batch_u8"))
+        native_ok = nat is not None
         if native_ok:
             # shared ctypes marshalling for both native finishes
             dims = np.ascontiguousarray(dims)

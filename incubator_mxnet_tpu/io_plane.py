@@ -1,9 +1,8 @@
 """Production data plane: the host→device staging ring.
 
-BENCH_r05 measured host-to-device at 13.8 MB/s — every batch paid a
-BLOCKING `device_put` on the training thread, serialized against the
-step it was feeding.  This module is the io tier that removes that
-serialization:
+Without it every batch pays a BLOCKING `device_put` on the training
+thread, serialized against the step it is feeding.  This module is the
+io tier that removes that serialization:
 
 * `H2DRing` — a pinned-style, double-buffered **staging ring**: batches
   are assembled into REUSABLE preallocated host staging buffers (one

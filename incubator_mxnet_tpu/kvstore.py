@@ -498,15 +498,12 @@ class KVStoreTPU(KVStore):
         if fn is None:
             import jax
             from jax.sharding import PartitionSpec as P
-            shard_map = getattr(jax, "shard_map", None)
-            if shard_map is None:                     # older jax
-                from jax.experimental.shard_map import shard_map
 
             def _psum(shards):           # shards: (1, *s) local block
                 return jax.lax.psum(shards[0], "dev")
 
-            fn = jax.jit(shard_map(_psum, mesh=mesh,
-                                   in_specs=P("dev"), out_specs=P()))
+            fn = jax.jit(jax.shard_map(_psum, mesh=mesh,
+                                       in_specs=P("dev"), out_specs=P()))
             self._allreduce_jit[ids] = fn
         return fn
 

@@ -571,14 +571,8 @@ class BaseModule:
         if not hasattr(train_data, "next") or \
                 not hasattr(train_data, "reset"):
             return train_data, None   # a bare iterable: leave it alone
-        try:
-            wrapped = _io_plane.DevicePrefetchIter(
-                train_data, placement=fs.ring_placement, name="fit")
-        except Exception as e:
-            self.logger.warning(
-                "h2d ring unavailable (%s); using the blocking input "
-                "path", str(e)[:200])
-            return train_data, None
+        wrapped = _io_plane.DevicePrefetchIter(
+            train_data, placement=fs.ring_placement, name="fit")
         return wrapped, wrapped
 
     def _start_supervisor(self):

@@ -319,14 +319,8 @@ class Module(BaseModule):
         # fused path and the unfused fallback share one state store.
         self._fused_step = None
         if fusable:
-            try:
-                from .. import fused as _fused
-                self._fused_step = _fused.FusedTrainStep(self, self._updater)
-            except Exception as e:  # never block training on the fast path
-                self.logger.warning(
-                    "fused train step unavailable (%s); Module.fit uses "
-                    "forward_backward+update", str(e)[:200])
-                self._fused_step = None
+            from .. import fused as _fused
+            self._fused_step = _fused.FusedTrainStep(self, self._updater)
 
         self.optimizer_initialized = True
         if self._preload_opt_states is not None:

@@ -80,11 +80,7 @@ for _name, (_f, _aliases) in _UNARY.items():
 @register("gamma")
 def _gamma(params, x):
     """tgamma (reference `elemwise_unary_op_basic.cc` gamma)."""
-    try:
-        return jax.scipy.special.gamma(x)
-    except AttributeError:  # older jax
-        return jnp.exp(jax.scipy.special.gammaln(x)) * jnp.where(
-            (x < 0) & (jnp.floor(x / 2) * 2 != jnp.floor(x)), -1.0, 1.0)
+    return jax.scipy.special.gamma(x)
 
 
 @register("_copy", aliases=("identity",))

@@ -19,32 +19,33 @@ Two surfaces:
   block with this kernel while `ppermute` rotates the KV shards.
 
 Layout: (B, T, H, D) at the API (the framework's attention layout); the
-kernel runs on (B·H, T, D).  On non-TPU backends both surfaces fall back
-to the jnp blockwise implementation — same math, same signatures, so the
-CPU test mesh exercises the identical call graph.
+kernel runs on (B·H, T, D).  The platform selects the implementation
+(`pallas_mode`): on ``tpu`` the kernel compiles or the call fails; on
+other backends both surfaces run the jnp blockwise reference — same
+math, same signatures, so the CPU test mesh exercises the identical
+call graph.
 """
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention", "flash_attention_partial"]
+__all__ = ["flash_attention", "flash_attention_partial", "pallas_mode"]
 
 _NEG = -1e30
 
 
-def _use_kernel():
-    """Run the Pallas kernel on TPU; MXNET_FLASH_INTERPRET=1 forces it in
-    interpreter mode so the CPU suite tests the KERNEL, not the fallback."""
-    import os
+def pallas_mode():
+    """``(run the Pallas kernel, interpreted)`` for this process: the
+    compiled kernel on ``tpu``, the jnp reference elsewhere.  Interpret
+    mode is never chosen for the caller — only ``MXNET_FLASH_INTERPRET=1``
+    asks for it, so the CPU suite can test the KERNEL's arithmetic."""
     if os.environ.get("MXNET_FLASH_INTERPRET") == "1":
         return True, True
-    try:
-        return jax.extend.backend.get_backend().platform == "tpu", False
-    except Exception:
-        return False, False
+    return jax.default_backend() == "tpu", False
 
 
 # ---------------------------------------------------------------------------
@@ -110,8 +111,8 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
         jax.lax.fori_loop(0, nk,
                           lambda i, _: (compute(i, masked=False), 0)[1], 0)
     o_ref[0] = acc_scr[:].astype(o_ref.dtype)
-    m_ref[0] = m_scr[:, 0]
-    l_ref[0] = l_scr[:, 0]
+    m_ref[0, 0] = m_scr[:, 0]
+    l_ref[0, 0] = l_scr[:, 0]
 
 
 def _fwd_kernel_stream(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
@@ -173,8 +174,8 @@ def _fwd_kernel_stream(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
     @pl.when(j == nk - 1)
     def _finish():
         o_ref[0] = acc_scr[:].astype(o_ref.dtype)
-        m_ref[0] = m_scr[:, 0]
-        l_ref[0] = l_scr[:, 0]
+        m_ref[0, 0] = m_scr[:, 0]
+        l_ref[0, 0] = l_scr[:, 0]
 
 
 def _stream_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
@@ -202,13 +203,13 @@ def _stream_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), q3.dtype),
-            jax.ShapeDtypeStruct((BH, Tq), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -243,8 +244,10 @@ def _partial_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
         block_k //= 2
     # whole-KV kernel maps (kv_len, D) K and V blocks into VMEM (fast, and
     # its dynamic loop bounds skip above-diagonal blocks entirely); past
-    # the VMEM budget, stream KV tiles through the grid instead
-    kv_bytes = 2 * kv_len * D * q3.dtype.itemsize
+    # the VMEM budget, stream KV tiles through the grid instead.  The
+    # pipeline double-buffers every blocked input, so K and V each
+    # count twice against the budget.
+    kv_bytes = 2 * 2 * kv_len * D * q3.dtype.itemsize
     if kv_bytes > _vmem_budget_bytes():
         return _stream_tpu(q3, k3, v3, q_off, k_off, causal,
                            block_q, block_k, interpret=interpret)
@@ -268,13 +271,13 @@ def _partial_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), q3.dtype),
-            jax.ShapeDtypeStruct((BH, Tq), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -288,7 +291,8 @@ def _partial_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
 
 
 def _partial_ref(q3, k3, v3, q_off, k_off, causal, block_k):
-    """jnp blockwise partial (non-TPU fallback; identical contract)."""
+    """jnp blockwise partial: the implementation off-TPU and the test
+    reference (identical contract)."""
     BH, Tq, D = q3.shape
     kv_len = k3.shape[1]
     scale = 1.0 / (D ** 0.5)
@@ -328,7 +332,7 @@ def flash_attention_partial(q, k, v, q_off=0, k_off=0, causal=False,
     q3 = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
     k3 = k.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], D)
     v3 = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], D)
-    use, interpret = _use_kernel()
+    use, interpret = pallas_mode()
     if use:
         o3, m3, l3 = _partial_tpu(q3, k3, v3, q_off, k_off, causal,
                                   block_q, block_k, interpret=interpret)

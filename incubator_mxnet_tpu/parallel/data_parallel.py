@@ -36,8 +36,6 @@ def data_parallel_step(loss_fn, optimizer_update, mesh, axis_name="dp",
     Returns step(params, opt_state, batch, lr) -> (params, opt_state, loss):
     params/opt_state replicated; batch sharded on axis 0 over `axis_name`.
     """
-    from .mesh import compat_shard_map
-
     def spmd_step(params, opt_state, batch, lr):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         # gradient all-reduce over the data axis (kvstore push+pull fused)
@@ -49,9 +47,9 @@ def data_parallel_step(loss_fn, optimizer_update, mesh, axis_name="dp",
 
     batch_spec = P(axis_name)
     rep = P()
-    step = compat_shard_map(spmd_step, mesh=mesh,
-                            in_specs=(rep, rep, batch_spec, rep),
-                            out_specs=(rep, rep, rep))
+    step = jax.shard_map(spmd_step, mesh=mesh,
+                         in_specs=(rep, rep, batch_spec, rep),
+                         out_specs=(rep, rep, rep), check_vma=False)
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
 

@@ -114,22 +114,6 @@ def dp_axis_of(mesh):
     return "dp" if "dp" in names else names[0]
 
 
-def compat_shard_map(fn, mesh, in_specs, out_specs):
-    """`shard_map` across the jax versions this framework supports: the
-    stable `jax.shard_map` (check_vma) when present, else the
-    `jax.experimental.shard_map` spelling (check_rep).  Every SPMD
-    consumer (parallel/data_parallel.py, parallel/zero.py, the fused
-    step's pod fast path) builds through this one seam."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_vma=False)
-
-
 def local_mesh(n=None, axis_names=("dp",)):
     """Mesh over the first n local devices (testing convenience)."""
     import jax

@@ -83,7 +83,6 @@ def zero_train_step(loss_fn, update_fn, mesh, axis_name="dp", donate=True):
     1/N shards (out_spec P(axis_name) on the leading dim).
     """
     from jax.sharding import PartitionSpec as P
-    from .mesh import compat_shard_map
 
     def spmd_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -92,10 +91,10 @@ def zero_train_step(loss_fn, update_fn, mesh, axis_name="dp", donate=True):
                                             update_fn, axis_name)
         return new_params, new_state, loss
 
-    step = compat_shard_map(
+    step = jax.shard_map(
         spmd_step, mesh=mesh,
         in_specs=(P(), P(axis_name), P(axis_name)),
-        out_specs=(P(), P(axis_name), P()))
+        out_specs=(P(), P(axis_name), P()), check_vma=False)
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
 

@@ -4,8 +4,10 @@ The fused kernel runs the matmul on the MXU with the bias add and ReLU
 applied in VMEM before the tile is written back — the epilogue fusion XLA
 usually does on its own, expressed by hand to prove the escape hatch
 works end-to-end (graph partition -> custom kernel inside the jitted
-program -> custom VJP for training).  Off-TPU the same kernel executes in
-Pallas interpret mode, so tests run on the CPU mesh.
+program -> custom VJP for training).  On ``tpu`` the kernel compiles or
+the call fails; elsewhere the op is the plain XLA expression, and
+``MXNET_FLASH_INTERPRET=1`` runs the kernel interpreted for the CPU tests
+(`ops.flash_attention.pallas_mode`).
 """
 from __future__ import annotations
 
@@ -15,18 +17,12 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import registry as _reg
+from ..ops.flash_attention import pallas_mode
 from .subgraph_property import SubgraphProperty, register_subgraph_property
 from .partition import external_inputs
 
 
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def _fc_relu_pallas(x, w, b):
+def _fc_relu_pallas(x, w, b, interpret=False):
     """relu(x @ w.T + b) via one Pallas kernel."""
     from jax.experimental import pallas as pl
 
@@ -41,15 +37,22 @@ def _fc_relu_pallas(x, w, b):
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(x, w, b)
+
+
+def _fc_relu(x, w, b):
+    use, interpret = pallas_mode()
+    if use:
+        return _fc_relu_pallas(x, w, b, interpret=interpret)
+    return jnp.maximum(x @ w.T + b, 0.0).astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=1)
 def _fused_fc_relu_fn():
     @jax.custom_vjp
     def fused(x, w, b):
-        return _fc_relu_pallas(x, w, b)
+        return _fc_relu(x, w, b)
 
     def fwd(x, w, b):
         y = fused(x, w, b)

@@ -315,76 +315,3 @@ def get_mnist_like(num=1000, seed=0):
 def list_gpus():
     from .context import num_gpus
     return list(range(num_gpus()))
-
-
-# -- environment capability probes (skip-guards for tier-1 tests) -------------
-# The parallel/ and dist/ subsystems target jax builds with (a) the
-# stable `jax.shard_map` export and (b) multiprocess collectives on the
-# CPU backend (the pod test mesh).  Containers with an older jaxlib lack
-# one or both; tests gate on these probes instead of failing red, so a
-# tier-1 run is green everywhere and the skips NAME the missing
-# capability.
-
-def has_stable_shard_map():
-    """Whether this jax exports the stable ``jax.shard_map`` API the
-    parallel subsystem (data_parallel, zero, pipeline, ring_attention,
-    gluon TP/ZeRO sharding — all written and tolerance-calibrated
-    against it) requires."""
-    try:
-        from jax import shard_map  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-_MP_COLLECTIVES_PROBE = """
-import sys
-import numpy as np
-import jax
-jax.config.update("jax_platforms", "cpu")
-rank, port = int(sys.argv[1]), int(sys.argv[2])
-jax.distributed.initialize(coordinator_address="127.0.0.1:%d" % port,
-                           num_processes=2, process_id=rank)
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-reps = [[d for d in jax.devices() if d.process_index == p][0]
-        for p in range(2)]
-mesh = Mesh(np.array(reps), ("w",))
-local = jax.device_put(np.full(4, rank + 1.0), reps[rank])[None]
-garr = jax.make_array_from_single_device_arrays(
-    (2, 4), NamedSharding(mesh, P("w")), [local])
-out = jax.jit(lambda x: x.sum(axis=0),
-              out_shardings=NamedSharding(mesh, P()))(garr)
-assert float(np.asarray([s.data for s in out.addressable_shards][0])[0]) \\
-    == 3.0
-"""
-
-_mp_collectives_cache = [None]
-
-
-def has_multiprocess_cpu_collectives(timeout=90):
-    """Whether TWO processes can jointly execute an XLA reduction over a
-    global CPU mesh (the dist kvstore collective plane's recipe).  Older
-    jaxlib raises 'Multiprocess computations aren't implemented on the
-    CPU backend' at dispatch; this probes the real execution path in two
-    throwaway subprocesses and caches the verdict for the session."""
-    if _mp_collectives_cache[0] is None:
-        import socket
-        import subprocess
-        import sys
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        s.close()
-        procs = [subprocess.Popen(
-            [sys.executable, "-c", _MP_COLLECTIVES_PROBE, str(r), str(port)],
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-            for r in range(2)]
-        ok = True
-        for p in procs:
-            try:
-                ok &= p.wait(timeout=timeout) == 0
-            except subprocess.TimeoutExpired:
-                p.kill()
-                ok = False
-        _mp_collectives_cache[0] = ok
-    return _mp_collectives_cache[0]

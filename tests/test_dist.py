@@ -13,23 +13,6 @@ import sys
 import numpy as np
 import pytest
 
-from incubator_mxnet_tpu import test_utils as tu
-
-
-def _require_mp_collectives():
-    """Capability guard: collective-mode tests execute a real XLA
-    reduction across worker PROCESSES on the CPU backend, which older
-    jaxlib rejects at dispatch ("Multiprocess computations aren't
-    implemented on the CPU backend").  The probe (two throwaway
-    subprocesses running the collective plane's exact recipe, cached
-    per session) runs LAZILY inside the guarded tests so plain
-    collection — and deselected runs — never pay for it."""
-    if not tu.has_multiprocess_cpu_collectives():
-        pytest.skip("this jaxlib cannot execute multiprocess XLA "
-                    "collectives on the CPU backend (the collective "
-                    "data plane's recipe)")
-
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 WORKER = r"""
@@ -126,8 +109,6 @@ def test_dist_sync_multiprocess(tmp_path, n_workers, collective):
     plane).  collective="1": gradients all-reduce over the global device
     mesh (XLA collectives; server = control plane) — same observable
     semantics either way."""
-    if collective == "1":
-        _require_mp_collectives()
     from incubator_mxnet_tpu.dist.server import ParameterServer
 
     script = tmp_path / "worker.py"
@@ -246,7 +227,6 @@ def test_launcher(tmp_path):
     """tools/launch.py spawns server+workers and propagates exit codes.
     (Launched workers default to MXNET_KVSTORE_COLLECTIVE=1, so the data
     plane needs multiprocess CPU collectives.)"""
-    _require_mp_collectives()
     script = tmp_path / "trivial.py"
     script.write_text(
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"
@@ -360,7 +340,6 @@ print("worker %d OK" % rank)
 def test_dist_collective_compression_halves_payload(tmp_path):
     """Collective mode + 2-bit compression: gradients quantize with error
     feedback device-side and the global all-reduce payload is bf16."""
-    _require_mp_collectives()
     from incubator_mxnet_tpu.dist.server import ParameterServer
 
     n_workers = 2

@@ -9,12 +9,6 @@ import jax.numpy as jnp
 
 from incubator_mxnet_tpu.ops.flash_attention import (flash_attention,
                                                      flash_attention_partial)
-from incubator_mxnet_tpu import test_utils as tu
-
-requires_shard_map = pytest.mark.skipif(
-    not tu.has_stable_shard_map(),
-    reason="this jax build lacks the stable jax.shard_map API the "
-           "ring-attention integration is written against")
 
 
 def _naive(q, k, v, causal=False):
@@ -78,7 +72,6 @@ def test_pallas_kernel_interpreted_matches_ref(monkeypatch, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@requires_shard_map
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_pallas_path(causal):
     """ring_attention(use_pallas=True) must equal the plain path and full

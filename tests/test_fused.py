@@ -381,7 +381,7 @@ def _fit_with_block(block_k, reset_at=None, num_epoch=1):
 def test_block_callbacks_fire_per_logical_step():
     """K>1 fused blocks: each batch-end callback must observe BATCH-j
     metric state — identical to per-batch (K=1) dispatch — not the
-    block-final totals (round-5 VERDICT/ADVICE)."""
+    block-final totals."""
     ref = _fit_with_block(1)
     blocked = _fit_with_block(4)
     assert [b for b, _ in ref] == [b for b, _ in blocked]

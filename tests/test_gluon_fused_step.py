@@ -119,6 +119,29 @@ def test_estimator_fused_falls_back_on_dropout():
         os.environ.pop("MXNET_FUSED_TRAIN_STEP", None)
 
 
+def test_estimator_device_error_is_raised_not_replaced(monkeypatch):
+    """Only Python that cannot trace selects the eager loop (the dropout
+    case above); a compile or device error in the selected step is raised
+    with its cause."""
+    import jax
+    from incubator_mxnet_tpu.base import MXNetError
+    _, _, est, _ = _run(True, steps=2)
+    assert est._fused is not None and not est._fused.broken
+
+    def refuse(*args):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: injected by the test")
+
+    monkeypatch.setattr(est._fused, "_jit", refuse)
+    monkeypatch.setenv("MXNET_FUSED_TRAIN_STEP", "1")
+    monkeypatch.setenv("MXNET_FUSED_STEP_BLOCK", "1")
+    X, y = _data()
+    with pytest.raises(MXNetError, match="RESOURCE_EXHAUSTED") as ei:
+        est.fit(iter([(nd.array(X[:16]), nd.array(y[:16]))]), epochs=1,
+                event_handlers=[])
+    assert isinstance(ei.value.__cause__, jax.errors.JaxRuntimeError)
+
+
 def test_estimator_fused_then_eager_state_shared():
     """Switching to the eager path mid-training (new kvstore etc.) keeps
     optimizer state: both paths use the trainer's updater store."""
@@ -179,8 +202,8 @@ def _estimator_fit_with_block(block_k, steps=8):
 
 def test_estimator_block_handlers_fire_per_logical_step():
     """K>1 Estimator blocks: batch-j handlers must observe batch-j
-    metric state, matching per-batch dispatch exactly (round-5
-    VERDICT/ADVICE K>1 callback semantics)."""
+    metric state, matching per-batch dispatch exactly (the K>1
+    callback semantics)."""
     ref = _estimator_fit_with_block(1)
     blocked = _estimator_fit_with_block(4)
     assert [b for b, _ in ref] == [b for b, _ in blocked]

@@ -10,16 +10,6 @@ import jax.numpy as jnp
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import parallel as par
-from incubator_mxnet_tpu import test_utils as tu
-
-# capability guard, not an xfail: these tests exercise the stable
-# `jax.shard_map` API (and the collective numerics of that jax
-# generation); a container whose jax predates it skips with the missing
-# capability named instead of failing tier-1 red
-requires_shard_map = pytest.mark.skipif(
-    not tu.has_stable_shard_map(),
-    reason="this jax build lacks the stable jax.shard_map API the "
-           "parallel subsystem is written against")
 
 
 def test_make_mesh():
@@ -31,7 +21,6 @@ def test_make_mesh():
         par.make_mesh({"dp": 5})
 
 
-@requires_shard_map
 def test_data_parallel_step_matches_single_device():
     """DP-8 training must match single-device training on the full batch."""
     mesh = par.make_mesh({"dp": 8})
@@ -61,7 +50,6 @@ def test_data_parallel_step_matches_single_device():
                                rtol=1e-5, atol=1e-6)
 
 
-@requires_shard_map
 def test_collectives_in_shard_map():
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -76,7 +64,6 @@ def test_collectives_in_shard_map():
     np.testing.assert_allclose(np.asarray(s), np.full((8, 1), 28.0))
 
 
-@requires_shard_map
 def test_ring_attention_matches_full():
     """Ring attention over 4 sequence shards == exact full attention."""
     from jax import shard_map
@@ -105,7 +92,6 @@ def test_ring_attention_matches_full():
                                atol=2e-5)
 
 
-@requires_shard_map
 def test_ring_attention_causal():
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -165,7 +151,6 @@ def test_tensor_parallel_sharding():
     assert proj.sharding.spec == jax.sharding.PartitionSpec(None, "tp")
 
 
-@requires_shard_map
 def test_pipeline_step():
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -186,7 +171,6 @@ def test_pipeline_step():
                                np.arange(n_micro) + 4.0)
 
 
-@requires_shard_map
 def test_pipeline_train_step_decreases_loss_and_matches_sequential():
     """GPipe training over pp=2: forward == sequential stage composition,
     and the fused train step drives the loss down."""
@@ -250,7 +234,6 @@ def test_pipeline_train_step_decreases_loss_and_matches_sequential():
                                rtol=1e-4, atol=1e-5)
 
 
-@requires_shard_map
 def test_zero_sharded_optimizer_matches_replicated_adam():
     """ZeRO dp-8 adam == replicated adam; state lives sharded 1/N."""
     from incubator_mxnet_tpu.parallel.zero import (

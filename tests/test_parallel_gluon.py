@@ -1,27 +1,19 @@
 """Mesh parallelism driven through the USER-FACING Gluon API.
 
-VERDICT round-2 item 7: tensor parallelism + ZeRO must be reachable from
-Block/Trainer, not only from hand-written shard_map.  A small transformer
+Tensor parallelism + ZeRO must be reachable from Block/Trainer, not only
+from hand-written shard_map.  A small transformer
 trains on the 8-device CPU mesh with Megatron-sharded parameters and
 ZeRO-sharded optimizer state, via the ordinary autograd/Trainer loop, and
 must match the single-device run.
 """
 import numpy as np
-import pytest
 
 import jax
 from jax.sharding import PartitionSpec as P
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import autograd, gluon, nd, parallel
-from incubator_mxnet_tpu import test_utils as tu
 from incubator_mxnet_tpu.parallel import ShardingRules
-
-requires_shard_map = pytest.mark.skipif(
-    not tu.has_stable_shard_map(),
-    reason="this jax build lacks the stable jax.shard_map API; the "
-           "TP+ZeRO parity tolerances are calibrated against that jax "
-           "generation's sharded-reduction numerics")
 
 
 class MiniTransformer(gluon.HybridBlock):
@@ -96,7 +88,6 @@ def _train(mesh=None, zero=False, steps=4, hybridize=False):
     return params, losses, net, trainer, shardings
 
 
-@requires_shard_map
 def test_gluon_tp_zero_matches_single_device():
     ref_params, ref_losses, _, _, _ = _train(mesh=None)
     mesh = parallel.make_mesh({"dp": 4, "tp": 2})

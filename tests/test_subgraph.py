@@ -10,6 +10,13 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import nd, subgraph
 
 
+@pytest.fixture(autouse=True)
+def _interpreted_kernel(monkeypatch):
+    """Off-TPU the fused op is the plain XLA expression; asking for
+    interpret mode by name makes these tests run the Pallas KERNEL."""
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+
+
 def _mlp():
     data = mx.sym.Variable("data")
     h = mx.sym.FullyConnected(data, num_hidden=16, name="fc1")

@@ -2,20 +2,21 @@
 pattern: rerun the operator battery on the accelerator and compare against
 the CPU context).
 
-Run with `python -m pytest tests_tpu -q` on a machine with a TPU attached.
-Unlike `tests/` (which pins everything to a virtual CPU mesh), this lane
-keeps the real platform and skips itself when no TPU is present.
+This lane is run on purpose, through the chip tool, from the repo root:
+`python -m pytest tests_tpu -q`.  Unlike `tests/` (which pins everything to
+a virtual CPU mesh) it keeps the real platform — and without a TPU it is an
+error at collection, not a green run of skips.
 """
 import numpy as np
 import pytest
 
 
 def pytest_collection_modifyitems(config, items):
-    import incubator_mxnet_tpu as mx
-    if mx.context.num_tpus() == 0:
-        skip = pytest.mark.skip(reason="no TPU device attached")
-        for item in items:
-            item.add_marker(skip)
+    import jax
+    if jax.default_backend() != "tpu":
+        raise pytest.UsageError(
+            "tests_tpu/ is the chip lane and JAX came up on "
+            f"{jax.default_backend()!r}: nothing here may pass on a CPU")
 
 
 @pytest.fixture(autouse=True)

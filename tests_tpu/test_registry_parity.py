@@ -91,6 +91,8 @@ CASES = {
         grad_req="null", spatial_scale=1.0, output_dim=2, group_size=2,
         pooled_size=2, part_size=2, sample_per_part=2, trans_std=0.1),
     "LayerNorm": _case({"data": (4, 6)}),
+    "BlockwiseAttention": _case({"query": (2, 8, 8), "key": (2, 8, 8),
+                                 "value": (2, 8, 8)}, num_heads=2),
     "topk": _case({"data": (4, 6)}, grad_req="null", k=2),
     # scalar-op family: one representative shape, scalar=2.5
     **{n: _case({"data": V}, scalar=2.5) for n in (

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Op-level regression bench battery (VERDICT #10).
+"""Op-level regression bench battery.
 
 The round bench (`bench.py`) times whole models — a kernel regression in
 one op class hides inside a 3% end-to-end drift until it is expensive to

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Scaling-curve bench: the 1→N data-parallel sweep next to BENCH_r05.
+"""Scaling-curve bench: the 1→N data-parallel sweep.
 
 Sweeps dp = 1,2,4,...,N (host-platform virtual devices on CPU — the
 TPU-mesh stand-in per the build contract — real devices on TPU), runs

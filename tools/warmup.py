@@ -51,6 +51,9 @@ def coldstart_probe(timeout=600):
     import shutil
     import subprocess
     import tempfile
+    # a deliberately COLD probe: the first phase must find nothing, so
+    # this program-cache dir is new every time (the one place a temporary
+    # cache name is right)
     cache = tempfile.mkdtemp(prefix="mxnet-coldstart-")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cmd = [sys.executable, os.path.abspath(__file__),
@@ -107,7 +110,7 @@ def _fused_vs_jax_compile():
     X = rng.randn(32, 3, 8, 8).astype("f4")
     y = rng.randint(0, 10, 32).astype("f4")
     it = io.NDArrayIter(X, y, batch_size=16, label_name="softmax_label")
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.tpu()
     mod = mx.mod.Module(net, context=ctx)
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
     mod.init_params(mx.initializer.Xavier())
