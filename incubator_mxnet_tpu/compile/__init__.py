@@ -18,7 +18,11 @@ graphs (`gluon/block.py`) — with ONE cache product:
 * **stats plane** — `stats()` / `findings()` feed
   `analysis.runtime_report()` and the ``mxlint --cache-report`` CLI;
   compiles are attributable to churned signatures via the recompile
-  auditor's history.
+  auditor's history;
+* **names** — `op_scopes()` (scopes.py): for a live program, which
+  phase of the train step and which graph node each HLO instruction of
+  its executable came from — what turns a profile's ``fusion.3426``
+  into ``Convolution bwd``.
 
 Knobs: ``MXNET_PROGRAM_CACHE`` (master switch),
 ``MXNET_PROGRAM_CACHE_DIR`` (disk tier location),
@@ -40,7 +44,7 @@ from . import warmup  # noqa: F401
 from .warmup import warm, write_manifest, export_all  # noqa: F401
 
 __all__ = ["ProgramCache", "CachedProgram", "cached_jit", "get_cache",
-           "set_cache_dir", "add_source", "enabled", "stats",
+           "set_cache_dir", "add_source", "enabled", "stats", "op_scopes",
            "write_stats", "findings", "warm", "write_manifest",
            "export_all", "graph_hash_of_jaxpr", "graph_hash_of_text",
            "device_fingerprint", "entry_key"]
@@ -59,10 +63,17 @@ def place_compilation_cache():
     findable again, so it is never a temporary name, a pid or a time.
     Called once, when the package is imported — before anything can
     compile, whichever of the library's `jax.jit` sites compiles first
-    (a config update; no backend is touched)."""
+    (a config update; no backend is touched).
+
+    The cache's key takes the programs' metadata in: `op_scopes` reads
+    the scope names out of an executable's `op_name` metadata, and a key
+    without them (JAX's default) hands a program whose scopes changed
+    the executable of the old ones, stale names and all.  The price is
+    that a moved source line compiles again."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    import jax
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     jax.config.update("jax_compilation_cache_dir",
@@ -140,6 +151,48 @@ def write_stats(path=None):
     return get_cache().write_stats(path)
 
 
+_scope_maps = {}    # id(executable) -> (executable, its parsed map)
+
+
+def op_scopes(label=None):
+    """``{hlo_instruction_name: {"phase", "op", "node", "mixed"}}`` of a
+    program the cache holds live, parsed (once) from its executable's own
+    optimized HLO text — compiled here, or loaded from JAX's or this
+    cache's disk tier: the text comes with all three.  Of the programs
+    labelled `label` (of all, without one): the busiest live wrapper's
+    newest executable, else the live tier's most recently used one — a
+    fit's executable outlives its module there.
+
+    `phase` is one of ``fwd``, ``bwd``, ``exchange``, ``optimizer``,
+    ``guardian``, ``metric``, ``other``; `op` and `node` the MXNet
+    operator kind and node name (None outside a graph node); `mixed`
+    whether a fusion's insides fall in more than one phase or in another
+    than its own, listed as `inside` then (scopes.py has the rules).
+    Instruction names are those a `jax.profiler` trace gives its device
+    events.  The names are those of the graph that COMPILED the
+    executable: this cache keys a program by its structure, so a graph
+    that differs in node names alone shares the executable, and reads the
+    first graph's `node`s.  {} where no such program holds an executable
+    that can print its HLO."""
+    from . import scopes as _scopes
+    cache = get_cache()
+    held = sorted(cache.programs(), key=lambda p: -p.mem_hits)
+    candidates = [(p.label, exe) for p in held
+                  for exe in reversed(p.executables())]
+    candidates += reversed(cache.live_programs())
+    for exe_label, exe in candidates:
+        if label is not None and exe_label != label:
+            continue
+        parsed = _scope_maps.get(id(exe))
+        if parsed is None or parsed[0] is not exe:
+            parsed = _scope_maps[id(exe)] = (exe, _scopes.of_executable(exe))
+            while len(_scope_maps) > 8:
+                _scope_maps.pop(next(iter(_scope_maps)))
+        if parsed[1]:
+            return parsed[1]
+    return {}
+
+
 def reset_for_tests():
     """Drop the singleton (tests that flip env knobs between cases).
     The atexit flush reads the live singleton, so a replacement cache
@@ -148,6 +201,7 @@ def reset_for_tests():
     with _cache_lock:
         _cache = None
     _enabled = None
+    _scope_maps.clear()
 
 
 def findings():

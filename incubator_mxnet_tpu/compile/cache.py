@@ -138,7 +138,9 @@ class ProgramCache:
         # wasted work, and with the original alive the clone's teardown
         # double-frees runtime state on this jaxlib (observed glibc heap
         # corruption).  Bounded LRU; entries are dropped oldest-first.
-        self._live = {}
+        # It outlives the wrappers (a fit's module may be gone while its
+        # executable is held here), so `op_scopes` reads it too.
+        self._live = {}          # entry-key -> (executable, its label)
         self._live_cap = 64
         # keys whose entry was found corrupt/stale in a READ-ONLY source
         # (we cannot delete there): the next export of that key rewrites
@@ -203,18 +205,24 @@ class ProgramCache:
         one (compiled or deserialized earlier) — the in-process restart
         fast path: no compile, no deserialize."""
         with self._lock:
-            exe = self._live.get(key)
-            if exe is not None:
-                self.counters["live_hits"] += 1
-                # LRU touch
-                self._live[key] = self._live.pop(key)
-            return exe
+            held = self._live.get(key)
+            if held is None:
+                return None
+            self.counters["live_hits"] += 1
+            # LRU touch
+            self._live[key] = self._live.pop(key)
+            return held[0]
 
-    def live_put(self, key, exe):
+    def live_put(self, key, exe, label=""):
         with self._lock:
-            self._live[key] = exe
+            self._live[key] = (exe, label)
             while len(self._live) > self._live_cap:
                 self._live.pop(next(iter(self._live)))
+
+    def live_programs(self):
+        """[(label, executable)] of the live tier, oldest use first."""
+        with self._lock:
+            return [(label, exe) for exe, label in self._live.values()]
 
     # -- lookup / store ------------------------------------------------------
     def _paths(self, key):

@@ -35,6 +35,7 @@ import re
 import time as _time
 
 from ..analysis import locks as _alocks
+from ..obs import trace as _obs_trace
 
 __all__ = ["CachedProgram", "cached_jit", "graph_hash_of_jaxpr",
            "graph_hash_of_text"]
@@ -146,6 +147,13 @@ class CachedProgram:
         recompile certification) reads."""
         return len(self._programs)
 
+    def executables(self):
+        """The AOT executables this wrapper holds, oldest first (the
+        plain-jit signatures hold none)."""
+        with self._lock:
+            return [e for e in self._programs.values()
+                    if e is not _PLAIN and e is not None]
+
     # -- acquire -------------------------------------------------------------
     def _entry_key(self, sig, devices):
         from . import cache as _cache
@@ -175,10 +183,12 @@ class CachedProgram:
             if exe is not None:
                 return exe
             if cache.enabled():
-                exe = cache.load(key, devices)
+                with _obs_trace.span("compile.load", cat="compile",
+                                     label=self.label):
+                    exe = cache.load(key, devices)
                 if exe is not None:
                     self.disk_hits += 1
-                    cache.live_put(key, exe)
+                    cache.live_put(key, exe, self.label)
                     return exe
                 self.disk_misses += 1
                 cache.bump("disk_misses")
@@ -188,16 +198,21 @@ class CachedProgram:
         # compile proper — the cold-start debt mxtop's CACHE line and
         # bench's compile_phases block report per program
         t0 = _time.perf_counter()
-        lowered = self._jit.lower(*args)
+        with _obs_trace.span("compile.lower", cat="compile",
+                             label=self.label):
+            lowered = self._jit.lower(*args)
         t1 = _time.perf_counter()
-        exe = lowered.compile()
+        # XLA's compile, or the load from JAX's persistent cache
+        with _obs_trace.span("compile.compile", cat="compile",
+                             label=self.label):
+            exe = lowered.compile()
         t2 = _time.perf_counter()
         self.lower_s_total += t1 - t0
         self.compile_s_total += t2 - t1
         cache.note_compile(self.label, sig_repr, lower_s=t1 - t0,
                            compile_s=t2 - t1)
         if key is not None:
-            cache.live_put(key, exe)
+            cache.live_put(key, exe, self.label)
             if cache.enabled():
                 cache.store(key, exe, meta={"label": self.label,
                                             "graph": self.graph_key,

@@ -363,9 +363,11 @@ class _TracedCore:
         def flat_core(*leaves):
             return core(*jax.tree_util.tree_unflatten(in_tree, leaves))
 
+        from .obs import trace as _obs_trace
         t0 = _time.perf_counter()
-        closed, out_shape = jax.make_jaxpr(
-            flat_core, return_shape=True)(*flat)
+        with _obs_trace.span("fused.trace", cat="compile"):
+            closed, out_shape = jax.make_jaxpr(
+                flat_core, return_shape=True)(*flat)
         self.trace_s = _time.perf_counter() - t0
         self._closed = closed
         self._in_tree = in_tree
@@ -1248,7 +1250,12 @@ class FusedTrainStep:
     # -- the traced step core ------------------------------------------------
     def _build_core(self, metric_fns):
         """The one-step train function over raw arrays.  Returned as plain
-        Python; `_trace_core` runs it exactly once under `make_jaxpr`."""
+        Python; `_trace_core` runs it exactly once under `make_jaxpr`.
+
+        Its phases run under `jax.named_scope`s — ``fwd``, ``bwd``,
+        ``exchange``, ``optimizer``, ``guardian``, ``metric`` (the names
+        `compile.op_scopes` classifies by) — which reach the compiled
+        program as `op_name` metadata and change nothing else in it."""
         import jax
         import jax.numpy as jnp
 
@@ -1288,8 +1295,9 @@ class FusedTrainStep:
             else:
                 inputs, lr_vec, wd_vec = x
             if derive:
-                ws = [jax.tree_util.tree_leaves(s)[p].astype(dt)
-                      for s, p, dt in zip(ss, mp_pos, w_dtypes)]
+                with jax.named_scope("fwd"):
+                    ws = [jax.tree_util.tree_leaves(s)[p].astype(dt)
+                          for s, p, dt in zip(ss, mp_pos, w_dtypes)]
             # t advances IN-GRAPH (donated carry): the host passes the
             # update counts once when (re)arming and never re-uploads the
             # vector — keeping every steady-state dispatch argument a
@@ -1312,19 +1320,22 @@ class FusedTrainStep:
                 outs, new_aux = gfn(tuple(args), tuple(auxs), sub)
                 return tuple(outs), tuple(new_aux)
 
-            outs, vjp, new_aux = jax.vjp(forward, list(ws), has_aux=True)
-            # scan carries must keep invariant dtypes (see gluon core): pin
-            # aux updates to the stored aux dtype
-            new_aux = tuple(
-                na.astype(a.dtype) if na.dtype != a.dtype else na
-                for na, a in zip(new_aux, auxs))
-            cts = tuple(
-                jnp.ones(o.shape, o.dtype)
-                if jnp.issubdtype(o.dtype, jnp.floating)
-                else jnp.zeros(o.shape, o.dtype) for o in outs)
-            (grads,) = vjp(cts)
-            if guard:
-                grads = [g * jnp.asarray(gmul, g.dtype) for g in grads]
+            with jax.named_scope("fwd"):
+                outs, vjp, new_aux = jax.vjp(forward, list(ws),
+                                             has_aux=True)
+                # scan carries must keep invariant dtypes (see gluon
+                # core): pin aux updates to the stored aux dtype
+                new_aux = tuple(
+                    na.astype(a.dtype) if na.dtype != a.dtype else na
+                    for na, a in zip(new_aux, auxs))
+            with jax.named_scope("bwd"):
+                cts = tuple(
+                    jnp.ones(o.shape, o.dtype)
+                    if jnp.issubdtype(o.dtype, jnp.floating)
+                    else jnp.zeros(o.shape, o.dtype) for o in outs)
+                (grads,) = vjp(cts)
+                if guard:
+                    grads = [g * jnp.asarray(gmul, g.dtype) for g in grads]
             pod_deltas = pod_outs_bad = None
             if pod_axis is not None:
                 # the pod fast path's gradient exchange: every gradient
@@ -1336,89 +1347,95 @@ class FusedTrainStep:
                 labels_p = inputs[len(inputs) - n_label:] if n_label \
                     else ()
                 extras = []
-                for fn, _m in metric_fns:
-                    dsum, dnum = fn(list(labels_p), list(outs))
-                    # dnum rides the float bundle; counts are exact in
-                    # f32 well past any step's sample count
-                    extras.append(jnp.asarray(dsum, jnp.float32))
-                    extras.append(jnp.asarray(dnum, jnp.float32))
+                with jax.named_scope("metric"):
+                    for fn, _m in metric_fns:
+                        dsum, dnum = fn(list(labels_p), list(outs))
+                        # dnum rides the float bundle; counts are exact
+                        # in f32 well past any step's sample count
+                        extras.append(jnp.asarray(dsum, jnp.float32))
+                        extras.append(jnp.asarray(dnum, jnp.float32))
                 n_metric = len(metric_fns)
                 extras.extend(list(new_aux))
                 if guard:
-                    oks = [jnp.isfinite(o).all() for o in outs
-                           if jnp.issubdtype(o.dtype, jnp.floating)]
-                    bad = jnp.float32(len(oks)) - sum(
-                        (o.astype(jnp.float32) for o in oks),
-                        jnp.float32(0.0))
+                    with jax.named_scope("guardian"):
+                        oks = [jnp.isfinite(o).all() for o in outs
+                               if jnp.issubdtype(o.dtype, jnp.floating)]
+                        bad = jnp.float32(len(oks)) - sum(
+                            (o.astype(jnp.float32) for o in oks),
+                            jnp.float32(0.0))
                     extras.append(bad)
-                grads, plan, sext, n_psums = _pod_bucket_psum(
-                    grads, pod_axis, pod_cap, extras)
-                self._pod_plan = plan
-                self._pod_psums = n_psums
-                pod_deltas = [(sext[2 * j], sext[2 * j + 1])
-                              for j in range(n_metric)]
-                # aux updates (BN moments) are averaged across shards —
-                # the reference executor group's cross-device aux merge
-                a0 = 2 * n_metric
-                new_aux = tuple(
-                    (sext[a0 + j] / jnp.asarray(pod_dp, na.dtype))
-                    .astype(na.dtype)
-                    for j, na in enumerate(new_aux))
+                with jax.named_scope("exchange"):
+                    grads, plan, sext, n_psums = _pod_bucket_psum(
+                        grads, pod_axis, pod_cap, extras)
+                    self._pod_plan = plan
+                    self._pod_psums = n_psums
+                    pod_deltas = [(sext[2 * j], sext[2 * j + 1])
+                                  for j in range(n_metric)]
+                    # aux updates (BN moments) are averaged across shards
+                    # — the reference executor group's cross-device aux
+                    # merge
+                    a0 = 2 * n_metric
+                    new_aux = tuple(
+                        (sext[a0 + j] / jnp.asarray(pod_dp, na.dtype))
+                        .astype(na.dtype)
+                        for j, na in enumerate(new_aux))
                 if guard:
                     pod_outs_bad = sext[-1]
-            new_ws, new_ss = _apply_traced(opt, indices, ws, grads, ss, ctx,
-                                           lr_vec, wd_vec, t_vec, rescale)
+            with jax.named_scope("optimizer"):
+                new_ws, new_ss = _apply_traced(opt, indices, ws, grads, ss,
+                                               ctx, lr_vec, wd_vec, t_vec,
+                                               rescale)
             if guard:
-                # the health word, computed where the data lives: one
-                # all-finite reduction over grads + floating outputs +
-                # the applied update, and the spike detector's signal —
-                # the parameter-DISPLACEMENT ratio ||new_w - w|| / ||w||.
-                # (A gradient norm is a poor damage proxy: a wrecked
-                # model can saturate into normal-looking gradients, and
-                # a converged model's gradient noise spans decades.  The
-                # displacement ratio measures the damage itself.)
-                parts = [jnp.isfinite(g).all() for g in grads]
-                if pod_axis is not None:
-                    # the shard-local output check already crossed the
-                    # wire inside the bundled exchange: a shard whose
-                    # LOCAL outputs went non-finite refuses the step on
-                    # every shard (grads/new_ws are globally identical
-                    # post-exchange, so those checks need no wire)
-                    parts.append(pod_outs_bad <= jnp.float32(0.5))
-                else:
-                    parts += [jnp.isfinite(o).all() for o in outs
-                              if jnp.issubdtype(o.dtype, jnp.floating)]
-                parts += [jnp.isfinite(nw).all() for nw in new_ws]
-                finite = parts[0]
-                for p in parts[1:]:
-                    finite = jnp.logical_and(finite, p)
-                unorm2 = sum(
-                    jnp.sum(jnp.square(nw.astype(jnp.float32)
-                                       - w.astype(jnp.float32)))
-                    for nw, w in zip(new_ws, ws))
-                wnorm2 = sum(
-                    jnp.sum(jnp.square(w.astype(jnp.float32)))
-                    for w in ws)
-                signal = jnp.sqrt(unorm2) / (jnp.sqrt(wnorm2)
-                                             + jnp.float32(1e-12))
-                # skip-batch: a non-finite step's updates are refused IN
-                # THE PROGRAM — weights/optimizer state/aux keep their
-                # input values; RNG key and update counts still advance,
-                # so a skipped step is deterministic and reproducible
-                def keep(new, old):
-                    return jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(finite, n,
-                                               o.astype(n.dtype)),
-                        new, old)
+                with jax.named_scope("guardian"):
+                    # the health word, computed where the data lives: one
+                    # all-finite reduction over grads + floating outputs +
+                    # the applied update, and the spike detector's signal —
+                    # the parameter-DISPLACEMENT ratio ||new_w - w|| / ||w||.
+                    # (A gradient norm is a poor damage proxy: a wrecked
+                    # model can saturate into normal-looking gradients, and
+                    # a converged model's gradient noise spans decades.  The
+                    # displacement ratio measures the damage itself.)
+                    parts = [jnp.isfinite(g).all() for g in grads]
+                    if pod_axis is not None:
+                        # the shard-local output check already crossed the
+                        # wire inside the bundled exchange: a shard whose
+                        # LOCAL outputs went non-finite refuses the step on
+                        # every shard (grads/new_ws are globally identical
+                        # post-exchange, so those checks need no wire)
+                        parts.append(pod_outs_bad <= jnp.float32(0.5))
+                    else:
+                        parts += [jnp.isfinite(o).all() for o in outs
+                                  if jnp.issubdtype(o.dtype, jnp.floating)]
+                    parts += [jnp.isfinite(nw).all() for nw in new_ws]
+                    finite = parts[0]
+                    for p in parts[1:]:
+                        finite = jnp.logical_and(finite, p)
+                    unorm2 = sum(
+                        jnp.sum(jnp.square(nw.astype(jnp.float32)
+                                           - w.astype(jnp.float32)))
+                        for nw, w in zip(new_ws, ws))
+                    wnorm2 = sum(
+                        jnp.sum(jnp.square(w.astype(jnp.float32)))
+                        for w in ws)
+                    signal = jnp.sqrt(unorm2) / (jnp.sqrt(wnorm2)
+                                                 + jnp.float32(1e-12))
+                    # skip-batch: a non-finite step's updates are refused IN
+                    # THE PROGRAM — weights/optimizer state/aux keep their
+                    # input values; RNG key and update counts still advance,
+                    # so a skipped step is deterministic and reproducible
+                    def keep(new, old):
+                        return jax.tree_util.tree_map(
+                            lambda n, o: jnp.where(finite, n,
+                                                   o.astype(n.dtype)),
+                            new, old)
 
-                new_ws = [jnp.where(finite, nw, w.astype(nw.dtype))
-                          for nw, w in zip(new_ws, ws)]
-                new_ss = tuple(keep(ns, s) for ns, s in zip(new_ss, ss))
-            if guard:
-                # BN aux updated by a non-finite forward is refused too
-                new_aux = tuple(
-                    jnp.where(finite, na, a.astype(na.dtype))
-                    for na, a in zip(new_aux, auxs))
+                    new_ws = [jnp.where(finite, nw, w.astype(nw.dtype))
+                              for nw, w in zip(new_ws, ws)]
+                    new_ss = tuple(keep(ns, s) for ns, s in zip(new_ss, ss))
+                    # BN aux updated by a non-finite forward is refused too
+                    new_aux = tuple(
+                        jnp.where(finite, na, a.astype(na.dtype))
+                        for na, a in zip(new_aux, auxs))
             # keep the persistent carries in their input layout (replicated
             # for DP; whatever the user sharded for TP/ZeRO).  Inside the
             # pod shard_map the layout is enforced by the out_specs
@@ -1438,32 +1455,34 @@ class FusedTrainStep:
                     for w, s in zip(new_ws, self._call_w_shardings))
             else:
                 new_ws = tuple(new_ws)
-            labels = inputs[len(inputs) - n_label:] if n_label else ()
-            new_mcarry = []
-            for j, ((fn, _), (msum, mnum)) in enumerate(
-                    zip(metric_fns, mcarry)):
-                if pod_deltas is not None:
-                    # global deltas arrived inside the bundled exchange
-                    dsum, dnum = pod_deltas[j]
-                    dnum = dnum.astype(jnp.int32)
-                else:
-                    dsum, dnum = fn(list(labels), list(outs))
-                    dsum = jnp.asarray(dsum, jnp.float32)
-                    dnum = jnp.asarray(dnum, jnp.int32)
-                if guard:
-                    # a skipped batch must not poison the metric totals
-                    dsum = jnp.where(finite, dsum, jnp.zeros_like(dsum))
-                    dnum = jnp.where(finite, dnum, jnp.zeros_like(dnum))
-                # counts carry as int32: float32 would silently stop
-                # incrementing past 2^24 samples
-                new_mcarry.append((msum + dsum, mnum + dnum))
+            with jax.named_scope("metric"):
+                labels = inputs[len(inputs) - n_label:] if n_label else ()
+                new_mcarry = []
+                for j, ((fn, _), (msum, mnum)) in enumerate(
+                        zip(metric_fns, mcarry)):
+                    if pod_deltas is not None:
+                        # global deltas arrived inside the bundled exchange
+                        dsum, dnum = pod_deltas[j]
+                        dnum = dnum.astype(jnp.int32)
+                    else:
+                        dsum, dnum = fn(list(labels), list(outs))
+                        dsum = jnp.asarray(dsum, jnp.float32)
+                        dnum = jnp.asarray(dnum, jnp.int32)
+                    if guard:
+                        # a skipped batch must not poison the metric totals
+                        dsum = jnp.where(finite, dsum, jnp.zeros_like(dsum))
+                        dnum = jnp.where(finite, dnum, jnp.zeros_like(dnum))
+                    # counts carry as int32: float32 would silently stop
+                    # incrementing past 2^24 samples
+                    new_mcarry.append((msum + dsum, mnum + dnum))
             new_inner = (new_ws, new_ss, tuple(new_aux), tuple(new_mcarry),
                          key, t_vec)
             if guard:
                 # per-step health word: fetched asynchronously by the
                 # guardian (device scalars; no host sync on this path)
-                return new_inner, (tuple(outs),
-                                   (finite.astype(jnp.float32), signal))
+                with jax.named_scope("guardian"):
+                    ok = finite.astype(jnp.float32)
+                return new_inner, (tuple(outs), (ok, signal))
             return new_inner, tuple(outs)
 
         return core

@@ -33,10 +33,19 @@ io tier that removes that serialization:
   runtime.  `ImageRecordIter`/`ImageIter` accept ``num_parts='auto'``
   and re-resolve at every `reset()`.
 
-Telemetry: every transfer runs under an ``io.h2d`` trace span (mxtrace
-shows input overlap against ``fit.step``), and the ring registers its
-stats under the ``io.*`` dotted namespace in the obs MetricsRegistry —
-prefetch depth, occupancy, stalls, bytes, decode-worker queue depth.
+Telemetry: the feeder's three stages run under three sibling leaf
+spans per batch — ``io.source`` (the inner iterator's `next()`),
+``io.stage`` (the cast and copy into the staging slot), ``io.h2d``
+(`device_put` and the wait for it) — beside three always-on counters
+of the same intervals (``source_s``, ``stage_s``, ``put_s``).  Spans
+stand around WORK only: a wait during which the device may be idle (the
+consumer's `get()` on an empty ring, the feeder's wait for a free slot)
+is a counter (``stall_s``, ``stalls``) and never a span, because a
+profile's reader names an idle gap by the span that overlaps it most,
+and a span over a wait would cover every feed-bound gap and hide the
+stage that caused it.  The ring registers its stats under the ``io.*``
+dotted namespace in the obs MetricsRegistry — prefetch depth,
+occupancy, stalls, bytes, decode-worker queue depth.
 """
 from __future__ import annotations
 
@@ -92,7 +101,8 @@ _registered = False
 # wrappers are released when fit returns; the bench io lane reads
 # before/after deltas of these)
 _TOTALS = {"stalls": 0, "stall_s": 0.0, "batches": 0, "bytes": 0,
-           "h2d_s": 0.0, "staging_copies": 0, "zero_copy": 0}
+           "h2d_s": 0.0, "source_s": 0.0, "stage_s": 0.0, "put_s": 0.0,
+           "staging_copies": 0, "zero_copy": 0}
 _totals_lock = None
 
 
@@ -130,9 +140,17 @@ def _register_producer():
 
 def stats():
     """Io-tier stats (the ``io`` metrics producer): process-lifetime
-    totals (stalls, batches, bytes, h2d seconds, staging/zero-copy
-    counts — these survive individual rings) plus the LIVE rings'
-    count, configured prefetch depth, and current queue occupancy."""
+    totals (stalls, batches, bytes, the feeder's seconds by stage,
+    staging/zero-copy counts — these survive individual rings) plus the
+    LIVE rings' count, configured prefetch depth, and current queue
+    occupancy.
+
+    The feeder's seconds: ``source_s`` in the inner iterator's `next()`,
+    ``stage_s`` in the cast and copy into the staging slot, ``put_s`` in
+    `device_put` and the wait for it; ``h2d_s`` is stage + put (with the
+    adoption check between them), what one `H2DRing.put` costs once a
+    slot is free.  ``stall_s`` is the CONSUMER's wait on an empty
+    ring."""
     with _totals_guard():
         out = dict(_TOTALS)
     out.update({"rings": 0, "prefetch_depth": 0, "occupancy": 0})
@@ -266,7 +284,8 @@ class H2DRing:
         self._adopt_possible = None   # resolved on first transfer
         self._ended = None            # _EndOfData once the source dried
         self._stats = {"stalls": 0, "stall_s": 0.0, "batches": 0,
-                       "bytes": 0, "h2d_s": 0.0, "staging_copies": 0,
+                       "bytes": 0, "h2d_s": 0.0, "source_s": 0.0,
+                       "stage_s": 0.0, "put_s": 0.0, "staging_copies": 0,
                        "zero_copy": 0}
         self._stats_lock = _alocks.make_lock("io.ring.stats")
         _rings.add(self)
@@ -356,8 +375,11 @@ class H2DRing:
                 return False
         with self._put_lock:
             t0 = time.perf_counter()
-            staged, copies, slot = self._assemble(arrays)
-            nbytes = sum(int(a.nbytes) for a in staged)
+            with _trace.span("io.stage", cat="io", ring=self.name) as sp:
+                staged, copies, slot = self._assemble(arrays)
+                nbytes = sum(int(a.nbytes) for a in staged)
+                sp.note(bytes=nbytes, copies=copies)
+            t1 = time.perf_counter()
             with _trace.span("io.h2d", cat="io", ring=self.name,
                              bytes=nbytes):
                 devs = self._placement.put(staged)
@@ -365,6 +387,7 @@ class H2DRing:
                 # slot is free for reuse the moment this returns, and
                 # the consumer pops fully-resident arrays
                 jax.block_until_ready(devs)
+            t2 = time.perf_counter()
             if self._staging and self._may_adopt():
                 # retire any buffer the backend adopted zero-copy: it
                 # now BELONGS to the emitted device array and refilling
@@ -380,19 +403,33 @@ class H2DRing:
             self._stats["batches"] += 1
             self._stats["bytes"] += nbytes
             self._stats["h2d_s"] += dt
+            self._stats["stage_s"] += t1 - t0
+            self._stats["put_s"] += t2 - t1
             self._stats["staging_copies"] += copies
-        _totals_add(batches=1, bytes=nbytes, h2d_s=dt,
-                    staging_copies=copies)
-        m = _metrics()
-        m.counter("io.h2d.batches").inc()
-        m.counter("io.h2d.bytes").inc(nbytes)
+        _totals_add(batches=1, bytes=nbytes, h2d_s=dt, stage_s=t1 - t0,
+                    put_s=t2 - t1, staging_copies=copies)
         with self._cond:
             if self._closed or token not in (None, self._token):
                 return False
             self._q.append((devs, meta))
-            m.gauge("io.ring.occupancy").set(len(self._q))
+            _metrics().gauge("io.ring.occupancy").set(len(self._q))
             self._cond.notify_all()
         return True
+
+    def timed_source(self, fetch):
+        """`fetch()`, the feeder's pull of one batch from its source,
+        under the ``io.source`` span and the ``source_s`` counter (the
+        ring never sees the source, so its feeders call through here)."""
+        from .obs import trace as _trace
+        t0 = time.perf_counter()
+        try:
+            with _trace.span("io.source", cat="io", ring=self.name):
+                return fetch()
+        finally:
+            dt = time.perf_counter() - t0
+            with self._stats_lock:
+                self._stats["source_s"] += dt
+            _totals_add(source_s=dt)
 
     def put_end(self, exc=None, token=None):
         """Mark the source exhausted (or broken): `get` drains the queue
@@ -435,7 +472,6 @@ class H2DRing:
                 self._stats["stalls"] += 1
                 self._stats["stall_s"] += dt
             _totals_add(stalls=1, stall_s=dt)
-            _metrics().counter("io.ring.stalls").inc()
         if isinstance(item, _EndOfData):
             if item.exc is not None:
                 raise item.exc
@@ -558,7 +594,7 @@ class DevicePrefetchIter(DataIter):
             while not stop.is_set():
                 try:
                     with self._inner_lock:
-                        batch = self._inner.next()
+                        batch = ring.timed_source(self._inner.next)
                 except StopIteration:
                     ring.put_end(token=token)
                     return
@@ -683,7 +719,7 @@ class DevicePrefetchLoader:
         try:
             while not stop.is_set():
                 try:
-                    pair = next(it)
+                    pair = ring.timed_source(it.__next__)
                 except StopIteration:
                     ring.put_end(token=token)
                     return

@@ -13,6 +13,7 @@ from .. import metric as _metric
 from .. import io as _io
 from ..model import BatchEndParam
 from ..ndarray.ndarray import NDArray
+from ..obs import trace as _obs_trace
 
 
 class BaseModule:
@@ -439,16 +440,20 @@ class BaseModule:
                 resume_nbatch = ckpt_resume.nbatch
                 gstep = ckpt_resume.step
 
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        with _obs_trace.span("fit.bind", cat="train"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params, mesh=mesh)
+        with _obs_trace.span("fit.init_params", cat="train"):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with _obs_trace.span("fit.init_optimizer", cat="train"):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params, mesh=mesh)
         sup = self._start_supervisor()
         # h2d staging ring (io_plane.py, MXNET_IO_RING): wrap the
         # training iterator so batches decode, stage into reusable host
@@ -709,11 +714,15 @@ class BaseModule:
                     for b in block:
                         self.fit_step(b, eval_metric)
                         if batch_end_callback is not None:
-                            batch_end_params = BatchEndParam(
-                                epoch=epoch, nbatch=nbatch,
-                                eval_metric=eval_metric, locals=locals())
-                            for callback in _as_list(batch_end_callback):
-                                callback(batch_end_params)
+                            with _obs_trace.span("fit.callbacks",
+                                                 cat="train", epoch=epoch,
+                                                 nbatch=nbatch):
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric,
+                                    locals=locals())
+                                for callback in _as_list(batch_end_callback):
+                                    callback(batch_end_params)
                         nbatch += 1
                 if not end_of_batch:
                     try:
@@ -725,15 +734,23 @@ class BaseModule:
                 if monitor is not None:
                     self.update_metric(eval_metric, data_batch.label)
                     monitor.toc_print()
-                for _bi, _b in enumerate(burst):
-                    self._fit_block_cursor(_bi)
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(
-                            epoch=epoch, nbatch=nbatch,
-                            eval_metric=eval_metric, locals=locals())
-                        for callback in _as_list(batch_end_callback):
-                            callback(batch_end_params)
-                    nbatch += 1
+                if burst:
+                    # the burst of K cursor moves and callbacks: host
+                    # work between two blocks, under one span
+                    with _obs_trace.span("fit.callbacks", cat="train",
+                                         epoch=epoch, nbatch=nbatch,
+                                         k=len(burst)):
+                        for _bi, _b in enumerate(burst):
+                            self._fit_block_cursor(_bi)
+                            if batch_end_callback is not None:
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric,
+                                    locals=locals())
+                                for callback in _as_list(
+                                        batch_end_callback):
+                                    callback(batch_end_params)
+                            nbatch += 1
 
                 gstep += nbatch - nbatch_at_entry
                 if guardian is not None and nbatch > nbatch_at_entry:
@@ -771,20 +788,29 @@ class BaseModule:
             # host-sync hazards (analysis.hostsync would misattribute)
             from .. import analysis as _analysis
             with _analysis.hostsync.paused():
-                for name, val in eval_metric.get_name_value():
-                    self.logger.info("Epoch[%d] Train-%s=%f", epoch, name,
-                                     val)
-                toc = time.time()
-                self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
-                                 (toc - tic))
+                with _obs_trace.span("fit.epoch_end", cat="train",
+                                     epoch=epoch, nbatch=nbatch) as sp:
+                    # the metric read is where the loop first needs the
+                    # device's results: its time is the wait for the
+                    # last block (not work), told apart as `wait_us`
+                    wait_tic = time.perf_counter()
+                    name_values = eval_metric.get_name_value()
+                    sp.note(wait_us=int(
+                        (time.perf_counter() - wait_tic) * 1e6))
+                    for name, val in name_values:
+                        self.logger.info("Epoch[%d] Train-%s=%f", epoch,
+                                         name, val)
+                    toc = time.time()
+                    self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
+                                     (toc - tic))
 
-                arg_params_, aux_params_ = self.get_params()
-                self.set_params(arg_params_, aux_params_)
+                    arg_params_, aux_params_ = self.get_params()
+                    self.set_params(arg_params_, aux_params_)
 
-                if epoch_end_callback is not None:
-                    for callback in _as_list(epoch_end_callback):
-                        callback(epoch, self.symbol, arg_params_,
-                                 aux_params_)
+                    if epoch_end_callback is not None:
+                        for callback in _as_list(epoch_end_callback):
+                            callback(epoch, self.symbol, arg_params_,
+                                     aux_params_)
 
                 if eval_data:
                     res = self.score(

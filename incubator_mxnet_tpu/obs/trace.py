@@ -28,7 +28,15 @@ missing correlation:
 
 Enabled by pointing ``MXNET_OBS_TRACE`` at the shared span file (the
 env propagates to spawned workers/daemons) or `enable(path)`.  Off,
-every hook is a single global read returning a shared no-op span.  The
+every hook is a single global read returning a shared no-op span.
+
+**One clock.**  A live span also holds a `jax.profiler.TraceAnnotation`
+of its name for as long as it is open, so a profile taken by anyone —
+a benchmark's traced run, an operator's `jax.profiler.trace` — shows
+``io.stage``, ``fit.callbacks`` ... on the host plane against the
+device's ops, on the profile's own clock.  With no profile running the
+annotation is a check of one flag; `record_span` (already timed,
+post hoc) cannot hold one.  The
 in-memory buffer is bounded (``MXNET_OBS_TRACE_BUFFER``, drop-oldest
 with a ``dropped`` counter surfaced as a metric); it auto-flushes
 every ``_FLUSH_EVERY`` spans and at exit, and explicitly via
@@ -328,11 +336,30 @@ def _record(tr, sp, pa, name, cat, ts, dur, args):
         _flush_event.set()
 
 
+_TraceAnnotation = []     # [class] once resolved; [None] without jax
+
+
+def _annotate(name):
+    """An entered `jax.profiler.TraceAnnotation` of `name`, or None
+    where the profiler cannot be had."""
+    if not _TraceAnnotation:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        _TraceAnnotation.append(TraceAnnotation)
+    if _TraceAnnotation[0] is None:
+        return None
+    annotation = _TraceAnnotation[0](name)
+    annotation.__enter__()
+    return annotation
+
+
 class SpanHandle:
     """One live span; `end()` exactly once buffers the record."""
 
     __slots__ = ("trace", "span", "parent", "name", "cat", "t0", "args",
-                 "_done")
+                 "_done", "_annotation")
 
     def __init__(self, name, trace, parent, cat, args):
         self.name = name
@@ -340,9 +367,11 @@ class SpanHandle:
         self.span = _id("s")
         self.parent = parent
         self.cat = cat
-        self.t0 = time.time_ns() // 1000
         self.args = args
         self._done = False
+        # the same span on the profiler's clock (module docstring)
+        self._annotation = _annotate(name)
+        self.t0 = time.time_ns() // 1000
 
     def frame(self):
         """The wire form carried in a transport frame's ``tr`` field."""
@@ -356,10 +385,13 @@ class SpanHandle:
         if self._done:
             return
         self._done = True
+        dur = time.time_ns() // 1000 - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         if args:
             self.args.update(args)
         _record(self.trace, self.span, self.parent, self.name, self.cat,
-                self.t0, time.time_ns() // 1000 - self.t0, self.args)
+                self.t0, dur, self.args)
 
 
 class _NullSpan:

@@ -78,6 +78,7 @@ import numpy as _np
 
 from ..analysis import locks as _locks
 from ..base import MXNetError
+from ..obs import trace as _obs_trace
 from . import faults as _faults
 
 __all__ = ["TrainingGuardian", "TrainingDivergedError", "RollbackRequested",
@@ -297,8 +298,11 @@ class TrainingGuardian:
         self._allreduce = None   # kvstore reduction (multi-worker)
         self._kv_seen = _np.zeros(3, _np.float64)  # cumulative pulled
         self._sync_errors = 0
-        self._stats = {"steps_observed": 0, "polls": 0, "skips": 0,
-                       "spikes": 0, "rollbacks": 0, "quarantined": 0,
+        # poll_wait_s: seconds the polls stood in their device gather
+        # (the fit loop waits there for the block just dispatched)
+        self._stats = {"steps_observed": 0, "polls": 0, "poll_wait_s": 0.0,
+                       "skips": 0, "spikes": 0, "rollbacks": 0,
+                       "quarantined": 0,
                        "sync_degraded": 0, "injected_nonfinite": 0,
                        "injected_spike": 0}
         # telemetry plane: skip/rollback/quarantine counters under the
@@ -436,10 +440,19 @@ class TrainingGuardian:
         if not force and pending_steps < self.interval:
             return
         self._stats["polls"] += 1
-        tokens = self._classify(self._materialize())
-        local = self._ladder_inputs(tokens)
-        agreed = self._agree(local)
-        self._apply_ladder(agreed, tokens, gstep)
+        # a span around the poll's WORK; the wait for the device inside
+        # it (the gather) is no work and is told apart as `wait_us`
+        sp = _obs_trace.start_span("fit.guardian", cat="train",
+                                   steps=pending_steps, gstep=int(gstep))
+        wait0 = self._stats["poll_wait_s"]
+        try:
+            tokens = self._classify(self._materialize())
+            local = self._ladder_inputs(tokens)
+            agreed = self._agree(local)
+            self._apply_ladder(agreed, tokens, gstep)
+        finally:
+            sp.end(wait_us=int(
+                (self._stats["poll_wait_s"] - wait0) * 1e6))
 
     def _materialize(self):
         """One blocking gather of every pending device token ->
@@ -451,7 +464,9 @@ class TrainingGuardian:
         for e in pending:
             leaves.append(e["ok"])
             leaves.append(e["sig"])
+        t0 = time.perf_counter()
         host = jax.device_get(leaves)
+        self._stats["poll_wait_s"] += time.perf_counter() - t0
         out = []
         # pending tokens are exactly the last sum(k) dispatched steps,
         # ending at the fused step's counter (_gstep) — rollback-safe

@@ -692,7 +692,11 @@ def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
                     ins.append(jnp.asarray(params.pop(pname), dtype="float32"))
             if node.op.needs_rng:
                 ins.append(key_for(node))
-            out = op_fn(params, *ins)
+            # the MXNet operator kind and node name reach the compiled
+            # program as `op_name` metadata, on this op's forward ops and
+            # on their transposes in a backward pass (compile.op_scopes)
+            with jax.named_scope(f"{node.op.name}:{node.name}"):
+                out = op_fn(params, *ins)
             if not isinstance(out, (tuple, list)):
                 out = (out,)
             nout = node.op.num_outputs(params)
