@@ -352,7 +352,14 @@ KNOBS = {
                                 "trained steps between health-word "
                                 "polls: the device scalars accumulate "
                                 "and are gathered in ONE host read per "
-                                "interval (no per-step host sync)"),
+                                "interval (no per-step host sync). "
+                                "An unforced poll never waits for the "
+                                "newest dispatch, so a step is "
+                                "diagnosed at most interval + one "
+                                "dispatch's steps (MXNET_FUSED_STEP_"
+                                "BLOCK) after it ran; forced polls "
+                                "(epoch end, checkpoint and preemption "
+                                "snapshots) are exact"),
     "MXNET_GUARDIAN_SPIKE_WINDOW": (int, 16, "honored",
                                     "EWMA window (and warmup step "
                                     "count) of the loss-spike detector "

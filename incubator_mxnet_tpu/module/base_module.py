@@ -4,6 +4,7 @@ train loop :515-560)."""
 from __future__ import annotations
 
 import logging
+import statistics
 import time
 
 import numpy as _np
@@ -739,9 +740,12 @@ class BaseModule:
                     # work between two blocks, under one span
                     with _obs_trace.span("fit.callbacks", cat="train",
                                          epoch=epoch, nbatch=nbatch,
-                                         k=len(burst)):
+                                         k=len(burst)) as sp:
+                        moves = []
                         for _bi, _b in enumerate(burst):
+                            move_tic = time.perf_counter()
                             self._fit_block_cursor(_bi)
+                            moves.append(time.perf_counter() - move_tic)
                             if batch_end_callback is not None:
                                 batch_end_params = BatchEndParam(
                                     epoch=epoch, nbatch=nbatch,
@@ -751,13 +755,30 @@ class BaseModule:
                                         batch_end_callback):
                                     callback(batch_end_params)
                             nbatch += 1
+                        # a move enqueues a few tiny programs on the
+                        # block just dispatched, the same host work K
+                        # times.  The runtime takes only so many
+                        # programs in flight (32 on the v5e): with the
+                        # block before still running and that block's
+                        # own burst queued behind it, one enqueue of
+                        # this burst stands until that block ends.  What
+                        # a move took beyond the median move is that
+                        # wait FOR the device, not work
+                        typical = statistics.median_low(moves)
+                        sp.note(wait_us=int(sum(
+                            max(0.0, m - typical) for m in moves) * 1e6))
 
                 gstep += nbatch - nbatch_at_entry
                 if guardian is not None and nbatch > nbatch_at_entry:
                     # pair the block's health tokens with their stream
                     # positions, then run the policy ladder every
                     # MXNET_GUARDIAN_INTERVAL steps (one device gather;
-                    # raises RollbackRequested / TrainingDivergedError)
+                    # raises RollbackRequested / TrainingDivergedError).
+                    # The poll lags by one dispatch: it waits for the
+                    # block BEFORE the one dispatched above, which stays
+                    # queued on the device, so the device goes from one
+                    # block to the next with no host in between and this
+                    # loop collects the next block's batches under it
                     guardian.tag(epoch, nbatch_at_entry, train_data)
                     guardian.maybe_poll(gstep)
                 if self._supervisor is not None and nbatch > nbatch_at_entry:

@@ -22,7 +22,16 @@ Three layers:
   does NOT block on them; `maybe_poll` materializes the accumulated
   tokens every ``MXNET_GUARDIAN_INTERVAL`` steps (one gather), so
   steady-state overhead is a fused reduction per step and one small
-  device->host read per interval (<2%, gated in bench.py).
+  device->host read per interval (<2%, gated in bench.py).  An unforced
+  poll LAGS by one dispatch: it never gathers the health word of the
+  newest dispatch (the block, or the single step, the device is still
+  running), so the fit loop waits for work the device has finished or
+  is finishing, with the next dispatch already queued behind it — the
+  device never drains for a poll.  A step is therefore diagnosed at
+  most ``interval`` + one dispatch's steps after it ran; a FORCED poll
+  (epoch end, every checkpoint and preemption snapshot, so the end of
+  `fit` too) gathers everything, so a manifest is never stamped
+  healthy on evidence older than its own step.
 
 * **policy ladder** (this module) — on each poll:
 
@@ -227,8 +236,10 @@ class TrainingGuardian:
     (`TrainingGuardian.maybe_create`), `attach()`es it to the bound
     module after `init_optimizer` (wires the fused step's in-graph
     health word, the kvstore reduction, and the iterator's quarantine),
-    then calls `tag()` + `maybe_poll()` per processed block and
-    `health_stamp()` at every checkpoint snapshot."""
+    then calls `tag()` + `maybe_poll()` per processed block (the poll
+    lags by one dispatch), `maybe_poll(force=True)` before every
+    snapshot and at the epoch's end, and `health_stamp()` at every
+    checkpoint snapshot."""
 
     @classmethod
     def maybe_create(cls, checkpoint_dir=None, logger=None):
@@ -275,7 +286,6 @@ class TrainingGuardian:
         # until a poll materializes them (no per-step host sync)
         self._pending = []
         self._untagged = 0       # trailing pending entries without a pos
-        self._steps_since_poll = 0
         self._gstep = 0          # trained-step counter (mirrors fit's)
         # spike detector state: EWMA + EW variance over LOG(signal) —
         # training signals decay exponentially, so a linear EWMA lags
@@ -299,8 +309,10 @@ class TrainingGuardian:
         self._kv_seen = _np.zeros(3, _np.float64)  # cumulative pulled
         self._sync_errors = 0
         # poll_wait_s: seconds the polls stood in their device gather
-        # (the fit loop waits there for the block just dispatched)
+        # (the fit loop waits there for the dispatch BEFORE the newest);
+        # polls_lagged: polls that left a dispatch in flight
         self._stats = {"steps_observed": 0, "polls": 0, "poll_wait_s": 0.0,
+                       "polls_lagged": 0, "steps_in_flight_max": 0,
                        "skips": 0, "spikes": 0, "rollbacks": 0,
                        "quarantined": 0,
                        "sync_degraded": 0, "injected_nonfinite": 0,
@@ -388,7 +400,9 @@ class TrainingGuardian:
     def record_health(self, k, ok, sig):
         """Health word of the last dispatch: `ok`/`sig` are device
         scalars (k==1) or stacked device vectors (a K-step block).  No
-        host sync here — `maybe_poll` materializes them in one gather."""
+        host sync here — `maybe_poll` materializes them in one gather,
+        and an unforced one only once a newer dispatch stands behind
+        this entry."""
         self._pending.append({"ok": ok, "sig": sig, "k": int(k),
                               "pos": None})
         self._untagged += 1
@@ -430,23 +444,36 @@ class TrainingGuardian:
                       nbatch=int(nbatch))
 
     def maybe_poll(self, gstep, force=False):
-        """Materialize pending health tokens and run the policy ladder —
-        every ``interval`` trained steps (or on `force`: checkpoint
-        boundaries, epoch ends).  Raises `RollbackRequested` on a
-        diagnosed spike, `TrainingDivergedError` past the budget."""
-        if not self._pending:
+        """Materialize pending health tokens and run the policy ladder.
+        Unforced (the fit loop, after every dispatch): the newest
+        dispatch stays in flight, and the OLDER tokens are gathered once
+        they cover ``interval`` trained steps — the loop waits for the
+        dispatch before the one it just queued, never for that one.
+        On `force` (checkpoint boundaries, epoch ends): everything, the
+        dispatch in flight included.  Raises `RollbackRequested` on a
+        diagnosed spike, `TrainingDivergedError` past the budget; a
+        dispatch still in flight is then never judged
+        (`rollback_committed` drops it): the restore discards its
+        update, its positions are not quarantined, and the replay trains
+        on them again."""
+        due = self._pending if force else self._pending[:-1]
+        due_steps = sum(e["k"] for e in due)
+        if not due or (not force and due_steps < self.interval):
             return
-        pending_steps = sum(e["k"] for e in self._pending)
-        if not force and pending_steps < self.interval:
-            return
+        in_flight = sum(e["k"] for e in self._pending[len(due):])
         self._stats["polls"] += 1
+        if in_flight:
+            self._stats["polls_lagged"] += 1
+            self._stats["steps_in_flight_max"] = max(
+                self._stats["steps_in_flight_max"], in_flight)
         # a span around the poll's WORK; the wait for the device inside
         # it (the gather) is no work and is told apart as `wait_us`
         sp = _obs_trace.start_span("fit.guardian", cat="train",
-                                   steps=pending_steps, gstep=int(gstep))
+                                   steps=due_steps, in_flight=in_flight,
+                                   gstep=int(gstep))
         wait0 = self._stats["poll_wait_s"]
         try:
-            tokens = self._classify(self._materialize())
+            tokens = self._classify(self._materialize(len(due)))
             local = self._ladder_inputs(tokens)
             agreed = self._agree(local)
             self._apply_ladder(agreed, tokens, gstep)
@@ -454,12 +481,17 @@ class TrainingGuardian:
             sp.end(wait_us=int(
                 (self._stats["poll_wait_s"] - wait0) * 1e6))
 
-    def _materialize(self):
-        """One blocking gather of every pending device token ->
-        [(pos, step_offset, ok, sig)] flattened per step."""
+    def _materialize(self, n):
+        """One blocking gather of the `n` oldest pending device tokens
+        -> [(pos, step_offset, ok, sig)] flattened per step; the newer
+        ones stay pending."""
         import jax
-        pending, self._pending = self._pending, []
-        self._untagged = 0
+        # pending tokens are exactly the last sum(k) dispatched steps,
+        # those left in flight included, ending at the fused step's
+        # counter (_gstep) — rollback-safe
+        base_step = self._gstep - sum(e["k"] for e in self._pending)
+        pending, self._pending = self._pending[:n], self._pending[n:]
+        self._untagged = min(self._untagged, len(self._pending))
         leaves = []
         for e in pending:
             leaves.append(e["ok"])
@@ -468,9 +500,6 @@ class TrainingGuardian:
         host = jax.device_get(leaves)
         self._stats["poll_wait_s"] += time.perf_counter() - t0
         out = []
-        # pending tokens are exactly the last sum(k) dispatched steps,
-        # ending at the fused step's counter (_gstep) — rollback-safe
-        base_step = self._gstep - sum(e["k"] for e in pending)
         consumed = 0
         for i, e in enumerate(pending):
             ok = _np.atleast_1d(_np.asarray(host[2 * i]))

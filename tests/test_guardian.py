@@ -68,11 +68,11 @@ def _data(n=128, bs=8):
     return io.NDArrayIter(x, y, batch_size=bs, shuffle=False)
 
 
-def _fit(mod, ckpt=None, n=128, num_epoch=2, resume=False):
+def _fit(mod, ckpt=None, n=128, num_epoch=2, resume=False, period=4):
     mod.fit(_data(n=n), num_epoch=num_epoch, optimizer="sgd",
             optimizer_params={"learning_rate": 0.05}, eval_metric="acc",
             initializer=mx.initializer.Xavier(),
-            checkpoint_dir=ckpt, checkpoint_period=4, resume=resume)
+            checkpoint_dir=ckpt, checkpoint_period=period, resume=resume)
     return mod
 
 
@@ -176,9 +176,143 @@ def test_guarded_matches_unguarded_numerics(monkeypatch):
     assert sha_on == sha_off
 
 
+# -- the poll lags by one dispatch ---------------------------------------------
+
+def _dispatch(g, k, nbatch0, ok=None):
+    """What the fused step and the fit loop do around one dispatch of k
+    steps that starts at batch `nbatch0` of epoch 0."""
+    g.step_multipliers(k)
+    ok = np.ones(k, np.float32) if ok is None else np.asarray(ok, np.float32)
+    sig = np.full(k, 0.01, np.float32)
+    g.record_health(k, ok if k > 1 else ok[0], sig if k > 1 else sig[0])
+    g.tag(0, nbatch0)
+
+
+def _judged(g, monkeypatch):
+    """Every poll's tokens as [(pos, step)], in the order judged."""
+    out = []
+    classify = g._classify
+
+    def spy(raw):
+        out.append([(pos, step) for pos, step, _, _ in raw])
+        return classify(raw)
+
+    monkeypatch.setattr(g, "_classify", spy)
+    return out
+
+
+@pytest.mark.parametrize("k,interval", [(8, 8), (1, 4)],
+                         ids=["block", "per-step"])
+def test_unforced_poll_leaves_newest_dispatch_in_flight(monkeypatch, k,
+                                                        interval):
+    g = TrainingGuardian(interval=interval, window=4)
+    judged = _judged(g, monkeypatch)
+    per_interval = interval // k         # dispatches that fill an interval
+    for i in range(per_interval):
+        _dispatch(g, k, i * k)
+        g.maybe_poll((i + 1) * k)
+    assert judged == []                  # nothing OLDER than the newest is due
+    _dispatch(g, k, interval)
+    g.maybe_poll(interval + k)
+    assert judged == [[((0, j), j + 1) for j in range(interval)]]
+    (kept,) = g._pending
+    assert kept["k"] == k and kept["pos"] == (0, interval)
+    st = g.stats()
+    assert st["polls"] == st["polls_lagged"] == 1
+    assert st["steps_in_flight_max"] == k
+    # forced: the dispatch in flight too, numbered and placed after the rest
+    g.maybe_poll(interval + k, force=True)
+    assert judged[1] == [((0, j), j + 1)
+                         for j in range(interval, interval + k)]
+    assert g._pending == [] and g.stats()["polls_lagged"] == 1
+    g.maybe_poll(interval + k, force=True)       # nothing pending: no poll
+    assert g.stats()["polls"] == 2
+    # forced with two dispatches pending: both, in step order
+    done = interval + k
+    for i in range(2):
+        _dispatch(g, k, done + i * k)
+    g.maybe_poll(done + 2 * k, force=True)
+    assert judged[2] == [((0, j), j + 1) for j in range(done, done + 2 * k)]
+    assert g._pending == []
+
+
+def test_retained_entry_still_takes_its_tag(monkeypatch):
+    """A poll between a dispatch and its `tag` (a guardian driven outside
+    the fit loop) keeps the newest entry untagged, and the next `tag`
+    still places it."""
+    g = TrainingGuardian(interval=8, window=4)
+    judged = _judged(g, monkeypatch)
+    for _ in range(2):
+        g.step_multipliers(8)
+        g.record_health(8, np.ones(8, np.float32), np.ones(8, np.float32))
+    g.maybe_poll(16)
+    assert judged == [[(None, j + 1) for j in range(8)]]
+    g.tag(0, 8)
+    assert g._pending[0]["pos"] == (0, 8) and g._untagged == 0
+
+
+def test_fit_never_waits_for_the_newest_dispatch(monkeypatch):
+    """Through a whole fit no unforced poll asks `jax.device_get` for the
+    arrays of the dispatch in flight; every forced one does.  A clean
+    run's `polls_lagged` counts exactly its unforced polls."""
+    import jax
+    now = {}
+    gathers = []        # (forced, asked for the newest dispatch's arrays)
+    poll, device_get = TrainingGuardian.maybe_poll, jax.device_get
+
+    def spy_poll(self, gstep, force=False):
+        now.update(force=force, newest=self._pending[-1]
+                   if self._pending else None)
+        try:
+            return poll(self, gstep, force=force)
+        finally:
+            now.clear()
+
+    def spy_get(x):
+        if now:
+            newest = now["newest"]
+            gathers.append((now["force"], any(
+                leaf is newest["ok"] or leaf is newest["sig"] for leaf in x)))
+        return device_get(x)
+
+    monkeypatch.setattr(TrainingGuardian, "maybe_poll", spy_poll)
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    mod = _fit(_model(), n=256)                  # 4 blocks of 8 an epoch
+    unforced = [asked for forced, asked in gathers if not forced]
+    assert len(unforced) == 6 and not any(unforced)
+    assert [asked for forced, asked in gathers if forced] == [True, True]
+    st = mod._guardian.stats()
+    assert st["polls"] == 8 and st["polls_lagged"] == len(unforced)
+    assert st["steps_in_flight_max"] == 8 and st["steps_observed"] == 64
+
+
+def test_nonfinite_quarantined_at_true_position_one_poll_later(tmp_path,
+                                                               monkeypatch):
+    """Step 5 (epoch 0, batch 4) is refused in-graph when it runs; its
+    quarantine line is written once block 2 is dispatched, by the poll
+    that judges block 1, and names the step's own position."""
+    monkeypatch.setenv("MXNET_GUARDIAN_QUARANTINE", str(tmp_path / "q.jsonl"))
+    seen = []
+    quarantine = TrainingGuardian._quarantine
+
+    def spy(self, pos, step, reason, signal):
+        seen.append((self._gstep, pos, step, reason))
+        return quarantine(self, pos, step, reason, signal)
+
+    monkeypatch.setattr(TrainingGuardian, "_quarantine", spy)
+    faults.configure("seed=7;grad.nonfinite:error(at=5)")
+    mod = _fit(_model(), n=256, num_epoch=1)
+    assert seen == [(16, (0, 4), 5, "nonfinite")]
+    (entry,) = QuarantineLog(str(tmp_path / "q.jsonl")).load()
+    assert (entry["epoch"], entry["nbatch"], entry["step"]) == (0, 4, 5)
+    assert mod._guardian.stats()["skips"] == 1
+
+
 # -- rollback ------------------------------------------------------------------
 
-def test_spike_rollback_bit_identical(tmp_path, fast_guardian):
+def _spike_rollback(tmp_path, n, period, at):
+    """A run with a `loss.spike` injected at step `at`, and the clean
+    reference over the same schedule with the same quarantine file."""
     ck_a = str(tmp_path / "ck-spike")
     ck_b = str(tmp_path / "ck-ref")
     # warm the scan AND 1-step programs: the post-rollback resume trains
@@ -191,9 +325,9 @@ def test_spike_rollback_bit_identical(tmp_path, fast_guardian):
     finally:
         os.environ.pop("MXNET_FUSED_STEP_BLOCK", None)
 
-    faults.configure("seed=7;loss.spike:error(at=10)")
+    faults.configure(f"seed=7;loss.spike:error(at={at})")
     c0 = mxcompile.stats()["counters"]["compiles"]
-    mod = _fit(_model(), ck_a)
+    mod = _fit(_model(), ck_a, n=n, period=period)
     st = mod._guardian.stats()
     compiles_during_recovery = mxcompile.stats()["counters"]["compiles"] - c0
     faults.clear()
@@ -205,9 +339,40 @@ def test_spike_rollback_bit_identical(tmp_path, fast_guardian):
     os.makedirs(ck_b)
     q = (tmp_path / "ck-spike" / "quarantine.jsonl").read_text()
     (tmp_path / "ck-ref" / "quarantine.jsonl").write_text(q)
-    ref = _fit(_model(), ck_b)
+    ref = _fit(_model(), ck_b, n=n, period=period)
     assert _sha(mod) == _sha(ref)
     assert ref._guardian.stats()["rollbacks"] == 0
+    return mod
+
+
+def test_spike_rollback_bit_identical(tmp_path, fast_guardian):
+    _spike_rollback(tmp_path, n=128, period=4, at=10)
+
+
+def test_spike_in_lagged_block_rollback_bit_identical(tmp_path, monkeypatch,
+                                                      fast_guardian):
+    """Snapshots 16 steps apart leave the polls of blocks 3 and 4 unforced:
+    the spike at step 20 (block 3) is diagnosed once block 4 is dispatched,
+    with the last good step a poll right after block 3 would name; block 4
+    is dropped unjudged, so its positions are not quarantined and the
+    replay trains on them again."""
+    polls = []
+    ladder = TrainingGuardian._apply_ladder
+
+    def spy(self, agreed, tokens, gstep):
+        polls.append((self._gstep, [t[1] for t in tokens]))
+        return ladder(self, agreed, tokens, gstep)
+
+    monkeypatch.setattr(TrainingGuardian, "_apply_ladder", spy)
+    mod = _spike_rollback(tmp_path, n=256, period=16, at=20)
+    dispatched, judged = next(p for p in polls if 20 in p[1])
+    assert judged == list(range(17, 25)) and dispatched == 32
+    g = mod._guardian
+    assert g.last_rollback_window == (20, 20)      # last good step 19
+    entries = QuarantineLog(g.quarantine.path).load()
+    assert sorted((e["epoch"], e["nbatch"]) for e in entries) == \
+        [(0, nb) for nb in range(19, 24)]
+    assert {e["reason"] for e in entries} == {"loss-spike"}
 
 
 def test_health_stamp_in_manifest(tmp_path):
@@ -337,6 +502,40 @@ def test_multi_worker_agreement():
     # the old verdict (decisions are taken on deltas)
     again = g_ok._agree(np.asarray([0, 0, 0], np.float64))
     assert again[0] == 0 and again[1] == 0
+
+
+def test_multi_worker_agreement_lagged_polls_pair_up():
+    """Two workers lag alike: every poll of one meets a poll of the other
+    over the same steps, so their all-reduces pair up one for one, and the
+    forced drain at the end leaves neither with a dispatch in flight."""
+    store = {}
+    calls = {"bad": 0, "ok": 0}
+    workers = {name: TrainingGuardian(interval=8, window=4)
+               for name in calls}
+    for name, g in workers.items():
+        g._wire_kvstore(_StubKV(store))
+
+        def counted(vec, name=name, allreduce=g._allreduce):
+            calls[name] += 1
+            return allreduce(vec)
+
+        g._allreduce = counted
+    for block in range(3):
+        for name, g in workers.items():
+            # worker "bad" alone saw a non-finite step, in the second block
+            ok = np.ones(8, np.float32)
+            ok[3] = 0.0 if (name, block) == ("bad", 1) else 1.0
+            _dispatch(g, 8, block * 8, ok=ok)
+            g.maybe_poll((block + 1) * 8)
+        assert calls["bad"] == calls["ok"] == block   # first block: none yet
+    # the second block's verdict came with the third in flight, on both sides
+    assert workers["bad"].stats()["skips"] == 1
+    assert workers["ok"].stats()["consecutive_failures"] == 1
+    assert workers["ok"].stats()["skips"] == 0
+    for g in workers.values():
+        g.maybe_poll(24, force=True)
+        assert g._pending == [] and g.stats()["polls_lagged"] == 2
+    assert calls == {"bad": 3, "ok": 3}
 
 
 def test_agreement_degrades_to_local():

@@ -281,16 +281,23 @@ def test_fit_spans_and_stage_counters():
     callbacks = _by_name(spans, "fit.callbacks")
     assert len(callbacks) == 3 and callbacks[0]["args"]["k"] == K
     assert [s["args"]["nbatch"] for s in callbacks] == [0, K, 2 * K]
-    # a poll every MXNET_GUARDIAN_INTERVAL (8) steps, so after the second
-    # block, and the forced one that drains the third at the epoch's end
+    # a poll once the blocks BEFORE the newest cover
+    # MXNET_GUARDIAN_INTERVAL (8) steps, so after the third block with
+    # that block in flight, and the forced one that drains it at the
+    # epoch's end
     polls = _by_name(spans, "fit.guardian")
     assert [s["args"]["steps"] for s in polls] == [2 * K, K]
+    assert [s["args"]["in_flight"] for s in polls] == [K, 0]
+    assert blocks[2]["ts"] < polls[0]["ts"]
     (epoch_end,) = _by_name(spans, "fit.epoch_end")
     assert epoch_end["args"]["nbatch"] == 3 * K
     for s in callbacks + polls + [epoch_end]:
         assert s["thread"] == main and s["pa"] is None
         assert all(_overlap(s, b) <= 1 for b in blocks), s
-    for s in polls + [epoch_end]:
+    # a wait FOR the device inside a work span is told apart: the poll's
+    # gather, the epoch end's metric read, and what a cursor move stood
+    # in the runtime's enqueue beyond the typical move
+    for s in callbacks + polls + [epoch_end]:
         assert 0 <= s["args"]["wait_us"] <= s["dur"] + 1
     waited = mod._guardian.stats()["poll_wait_s"]
     assert mod._guardian.stats()["polls"] == len(polls)
@@ -300,6 +307,31 @@ def test_fit_spans_and_stage_counters():
     for name in ("fit.bind", "fit.init_params", "fit.init_optimizer",
                  "fused.trace", "compile.lower", "compile.compile"):
         assert _by_name(spans, name), name
+
+
+def test_cursor_move_that_stands_in_the_enqueue_is_a_wait(monkeypatch):
+    """The K cursor moves are the same host work K times.  One that takes
+    longer stood in the runtime's enqueue behind a busy device: that time
+    is the `fit.callbacks` span's `wait_us`, the rest of the span its
+    work, and a callback's own time is never counted as a wait."""
+    stall, slow_callback = 0.15, 0.02
+    move = mx.mod.Module._fit_block_cursor
+
+    def stalled(self, j):
+        if j == 1:
+            time.sleep(stall)
+        return move(self, j)
+
+    monkeypatch.setattr(mx.mod.Module, "_fit_block_cursor", stalled)
+    obs_trace.enable()
+    _fit(blocks=2, callback=lambda param: time.sleep(
+        slow_callback if param.nbatch % K == 2 else 0))
+    callbacks = _by_name(obs_trace.buffered(), "fit.callbacks")
+    assert len(callbacks) == 2
+    for s in callbacks:
+        work = s["dur"] - s["args"]["wait_us"]
+        assert stall * 1e6 - 2000 <= s["args"]["wait_us"] <= s["dur"]
+        assert slow_callback * 1e6 <= work < stall * 1e6
 
 
 def test_tracing_off_opens_no_span_and_counters_still_advance(monkeypatch):
