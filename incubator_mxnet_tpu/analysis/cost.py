@@ -329,7 +329,8 @@ def _sym_flops(node, in_avals, out_avals):
             kvol = max(1, _aval_elems(in_avals[0]) //
                        max(1, _aval_elems(out_avals[0])))
         return float(out_elems * kvol)
-    if op in ("BatchNorm", "LayerNorm", "InstanceNorm", "L2Normalization"):
+    if op in ("BatchNorm", "LayerNorm", "InstanceNorm", "L2Normalization",
+              "RMSNorm"):
         return 8.0 * out_elems
     if op in ("softmax", "Softmax", "SoftmaxOutput", "SoftmaxActivation",
               "log_softmax"):

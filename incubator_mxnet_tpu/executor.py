@@ -378,8 +378,21 @@ class Executor:
         # over a remote-chip link dominates bind time otherwise
         plan = []   # (host_buffer, device) in creation order
 
+        # a parameter DECLARED float16/bfloat16 (`Variable(dtype=...)`, a
+        # gluon parameter's dtype) is bound in that type where the caller's
+        # type_dict says nothing of it: a language model's token ids cannot
+        # be bound in a low-precision type, so its data input cannot carry
+        # the low-precision lane the way an image batch does
+        declared = {}
+        for node in symbol._topo():
+            low = str(node._extra_attrs.get("__dtype__", "")) \
+                if node.is_variable else ""
+            if low in ("float16", "bfloat16"):
+                declared[node.name] = low
+
         def make(shape, name):
-            dt = np_dtype(type_dict.get(name, _np.float32))
+            dt = np_dtype(type_dict.get(name) or declared.get(name)
+                          or _np.float32)
             dev_ctx = var_group.get(name, ctx)
             plan.append((_np.zeros(shape, dt), dev_ctx.jax_device))
             return dev_ctx

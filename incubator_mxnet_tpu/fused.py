@@ -207,6 +207,18 @@ def reown_for_donation(tree):
 
 
 _REOWN_JIT = None
+# a carry larger than this is re-owned leaf by leaf, in place
+# (`FusedTrainStep._reown_in_place`); up to it, in one program, because a
+# program a leaf costs a carry of many small leaves more than the second
+# copy does (ResNet-50's several hundred leaves on the v5e: 4 s of set-up
+# and 0.1 s of every cold dispatch; PERF.md section 6, PR 30)
+REOWN_IN_PLACE_BYTES = 1 << 30
+
+
+def _tree_nbytes(tree):
+    import jax
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 # NOTE on donation safety (formerly a _AotCall pre-validation wrapper):
@@ -1710,6 +1722,11 @@ class FusedTrainStep:
                         "optimizer-state/aux write-backs (use the public "
                         "Module APIs, which flush first)")
                 self._flushed = True
+            # the holders have what the old carry had (or newer values
+            # from outside): let go of it, so that the cold dispatch does
+            # not hold the last block's masters and momentum beside the
+            # copies it is about to make
+            self._carry = None
             self._place_all()
 
         exec0 = self._exec0
@@ -1753,7 +1770,11 @@ class FusedTrainStep:
             # (checkpoint restore, set_params at epoch boundaries) —
             # donating host-staged buffers into an AOT executable
             # corrupts them; re-own through one XLA copy first
-            ws, ss, auxs = reown_for_donation((ws, ss, auxs))
+            if _tree_nbytes((ws, ss, auxs)) <= REOWN_IN_PLACE_BYTES:
+                ws, ss, auxs = reown_for_donation((ws, ss, auxs))
+            else:
+                del ws, ss, auxs
+                ws, ss, auxs = self._reown_in_place(states)
 
         mcarry = []
         for fn, m in metric_fns:
@@ -2118,6 +2139,43 @@ class FusedTrainStep:
         """Invalidate output views (an unfused forward/step supersedes)."""
         self.last_outputs = None
         self._block_outs = None
+
+    def _reown_in_place(self, states):
+        """The cold dispatch's (ws, ss, auxs), re-owned one leaf at a time,
+        each copy put in its holder's place (the executors' arrays, the
+        optimizer's states) so that the original is released before the
+        next leaf is copied.  `reown_for_donation` of the whole carry holds
+        two copies of every parameter, master and momentum at once; past
+        REOWN_IN_PLACE_BYTES that second copy is what does not fit beside
+        the program.  The holders end up on buffers of equal value that this
+        process's XLA computations own, which is what they hold after any
+        flush."""
+        execs = self._mod._exec_group.execs
+
+        def swap(dicts, name):
+            old = dicts[0][name]._data
+            new = reown_for_donation(old)
+            for d in dicts:
+                if d[name]._data is old:
+                    d[name]._set_data(new)
+            return new
+
+        def swap_state(s):
+            if isinstance(s, NDArray):
+                s._set_data(reown_for_donation(s._data))
+                return s._data
+            if isinstance(s, (tuple, list)):
+                return tuple(swap_state(x) for x in s)
+            return s
+
+        ws = [swap([e.arg_dict for e in execs], n)
+              for n in self._param_names]
+        ss = tuple(swap_state(s) for s in states)
+        auxs = [swap([e.aux_dict for e in execs], n)
+                for n in self._aux_names]
+        if getattr(self, "_seen_ws", None) is not None:
+            self._seen_ws, self._seen_aux = list(ws), list(auxs)
+        return ws, ss, auxs
 
     def _owns_exec_buffers(self):
         """True while the exec dicts still hold the arrays WE last wrote

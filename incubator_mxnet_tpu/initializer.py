@@ -171,6 +171,20 @@ class Normal(Initializer):
 
 
 @register
+class LogUniform(Initializer):
+    """log of uniform(low, high): the A_log of a gated delta-rule mixer,
+    whose decay rate exp(A_log) the family draws uniformly in (0, 16)."""
+
+    def __init__(self, low=1e-3, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, _, arr):
+        self._set(arr, np.log(_random.host_rng().uniform(
+            self.low, self.high, arr.shape)))
+
+
+@register
 class Xavier(Initializer):
     """Reference `initializer.py Xavier` (:728 area)."""
 

@@ -7,11 +7,18 @@ output head) and `lm_symbol`, its `Module.fit`-ready training graph.
 per-layer parameters scanned by one fixed-shape decode-step program
 and per-bucket prefill programs, with the KV cache as a donated carry
 — what `serving/decode.py`'s continuous-batching `DecodeEngine` runs.
+`qwen3_next.py` is the hybrid linear-attention LM of the Qwen3-Next family
+(`Qwen3NextLM`, `qwen3_next_symbol`): gated delta-rule and gated
+grouped-query attention layers in periods, routed experts in every layer;
+training path only.
 """
 from .model import (LMConfig, TransformerBlock, TransformerLM, lm_symbol,
                     lm_block_op_count)
+from .qwen3_next import (Qwen3NextConfig, Qwen3NextLM, Qwen3NextBlock,
+                         qwen3_next_symbol)
 from .decode_core import (DecodePrograms, stack_lm_params, init_kv_cache)
 
 __all__ = ["LMConfig", "TransformerBlock", "TransformerLM", "lm_symbol",
-           "lm_block_op_count", "DecodePrograms", "stack_lm_params",
+           "lm_block_op_count", "Qwen3NextConfig", "Qwen3NextLM",
+           "Qwen3NextBlock", "qwen3_next_symbol", "DecodePrograms", "stack_lm_params",
            "init_kv_cache"]

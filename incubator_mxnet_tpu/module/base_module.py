@@ -827,6 +827,7 @@ class BaseModule:
 
                     arg_params_, aux_params_ = self.get_params()
                     self.set_params(arg_params_, aux_params_)
+                    self._note_op_counters(aux_params_)
 
                     if epoch_end_callback is not None:
                         for callback in _as_list(epoch_end_callback):
@@ -854,6 +855,46 @@ class BaseModule:
                         lambda: self._elastic_snapshot(
                             ckpt_mgr, train_data, epoch + 1, 0, gstep,
                             sync=True, meta={"preempted": True}))
+
+    def _note_op_counters(self, aux_params):
+        """Leave in `mx.obs` what the graph's counting operators have added
+        to their auxiliary states since the last look (`OpDef.counters`
+        says which span, counters and gauges: `RoutedExperts`' routing load
+        is `moe.load`), read from the parameters the epoch's end has just
+        synchronised (no read of its own, none inside the block loop)."""
+        if self.symbol is None:     # a container of modules has no graph
+            return
+        nodes = self.__dict__.get("_counter_nodes")
+        if nodes is None:
+            # the auxiliary states are an op's last inputs
+            nodes = self._counter_nodes = [
+                (n.op, [(slot, var.name) for slot, (var, _) in list(zip(
+                    n.op.list_input_names(n.attrs),
+                    n.inputs))[-n.op.num_aux(n.attrs):]])
+                for n in self.symbol._topo()
+                if not n.is_variable and n.op.counters is not None]
+        seen = self.__dict__.setdefault("_counters_seen", {})
+
+        def since(name):
+            now = aux_params[name].asnumpy().astype(_np.float64)
+            before = seen.get(name, 0.0)
+            seen[name] = now
+            # counters that fell were set anew (`fit(aux_params=...)`)
+            return now if _np.any(now < before) else now - before
+
+        deltas = {}
+        for op, slots in nodes:
+            deltas.setdefault(op, []).append(
+                {slot: since(name) for slot, name in slots})
+        from ..obs import metrics as _obs_metrics
+        for op, per_node in deltas.items():
+            note = op.counters(per_node)
+            with _obs_trace.span(note["span"], cat="train", **note["args"]):
+                pass
+            for key, by in note["counters"].items():
+                _obs_metrics.counter(key).inc(by)
+            for key, value in note["gauges"].items():
+                _obs_metrics.gauge(key).set(value)
 
     def _elastic_snapshot(self, mgr, train_data, epoch, nbatch, step,
                           sync=False, meta=None):

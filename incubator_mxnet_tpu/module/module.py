@@ -107,8 +107,12 @@ class Module(BaseModule):
     @property
     def output_shapes(self):
         assert self.binded
-        outs = self._exec_group.execs[0].outputs
-        return list(zip(self._output_names, [o.shape for o in outs]))
+        # inferred, so that a container can wire the next module to them
+        # before anything has run (the executors have no outputs until then)
+        shapes = {d[0]: tuple(d[1]) for d in
+                  list(self._data_shapes) + list(self._label_shapes or [])}
+        _, out_shapes, _ = self._symbol.infer_shape(**shapes)
+        return list(zip(self._output_names, out_shapes))
 
     # -- params ----------------------------------------------------------------
     def get_params(self):

@@ -18,6 +18,9 @@ from . import init_ops      # noqa: F401
 from . import random_ops    # noqa: F401
 from . import nn            # noqa: F401
 from . import attention     # noqa: F401
+from . import lm_ops        # noqa: F401
+from . import delta_rule    # noqa: F401
+from . import experts       # noqa: F401
 from . import loss_output   # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import linalg_ops    # noqa: F401

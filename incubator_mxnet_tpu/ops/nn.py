@@ -374,6 +374,8 @@ def _activation(params, x):
         return jax.nn.softplus(x)
     if t == "softsign":
         return jax.nn.soft_sign(x)
+    if t == "silu":
+        return jax.nn.silu(x)
     raise MXNetError(f"Activation: unknown act_type {t}")
 
 

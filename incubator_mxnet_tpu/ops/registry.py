@@ -71,13 +71,14 @@ class OpDef:
     __slots__ = ("name", "fn", "nin", "nout", "naux", "params", "param_types",
                  "needs_rng", "mode_dependent", "stop_grad", "aliases",
                  "variadic_param", "dynamic_params", "input_names", "doc",
-                 "cache_key", "cost_meta")
+                 "cache_key", "cost_meta", "scan_remat", "counters")
 
     def __init__(self, name, fn, nin=1, nout=1, naux=0, params=None,
                  param_types=None, needs_rng=False, mode_dependent=False,
                  stop_grad=False, aliases=(), variadic_param=None,
                  dynamic_params=(), input_names=None, doc=None,
-                 cache_key=None, cost_meta=None):
+                 cache_key=None, cost_meta=None, scan_remat=False,
+                 counters=None):
         self.name = name
         self.fn = fn
         self.nin = nin
@@ -115,6 +116,19 @@ class OpDef:
         # the int8-slower-than-fp32 defect's static signature);
         # "quantized" — marks an int8-family op for the dtype-flow pass.
         self.cost_meta = dict(cost_meta) if cost_meta else None
+        # scan_remat: the op's residuals for the backward pass are far
+        # larger than its inputs (a chunked recurrence, routed experts), so
+        # a scanned layer holding it recomputes its activations in the
+        # backward pass instead of stacking them (`symbol.graph_eval_fn`)
+        self.scan_remat = bool(scan_remat)
+        # counters: the op's auxiliary states are counters it adds to in
+        # every training step.  `counters(deltas)`, `deltas` one {aux slot
+        # name: what was added since the last look (numpy)} per node of
+        # this op in a graph, gives {"span": name, "args": {...},
+        # "counters": {name: increment}, "gauges": {name: value}}, which
+        # `BaseModule.fit` leaves in `mx.obs` where it synchronises the
+        # parameters anyway, at the epoch's end
+        self.counters = counters
 
     # -- parameter handling ---------------------------------------------------
     def canonicalize_params(self, kwargs):

@@ -15,6 +15,7 @@ and reduction trees, training steps are jit-compiled SPMD programs over a
   with ppermute'd KV shards (long-context support beyond the reference's
   bucketing strategy)
 * `pipeline.py` — pipeline-parallel microbatch schedule over `pp`
+* `expert_parallel.py` — which experts of a routed layer a chip holds
 """
 from .mesh import make_mesh, mesh_axes, local_mesh, rebuild
 from .gluon_bridge import (shard_block, block_shardings,
@@ -23,6 +24,7 @@ from .collectives import (all_reduce, all_gather, reduce_scatter, ppermute,
                           broadcast, supervised)
 from .data_parallel import data_parallel_step, replicate, unreplicate
 from .tensor_parallel import shard_params, ShardingRules
+from .expert_parallel import ExpertShare
 from .ring_attention import ring_attention, blockwise_attention
 from .pipeline import pipeline_step, pipeline_train_step
 from .zero import zero_train_step, zero_update, zero_init_state

@@ -21,7 +21,7 @@ import threading
 
 import numpy as _np
 
-from ..base import MXNetError
+from ..base import MXNetError, py_literal
 from ..ops import registry as _reg
 
 __all__ = ["Symbol", "Variable", "var", "Group", "load", "load_json",
@@ -591,6 +591,11 @@ def load_json(json_str):
         attrs = {k: v for k, v in jn.get("attrs", jn.get("param", {})).items()}
         if jn["op"] == "null":
             node = _Node(None, jn["name"], {}, [])
+            if "__shape__" in attrs:
+                # JSON carries attrs as strings; every reader of a
+                # variable's shape (inference, the graph passes) takes a
+                # tuple, as `Variable(shape=...)` stores it
+                attrs["__shape__"] = tuple(py_literal(attrs["__shape__"]))
             node._extra_attrs.update(attrs)
         else:
             op = _reg.get(jn["op"])
@@ -625,7 +630,9 @@ def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
     arguments, aux states and checkpoints keep their per-layer layout.
     A run whose per-layer shapes turn out unequal at trace time (or
     whose carry changes shape) silently falls back to the inlined path —
-    the plan is structural, shapes are only known here."""
+    the plan is structural, shapes are only known here.  A scanned body
+    that holds an operator registered with `scan_remat` recomputes its
+    activations in the backward pass instead of stacking them."""
     import jax
     import jax.numpy as jnp
 
@@ -780,6 +787,12 @@ def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
             if tuple(c_aval.shape) != tuple(c0.shape) or \
                     c_aval.dtype != c0.dtype:
                 return False   # shape-changing block: scan carry invalid
+            if any(n.op.scan_remat for n in template):
+                # an operator of this layer keeps far more for its backward
+                # pass than it takes in (`OpDef.scan_remat`): stacked over
+                # the layers that would not fit, so the backward pass
+                # computes the layer's activations again from its carry
+                body = jax.checkpoint(body)
             carry_out, ys = jax.lax.scan(body, c0, xs)
             env[id(run["boundary"])] = (carry_out,)
             for slot, layer_nodes in enumerate(run["aux"]):

@@ -179,3 +179,25 @@ def test_python_loss_module():
     m.backward()
     g = m.get_input_grads()[0].asnumpy()
     np.testing.assert_allclose(g, grad_func(scores, labels), rtol=1e-6)
+
+
+def test_sequential_module_fit():
+    """A container of modules has no graph of its own (`symbol` is None):
+    `fit` runs through its epoch ends all the same, and trains."""
+    rng = np.random.RandomState(0)
+    x = rng.rand(64, 10).astype("f4")
+    y = (x[:, :4].argmax(axis=1)).astype("f4")
+    body = sym.Activation(sym.FullyConnected(
+        sym.Variable("data"), num_hidden=16, name="fc1"), act_type="relu")
+    head = sym.SoftmaxOutput(sym.FullyConnected(
+        sym.Variable("data"), num_hidden=4, name="fc2"), name="softmax")
+    seq = mx.mod.SequentialModule()
+    seq.add(mx.mod.Module(body, label_names=None, context=mx.cpu()))
+    seq.add(mx.mod.Module(head, context=mx.cpu()), take_labels=True,
+            auto_wiring=True)
+    assert seq.symbol is None
+    seq.fit(NDArrayIter(x, y, batch_size=8), num_epoch=3, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.5},
+            initializer=mx.init.Xavier(), eval_metric="acc")
+    (name, acc), = seq.score(NDArrayIter(x, y, batch_size=8), "acc")
+    assert acc > 0.5, acc

@@ -52,8 +52,14 @@ def _symbol():
     return mx.sym.SoftmaxOutput(f, name="softmax")
 
 
-def _fit(blocks=2, batch=8, side=8, source_sleep=0.0, callback=None):
-    """One `Module.fit` of `blocks` K-step blocks over a host iterator."""
+def _fit(blocks=2, batch=8, side=8, source_sleep=0.0, callback=None,
+         one_epoch=False):
+    """One `Module.fit` of `blocks` K-step blocks over a host iterator.
+    `one_epoch`: the iterator has this epoch and no other.  The reset at the
+    epoch's end starts the feeder reading ahead into the next epoch, and
+    how many of ITS batches get staged before `fit` returns and closes the
+    ring is a race (none on an idle machine, one to three under six test
+    workers); with no next epoch every count is of this epoch alone."""
     rng = np.random.default_rng(0)
     n = blocks * K * batch
     x = rng.random((n, 3, side, side), dtype=np.float32)
@@ -66,6 +72,8 @@ def _fit(blocks=2, batch=8, side=8, source_sleep=0.0, callback=None):
             time.sleep(source_sleep)
             return plain_next()
         it.next = slow_next
+    if one_epoch:
+        it.reset = lambda: None         # exhausted once, exhausted for good
     mod = mx.mod.Module(_symbol(), context=mx.cpu())
     mod.fit(it, num_epoch=1, optimizer="sgd",
             optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
@@ -236,14 +244,32 @@ def _overlap(a, b):
                                                              b["ts"])
 
 
+def _stage_timing_disagrees(spans, io0, io1):
+    """None where each stage counter timed the same interval as its span
+    and h2d_s is stage + put (and the adoption check), else what did not."""
+    for span_name, key in (("io.source", "source_s"),
+                           ("io.stage", "stage_s"), ("io.h2d", "put_s")):
+        durs = [s["dur"] for s in spans if s["name"] == span_name]
+        if sum(durs) / 1e6 != pytest.approx(
+                io1[key] - io0[key], rel=0.10, abs=30e-6 * len(durs)):
+            return f"{key}: spans {sum(durs) / 1e6} s, counter " \
+                   f"{io1[key] - io0[key]} s"
+    h2d = io1["h2d_s"] - io0["h2d_s"]
+    parts = io1["stage_s"] - io0["stage_s"] + io1["put_s"] - io0["put_s"]
+    if not parts <= h2d <= parts * 1.5 + 1e-3:
+        return f"h2d_s {h2d} s against stage + put {parts} s"
+    return None
+
+
 def test_fit_spans_and_stage_counters():
     obs_trace.enable()
     calls = []
     io0 = io_plane.stats()
     mod = _fit(blocks=3, batch=32, side=32, source_sleep=0.002,
-               callback=lambda param: calls.append(param.nbatch))
+               callback=lambda param: calls.append(param.nbatch),
+               one_epoch=True)
     io1 = io_plane.stats()
-    spans = obs_trace.buffered()
+    spans = list(obs_trace.buffered())
     assert calls == list(range(3 * K))
 
     # the feeder: three sibling leaf spans a batch, one after another
@@ -257,22 +283,29 @@ def test_fit_spans_and_stage_counters():
     assert len(_by_name(spans, "io.stage")) == batches
     assert len(_by_name(spans, "io.h2d")) == batches
     # more pulls than batches: the one that found the source dry (and
-    # the read-ahead of the epoch the reset at the epoch's end began)
+    # the one the reset at the epoch's end set off, dry as well)
     assert len(_by_name(spans, "io.source")) >= batches + 1
     stage = _by_name(spans, "io.stage")[0]
     assert stage["args"]["bytes"] == 32 * 3 * 32 * 32 * 4 + 32 * 4
     assert stage["args"]["copies"] == 2
-    # each counter times the same interval as its span
-    for span_name, key in (("io.source", "source_s"),
-                           ("io.stage", "stage_s"), ("io.h2d", "put_s")):
-        durs = [s["dur"] for s in spans if s["name"] == span_name]
-        assert sum(durs) / 1e6 == pytest.approx(
-            io1[key] - io0[key], rel=0.10, abs=30e-6 * len(durs)), key
+    # each counter times the same interval as its span, and h2d_s stays
+    # what it was: stage + put (and the adoption check).  A counter's two
+    # clock reads stand AROUND its span's, a few microseconds apart: under
+    # six test workers the feeder thread can lose the interpreter between
+    # them for longer than the whole tolerance.  The tolerance stays as it
+    # is; a run that fails it is measured again (a misplaced counter fails
+    # every time, a descheduled thread does not)
+    why = _stage_timing_disagrees(spans, io0, io1)
+    for _ in range(3):
+        if why is None:
+            break
+        obs_trace.reset()
+        again0 = io_plane.stats()
+        _fit(blocks=3, batch=32, side=32, source_sleep=0.002, one_epoch=True)
+        why = _stage_timing_disagrees(obs_trace.buffered(), again0,
+                                      io_plane.stats())
+    assert why is None, why
     assert io1["source_s"] - io0["source_s"] >= 0.002 * batches
-    # h2d_s stays what it was: stage + put (and the adoption check)
-    h2d = io1["h2d_s"] - io0["h2d_s"]
-    parts = io1["stage_s"] - io0["stage_s"] + io1["put_s"] - io0["put_s"]
-    assert parts <= h2d <= parts * 1.5 + 1e-3
 
     # the fit loop: work between two blocks, and the epoch's end
     main = threading.current_thread().name
