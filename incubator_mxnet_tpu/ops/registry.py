@@ -117,9 +117,11 @@ class OpDef:
         # "quantized" — marks an int8-family op for the dtype-flow pass.
         self.cost_meta = dict(cost_meta) if cost_meta else None
         # scan_remat: the op's residuals for the backward pass are far
-        # larger than its inputs (a chunked recurrence, routed experts), so
-        # a scanned layer holding it recomputes its activations in the
-        # backward pass instead of stacking them (`symbol.graph_eval_fn`)
+        # larger than its inputs (routed experts; a chunked recurrence,
+        # whose written backward pass still keeps a float32 state per
+        # chunk), so a scanned layer holding it recomputes its activations
+        # in the backward pass instead of stacking them
+        # (`symbol.graph_eval_fn`)
         self.scan_remat = bool(scan_remat)
         # counters: the op's auxiliary states are counters it adds to in
         # every training step.  `counters(deltas)`, `deltas` one {aux slot

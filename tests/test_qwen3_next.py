@@ -154,33 +154,89 @@ def test_attention_without_kv_heads_is_what_it_was():
 
 # -- the gated delta rule -------------------------------------------------------
 
-@pytest.mark.parametrize("chunk,length,group_bytes", [
-    (8, 37, None), (16, 37, None), (64, 50, None), (16, 32, None),
-    (8, 37, 1), (16, 32, 2 * 2 * 16 * 128 * 4 * 2)])
-def test_chunked_delta_rule_against_the_recurrence(chunk, length,
-                                                   group_bytes, monkeypatch):
-    """The chunked scan against the token-by-token recurrence, at two chunk
-    sizes and a length that is no multiple of either; decay rates from
-    nearly none to exp(-20) a token; the heads all at once, one at a time
-    and two at a time."""
-    if group_bytes is not None:
-        from incubator_mxnet_tpu.ops import delta_rule
-        monkeypatch.setattr(delta_rule, "GROUP_BYTES", group_bytes)
-    b, hk, hv, dk, dv = 2, 2, 4, 8, 6
+def _delta_inputs(b, length, hk, hv, dk, dv):
+    """Decay rates from nearly none to exp(-20) a token."""
     q, k = _rand(15, b, length, hk * dk), _rand(16, b, length, hk * dk)
     v = _rand(17, b, length, hv * dv)
-    a_log = jnp.log(jnp.asarray([0.01, 1.0, 6.0, 15.0], jnp.float32))
+    a_log = jnp.log(jnp.asarray(([0.01, 1.0, 6.0, 15.0] * hv)[:hv],
+                                jnp.float32))
     g = -jnp.exp(a_log) * jax.nn.softplus(_rand(18, b, length, hv) + 1.0)
     beta = jax.nn.sigmoid(_rand(19, b, length, hv))
+    return q, k, v, g, beta
 
+
+def _delta_recurrence(b, length, hk, hv, dk, dv):
     def ref(q, k, v, g, beta):
         qh = REF._l2(q.reshape(b, length, hk, dk)) * dk ** -0.5
         kh = REF._l2(k.reshape(b, length, hk, dk))
         qh, kh = (jnp.repeat(x, hv // hk, axis=2) for x in (qh, kh))
         o = REF.delta_rule(qh, kh, v.reshape(b, length, hv, dv), g, beta)
         return o.reshape(b, length, hv * dv)
-    _same_with_grads(_op("GatedDeltaRule", num_heads=hk, num_v_heads=hv,
-                         chunk_size=chunk), ref, (q, k, v, g, beta))
+    return ref
+
+
+@pytest.mark.parametrize("chunk,length,batch", [
+    (8, 37, 2), (16, 37, 2), (64, 50, 2), (16, 32, 2), (8, 64, 1),
+    (24, 100, 1)])
+def test_chunked_delta_rule_against_the_recurrence(chunk, length, batch):
+    """The scan driver (what the CPU takes) against the token-by-token
+    recurrence through the operator's custom VJP, values and all five
+    gradients: four chunk sizes, lengths that are multiples of the chunk
+    and lengths that are not, a chunk that is no power of two."""
+    hk, hv, dk, dv = 2, 4, 8, 6
+    _same_with_grads(
+        _op("GatedDeltaRule", num_heads=hk, num_v_heads=hv, chunk_size=chunk),
+        _delta_recurrence(batch, length, hk, hv, dk, dv),
+        _delta_inputs(batch, length, hk, hv, dk, dv))
+
+
+@pytest.mark.parametrize("batch,length", [(1, 128), (2, 200)])
+def test_delta_rule_kernel_interpreted_against_the_recurrence(batch, length):
+    """The KERNEL, interpreted, at shapes it tiles (key and value size 128,
+    chunks of 64, 2 key and 4 value heads) against the same recurrence:
+    one block of two chunks a grid step and two, without padding and with
+    it (200 positions are padded to 256)."""
+    from incubator_mxnet_tpu.ops import delta_rule
+    hk, hv, dk, dv = 2, 4, 128, 128
+
+    def kernel(q, k, v, g, beta):
+        o = delta_rule.gated_delta_rule(
+            q.reshape(batch, length, hk, dk), k.reshape(batch, length, hk, dk),
+            v.reshape(batch, length, hv, dv), g, beta, interpret=True)
+        return o.reshape(batch, length, hv * dv)
+    _same_with_grads(kernel, _delta_recurrence(batch, length, hk, hv, dk, dv),
+                     _delta_inputs(batch, length, hk, hv, dk, dv))
+
+
+def test_delta_rule_driver_follows_backend_and_shape(monkeypatch):
+    """The compiled kernel on ``tpu`` where the shapes tile, the scan
+    anywhere else; each traced call counts the driver it took."""
+    from incubator_mxnet_tpu.ops import delta_rule
+    assert delta_rule._driver(128, 128, 64, False) == "scan"      # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert delta_rule._driver(128, 128, 64, False) == "kernel"
+    assert delta_rule._driver(128, 256, 8, False) == "kernel"
+    assert delta_rule._driver(8, 128, 64, False) == "scan"        # key size
+    assert delta_rule._driver(128, 6, 64, False) == "scan"        # value size
+    assert delta_rule._driver(128, 128, 12, False) == "scan"      # chunk
+    monkeypatch.undo()
+    with pytest.raises(mx.MXNetError, match="tiles"):
+        delta_rule._driver(8, 128, 64, True)
+
+    def counts():
+        return tuple(mx.obs.counter("ops.delta_rule.lowered." + d).value
+                     for d in ("kernel", "scan"))
+    hk, hv, dk, dv = 1, 2, 8, 128
+    args = _delta_inputs(1, 16, hk, hv, dk, dv)
+    before = counts()
+    jax.jit(_op("GatedDeltaRule", num_heads=hk, num_v_heads=hv,
+                chunk_size=8)).lower(*args)
+    assert counts() == (before[0], before[1] + 1)
+    q, k, v, g, beta = _delta_inputs(1, 16, 1, 1, 128, 128)
+    delta_rule.gated_delta_rule(
+        q.reshape(1, 16, 1, 128), k.reshape(1, 16, 1, 128),
+        v.reshape(1, 16, 1, 128), g, beta, chunk_size=8, interpret=True)
+    assert counts() == (before[0] + 1, before[1] + 1)
 
 
 # -- routed experts ---------------------------------------------------------------
