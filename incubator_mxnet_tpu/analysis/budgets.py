@@ -154,7 +154,7 @@ def check_measured(measured, budgets):
             report.add(Finding(
                 "cost.budget", "budget-missing", HINT,
                 "program '%s' has no measured baseline entry — snapshot "
-                "it (run_tpu_parity coldstart stage --write-budgets) so "
+                "it (tools/warmup.py --measure-budgets --write-budgets) so "
                 "cold-start regressions become CI failures" % name,
                 location=name))
             continue

@@ -1,10 +1,10 @@
 """mxcost — static graph cost & communication analysis.
 
-The runtime only reveals cost problems after the fact: BENCH_OPS showed
-the int8 convnet 1.8x *slower* than fp32 (on CPU), a blocking h2d copy
+The runtime only reveals cost problems after the fact: an int8 graph
+that dequantizes before its dot computes in fp32, a blocking h2d copy
 caps the input path, and the pod fast path's whole value is its
-O(buckets) collective economy — yet none of those numbers could be
-predicted (or guarded) before a run.  mxcost is the predictive half: it
+O(buckets) collective economy — yet none of those could be predicted
+(or guarded) before a run.  mxcost is the predictive half: it
 walks Symbol graphs and traced jaxprs and derives, per program,
 
 * **per-op FLOPs / bytes-moved / arithmetic intensity** with a roofline
@@ -34,6 +34,7 @@ into hard CI failures on regression (new collectives, +bytes/step,
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as _np
@@ -927,17 +928,29 @@ def collectives_report(stats, target=None):
 
 
 # ---------------------------------------------------------------------------
-# the canonical bench program set (shared with tools/bench_ops.py and
-# the mxlint --cost-report default)
+# the canonical bench program set (the mxlint --cost-report default and
+# the COST_BUDGETS.json baseline)
 # ---------------------------------------------------------------------------
 
 BENCH_SHAPE = (8, 3, 32, 32)
 
 
+def _fresh_names(build):
+    """Compose `build`'s graph under auto-name counts that start at
+    zero, so that a finding names the same nodes in any process."""
+    @functools.wraps(build)
+    def composed(*args, **kwargs):
+        from ..symbol.symbol import _NameManager
+        with _NameManager.fresh():
+            return build(*args, **kwargs)
+    return composed
+
+
+@_fresh_names
 def build_bench_convnet(dtype="float32"):
-    """The BENCH_OPS quantization-battery convnet (conv3x3/16 + relu +
-    maxpool + flatten + fc32), with every variable declared at `dtype`
-    so the bf16 variant is bf16 end to end.  Returns (symbol, shapes)."""
+    """The quantization-battery convnet (conv3x3/16 + relu + maxpool +
+    flatten + fc32), with every variable declared at `dtype` so the
+    bf16 variant is bf16 end to end.  Returns (symbol, shapes)."""
     from .. import sym as S
     kw = {} if dtype == "float32" else {"dtype": dtype}
     # weight shapes are declared on the variables: a declared non-f32
@@ -964,9 +977,10 @@ def build_bench_convnet(dtype="float32"):
     return out, {"data": BENCH_SHAPE}
 
 
+@_fresh_names
 def build_bench_quantized_convnet():
-    """quantize_model over the fp32 bench convnet — THE int8 graph
-    BENCH_OPS times (same rewrite, same rng seed for the weights).
+    """quantize_model over the fp32 bench convnet: the int8 graph of
+    the budget baseline (fixed rng seed for the weights).
     Returns (qsym, shapes, dtypes) where dtypes carries the int8 weight
     dtypes the variable attrs cannot."""
     import numpy as np
@@ -988,7 +1002,8 @@ def build_bench_quantized_convnet():
 
 def bench_programs():
     """{name: (symbol, shapes, dtypes)} — the program set the budget
-    baseline covers.  Names match the BENCH_OPS artifact keys."""
+    baseline covers.  Names match the COST_BUDGETS.json keys.  Both
+    builders compose under fresh name counts."""
     fp32, shapes = build_bench_convnet("float32")
     bf16, _ = build_bench_convnet("bfloat16")
     qsym, qshapes, qdtypes = build_bench_quantized_convnet()
@@ -1003,8 +1018,7 @@ def analyze_bench_set(profile=None, dp=8, cap_bytes=None):
     """Analyze the canonical bench set + the dp-way collective plan for
     its fp32 params: {name: ProgramCost}, plus the plan stats under the
     key ``__collectives__``.  This is what the mxlint --cost-report
-    default run, the parity `cost` stage, and the budget baseline all
-    share."""
+    default run and the budget baseline share."""
     out = {}
     for name, (sym, shapes, dtypes) in sorted(bench_programs().items()):
         out[name] = analyze_symbol(sym, shapes=shapes, dtypes=dtypes,

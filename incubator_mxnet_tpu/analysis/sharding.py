@@ -43,8 +43,8 @@ plan).  mxshard closes that gap statically, before anything compiles:
   ``2*(n-1)/n * bytes`` per chip, all-gather ``(n-1)/n * bytes``).
 
 Surfaced via `tools/mxlint.py --shard-report` (budget-gated against
-COST_BUDGETS.json's ``sharding`` section) and the `run_tpu_parity`
-sharding stage.  Findings are plain `analysis.findings` currency; every
+COST_BUDGETS.json's ``sharding`` section; tests/test_sharding.py).
+Findings are plain `analysis.findings` currency; every
 code registers in CODE_TABLE.
 """
 from __future__ import annotations

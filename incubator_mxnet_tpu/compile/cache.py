@@ -450,7 +450,7 @@ class ProgramCache:
 
     def stats(self):
         """One dict: global counters + per-program signature/compile
-        breakdown (the mxlint cache-report's and bench's currency).
+        breakdown (the mxlint cache-report's and benchmark/'s currency).
         Memory-hit counts live on the programs (the warm dispatch path
         is lock-free) and are aggregated here."""
         with self._lock:

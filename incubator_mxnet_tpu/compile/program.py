@@ -196,7 +196,7 @@ class CachedProgram:
         self.compile_count += 1
         # phase-split timing: lower (trace -> StableHLO) vs the XLA
         # compile proper — the cold-start debt mxtop's CACHE line and
-        # bench's compile_phases block report per program
+        # benchmark/'s program_build_s report per program
         t0 = _time.perf_counter()
         with _obs_trace.span("compile.lower", cat="compile",
                              label=self.label):

@@ -25,8 +25,8 @@ parameter shapes produce the identical executable the production
 weights will hit.
 
 `warm` is what `tools/warmup.py` wraps; `selftest` is the tiny built-in
-model both the parity runner's cold-start stage and bench.py use to
-measure cold-vs-warm compile time.
+model `tools/warmup.py --selftest` and tests/test_program_cache.py build
+cold, then warm.
 """
 from __future__ import annotations
 

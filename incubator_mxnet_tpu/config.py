@@ -164,8 +164,7 @@ KNOBS = {
                               "async per-bucket dispatch on the "
                               "collective kvstore (bucket k's all-reduce "
                               "executes while bucket k+1 assembles); 0 "
-                              "blocks after each bucket — the A/B lever "
-                              "tools/run_scaling.py benches"),
+                              "blocks after each bucket"),
     "MXNET_MESH": (str, "", "honored",
                    "composed device-mesh spec for the fused train step, "
                    "e.g. 'dp=8' or 'dp=4,tp=2' (axis sizes multiply to "
@@ -473,8 +472,7 @@ KNOBS = {
     "MXNET_TSAN_LOG": (str, "", "honored",
                        "write the sanitizer's findings + lock-order "
                        "graph as one JSON artifact at process exit "
-                       "(rendered by tools/mxlint.py --tsan-report; "
-                       "the run_tpu_parity tsan stage gates on it)"),
+                       "(rendered by tools/mxlint.py --tsan-report)"),
     "MXNET_TSAN_RAISE": (_BOOL, False, "honored",
                          "escalate a NEW lock-order deadlock cycle to "
                          "an MXNetError at the acquisition site instead "
@@ -564,10 +562,7 @@ KNOBS = {
     "MXNET_EMBED_HBM_BUDGET_MB": (int, 64, "honored",
                                   "modeled single-device HBM budget for "
                                   "the embedding tier: ShardedEmbedding "
-                                  "refuses to densify a table over it, "
-                                  "and run_embed_bench certifies a "
-                                  "table >= 4x this budget trains and "
-                                  "serves sharded"),
+                                  "refuses to densify a table over it"),
     "MXNET_EMBED_PULL_CHUNK": (int, 65536, "honored",
                                "rows per embed_pull request when "
                                "streaming a whole shard back (checkpoint "

@@ -208,8 +208,7 @@ class KVStoreDist(KVStoreTPU):
 
     def stats(self):
         """PR 5 retry/failover counters, one dict — exported through
-        `JobSupervisor.stats()` into the chaos / run_tpu_parity
-        artifacts: per-channel idempotent resends, stale replies
+        `JobSupervisor.stats()` into the chaos artifacts: per-channel idempotent resends, stale replies
         discarded by sequence number, and every per-server breaker's
         state."""
         return {

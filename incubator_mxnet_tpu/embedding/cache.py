@@ -12,7 +12,8 @@ Program-cache discipline: the gather and the scatter are TWO
 buffer dies the moment the new one exists — no 2x cache HBM spike), and
 both pad their id axis to the next power of two so the signature set is
 O(log capacity) and the steady state (fixed batch, all hits) replays one
-executable with ZERO recompiles — the run_embed_bench gate.
+executable with ZERO recompiles
+(tests/test_embedding.py::test_cache_steady_state_has_zero_recompiles).
 """
 from __future__ import annotations
 

@@ -989,8 +989,8 @@ class FusedTrainStep:
         # barriers per step amplify per-partition skew.  The pod path
         # exchanges ALL gradients in O(buckets) collectives
         # (MXNET_KVSTORE_BUCKET_MB caps a bucket — the same knob and
-        # planning rule as the kvstore scheduler), which benches ~1.2x
-        # faster per step on the 8-way mesh.  Semantics: the psum of
+        # planning rule as the kvstore scheduler; its speed on a real
+        # mesh is not measured).  Semantics: the psum of
         # per-shard gradients is exactly the reference kvstore's
         # cross-device SUM (comm.h Reduce), so sum-normalized graphs
         # (normalization='null') match the global-view program bit-for-
@@ -2096,8 +2096,8 @@ class FusedTrainStep:
         trace seconds, the traced jaxpr's (recursive) equation count —
         the graph-size number the XLA compile scales with, ONE layer
         body per scan-deduped run — and per-program lower/compile
-        seconds from the unified cache (bench's `compile_phases`
-        artifact block reads this)."""
+        seconds from the unified cache (tools/warmup.py
+        --measure-budgets reads this)."""
         core = getattr(self, "_core_closed", None)
         out = {
             "trace_s": getattr(core, "trace_s", None)

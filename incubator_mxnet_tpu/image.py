@@ -515,8 +515,8 @@ class ImageRecordIterImpl(DataIter):
         # compose the model with `self.normalize_symbol(data)` (the
         # ImageNormalize op), which XLA fuses into the first conv.
         # 'auto'/None-as-string resolves from MXNET_IO_UINT8_WIRE — the
-        # production data-plane default (bench io lane, run_io_bench);
-        # an explicit True/False always wins.
+        # production data-plane default; an explicit True/False always
+        # wins.
         if isinstance(device_augment, str) and \
                 device_augment.lower() in ("auto", "none"):
             from . import config as _config

@@ -98,7 +98,7 @@ def auto_shard(part_index=None, num_parts=None):
 _rings = weakref.WeakSet()      # live rings (occupancy/depth at scrape)
 _registered = False
 # process-lifetime totals: a ring's counts must survive the ring (fit
-# wrappers are released when fit returns; the bench io lane reads
+# wrappers are released when fit returns; benchmark/ reads
 # before/after deltas of these)
 _TOTALS = {"stalls": 0, "stall_s": 0.0, "batches": 0, "bytes": 0,
            "h2d_s": 0.0, "source_s": 0.0, "stage_s": 0.0, "put_s": 0.0,

@@ -35,14 +35,14 @@ from . import optimizer as opt
 
 __all__ = ["KVStore", "create", "live_stats", "findings"]
 
-# live collective stores (weak): analysis.runtime_report() and the bench/
-# scaling tools read their stats() without holding the stores alive
+# live collective stores (weak): analysis.runtime_report() reads
+# their stats() without holding the stores alive
 _LIVE_STORES = weakref.WeakSet()
 
 
 def live_stats():
-    """stats() of every live collective (tpu/device) store — the
-    scaling-bench artifact's and runtime_report's read path."""
+    """stats() of every live collective (tpu/device) store —
+    runtime_report's read path."""
     out = []
     for kv in list(_LIVE_STORES):
         try:
@@ -428,8 +428,8 @@ class KVStoreTPU(KVStore):
     def stats(self):
         """Communication-economy counters of this store: allreduce
         dispatches, bytes reduced, bucket count/fill, overlap ratio —
-        surfaced through `analysis.runtime_report()` and stamped into
-        BENCH_SCALING.json by tools/run_scaling.py."""
+        surfaced through `analysis.runtime_report()` and held to the
+        static plan byte for byte in tests/test_scaling.py."""
         self._release_guard()
         c = self._counters
         return {

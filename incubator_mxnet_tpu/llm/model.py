@@ -31,7 +31,7 @@ from ..gluon.block import HybridBlock
 
 @dataclass
 class LMConfig:
-    """Static LM shape shared by training, serving and the bench."""
+    """Static LM shape shared by training and serving."""
     vocab_size: int = 256
     num_layers: int = 2
     num_heads: int = 2

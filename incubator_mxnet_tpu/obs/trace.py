@@ -76,10 +76,7 @@ _flusher = [None]
 # observability of the observability: nanoseconds the background
 # flusher spent serializing + writing spans (the increment races are
 # benign — it is a counter).  Exposed as 'trace.self_time_ms' in the
-# metrics scrape; the obs CI gate pairs it with a single-threaded
-# calibration of the per-span hook cost (`calibrate_span_cost`) —
-# in-hook wall timing under thread contention would count GIL waits
-# as telemetry cost.
+# metrics scrape.
 _self_ns = [0]
 # pid-prefixed ids: unique across processes with zero coordination (the
 # pid is cached — a syscall per span id would tax the hot path — and
@@ -171,48 +168,6 @@ def stats():
 def self_time_ns():
     """Nanoseconds the flusher spent serializing + writing spans."""
     return _self_ns[0]
-
-
-def calibrate_span_cost(n=8192, scratch=None):
-    """Measured ALL-IN cost of one span in seconds — open + close +
-    buffering + its share of serialization and write IO — from a
-    single-threaded loop in this process (no thread preemption to
-    inflate the numbers).  The obs CI gate multiplies this by the
-    spans-per-request observed in the traced run to compute the
-    hot-path overhead ratio deterministically; requires tracing to be
-    enabled with a file.
-
-    The synthetic spans land in a SCRATCH file (a throwaway temp file
-    unless `scratch` names one), never the run's shared span file —
-    merged traces and their orphan/span-count gates must see only real
-    workload spans."""
-    global _path
-    if not enabled() or _path is None:
-        return None
-    flush()
-    if scratch is None:
-        import tempfile
-        fd, scratch = tempfile.mkstemp(prefix="mxobs_cal_",
-                                       suffix=".jsonl")
-        os.close(fd)
-    saved, _path = _path, str(scratch)
-    try:
-        t0 = time.perf_counter_ns()
-        done = 0
-        while done < n:
-            # emit in sub-threshold batches then flush synchronously,
-            # so the background flusher never interleaves the timing
-            for i in range(256):
-                sp = start_span("calibrate.span", cat="calibrate",
-                                rid=f"c-{done + i}",
-                                priority="interactive")
-                sp.end(outcome="ok")
-            flush()
-            done += 256
-        return (time.perf_counter_ns() - t0) / done / 1e9
-    finally:
-        flush()
-        _path = saved
 
 
 def _as_dict(rec):

@@ -120,8 +120,8 @@ def _blockwise_attention(params, q, k, v):
 def naive_attention(q, k, v, num_heads, causal=True):
     """Reference O(T^2)-memory attention on (B, T, C) packed inputs —
     materializes the full score matrix.  The parity oracle for
-    `BlockwiseAttention` (tests/test_ring_attention.py) and the naive
-    lane of the bench_ops attention battery; not a registered op."""
+    `BlockwiseAttention` (tests/test_ring_attention.py); not a
+    registered op."""
     b, t, c = q.shape
     d = c // num_heads
     qh = q.reshape(b, t, num_heads, d).transpose(0, 2, 1, 3)

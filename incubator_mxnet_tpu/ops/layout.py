@@ -14,8 +14,9 @@ same idea — a graph-level rewrite used by the executor
 * transposes back to the API's NCHW at every other consumer and at graph
   heads, so results are bit-identical module the usual float reassociation.
 
-Measured on one v5e chip (ResNet-50 train, batch 128, bf16,
-same-process A/B, tools/perf_decomp.py): a hand-written NHWC control is
+A same-process A/B on one v5e chip before the benchmark existed
+(ResNet-50 train, batch 128, bf16; no ledger line, so a reason for the
+default and not a speed statement): a hand-written NHWC control is
 only ~0.5-3% faster than the NCHW control (XLA's layout assignment
 already tiles NCHW convolutions onto the MXU well), and the framework
 graph is ~3% SLOWER in NHWC because the per-step OIHW->HWIO weight

@@ -21,7 +21,7 @@ from .registry import register, REQUIRED
 # float32 on this design (see the _quantized_conv docstring), so each
 # declares ``compute_dtype="float32"`` — which is exactly the static
 # signature mxcost's dtype-flow pass flags as the int8-slower-than-fp32
-# defect (BENCH_OPS: int8 convnet 1.8x slower).  When the lowering
+# defect.  When the lowering
 # moves to native XLA int8 dot/conv with fused epilogues (ROADMAP open
 # item 4), these declarations change to "int8" and the findings — and
 # the CI budget gate holding their count — retire with the defect.

@@ -22,7 +22,8 @@ Three layers:
   does NOT block on them; `maybe_poll` materializes the accumulated
   tokens every ``MXNET_GUARDIAN_INTERVAL`` steps (one gather), so
   steady-state overhead is a fused reduction per step and one small
-  device->host read per interval (<2%, gated in bench.py).  An unforced
+  device->host read per interval (`guardian_device_ms` and
+  `guardian_poll_wait_ms` in benchmark/ read them).  An unforced
   poll LAGS by one dispatch: it never gathers the health word of the
   newest dispatch (the block, or the single step, the device is still
   running), so the fit loop waits for work the device has finished or

@@ -568,7 +568,7 @@ class JobSupervisor:
     def stats(self):
         """One dict of everything the supervisor (and the attached dist
         kvstore's PR 5 retry/breaker machinery) counted — exported into
-        the `run_tpu_parity` / chaos artifacts."""
+        the chaos artifacts."""
         v = self.view() or {}
         out = {
             "rank": self.rank,

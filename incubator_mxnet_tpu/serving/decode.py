@@ -4,9 +4,8 @@ The stateless serving plane (`ServedModel` + `MicroBatcher`) answers a
 request with one program dispatch.  An LM request is different: it
 holds STATE (its KV cache) across hundreds of dispatches.  Waiting for
 a full batch and decoding it in lockstep ("static batching") leaves
-every finished-early slot idle until the longest sequence completes —
-the aggregate-tokens/s gap `tools/run_lm_bench.py` measures.  This
-engine decodes continuously instead:
+every finished-early slot idle until the longest sequence completes.
+This engine decodes continuously instead:
 
 * a fixed pool of **slots** (rows of the fixed-shape KV cache);
 * every tick runs ONE decode-step program advancing all occupied

@@ -208,7 +208,7 @@ class FleetHost:
 
 class InProcessHost(FleetHost):
     """A logical host inside this process: ``spawn`` is a caller-supplied
-    factory (tests and the bench hand it a `LocalReplica` builder), and
+    factory (tests hand it a `LocalReplica` builder), and
     liveness is a flag tests flip.  The autoscaler/placement logic is
     identical to the cross-host path — only the actuation is local."""
 

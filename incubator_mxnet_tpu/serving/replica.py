@@ -289,8 +289,8 @@ def launch_worker(cmd, *, env=None, name="model", ready_timeout=240.0,
     """Run one worker argv and wait for its readiness handshake.
     Returns ``(proc, port, ready_info)`` where ``ready_info`` is the
     parsed ``REPLICA_READY`` evidence (programs / compiles / disk_hits
-    — the zero-compile spin-up cert chaos, bench, and the fleet
-    autoscaler all read).  ``launch(cmd, env) -> Popen`` overrides the
+    — the zero-compile spin-up cert chaos and the fleet
+    autoscaler read).  ``launch(cmd, env) -> Popen`` overrides the
     default local `subprocess.Popen` (remote-exec hook).  The line
     prefixes are parameters so the fleet host daemon's handshake
     (``HOSTD_PORT`` / ``HOSTD_READY``) shares this one implementation;
@@ -332,7 +332,7 @@ def launch_worker(cmd, *, env=None, name="model", ready_timeout=240.0,
                 port = int(line.split()[1])
             elif line.startswith(ready_prefix):
                 # "REPLICA_READY programs=N compiles=K disk_hits=D":
-                # the zero-compile spin-up evidence (chaos/bench read it)
+                # the zero-compile spin-up evidence (chaos reads it)
                 for tok in line.split()[1:]:
                     k, _, v = tok.partition("=")
                     if v.isdigit():

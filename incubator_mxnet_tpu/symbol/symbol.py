@@ -15,6 +15,7 @@ interchangeable (`symbol.py:1192 save`, `src/nnvm/legacy_json_util.cc`).
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 import threading
@@ -67,6 +68,19 @@ class _NameManager:
         c = cls._tls.counts.get(hint, 0)
         cls._tls.counts[hint] = c + 1
         return f"{hint}{c}"
+
+    @classmethod
+    @contextlib.contextmanager
+    def fresh(cls):
+        """Compose under counts that start at zero and put the thread's
+        own back on exit: a graph built inside has the same auto names
+        whatever the process composed before."""
+        saved = getattr(cls._tls, "counts", {})
+        cls._tls.counts = {}
+        try:
+            yield
+        finally:
+            cls._tls.counts = saved
 
 
 class _Node:

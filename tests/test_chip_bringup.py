@@ -1,12 +1,14 @@
 """The refusals that keep a CPU from passing for the chip.
 
 `chip_smoke.py` fails without a TPU (and only `--cpu-dry-run`, chosen by
-name, runs it here); `mx.tpu()` is a promise of an accelerator; a device
-error in a selected fused step is raised, not replaced by another path; the
-compile cache is placed by the environment variable when there is one.
+name, runs it here) and so does `benchmark/run.py`; `mx.tpu()` is a promise
+of an accelerator; a device error in a selected fused step is raised, not
+replaced by another path; the compile cache is placed by the environment
+variable when there is one; the README names no file that is gone.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -56,11 +58,37 @@ def test_chip_smoke_cpu_dry_run_is_stamped():
     assert set(summary["stages"]) == {"train", "serve"}
 
 
-def test_bench_refuses_the_cpu():
-    r = _run(["bench.py"])
-    assert r.returncode != 0
-    assert "JAX_PLATFORMS=cpu" in r.stderr and "refusing" in r.stderr
-    assert r.stdout.strip() == ""
+def test_benchmark_refuses_the_cpu():
+    r = _run(["benchmark/run.py", "--workload", "lstm_ptb_train",
+              "--seed", "1", "--seconds", "1"],
+             env={"JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 2
+    assert "needs a TPU chip" in r.stderr
+    assert r.stdout.strip() == ""                # no result line
+
+
+def test_readme_names_only_files_that_exist():
+    """A `tools/x.py`, a bare `x.py` and a root `X.json` that README.md
+    names in backticks is in the tree: the path as written, a bare name at
+    the root, under tools/ or in the package, an artifact at the root or
+    among those .gitignore says a run leaves behind."""
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as f:
+        spans = re.findall(r"`([^`\n]+)`", f.read())
+    with open(os.path.join(REPO, ".gitignore"), encoding="utf-8") as f:
+        ignored = set(f.read().split())
+    here = set(os.listdir(REPO)) | ignored
+    for root in ("tools", "incubator_mxnet_tpu"):
+        for _dir, _subdirs, files in os.walk(os.path.join(REPO, root)):
+            here.update(files)
+    named = set()
+    for span in spans:
+        named.update(re.findall(
+            r"(?<![\w/.<*{-])(tools/\w+\.py|\w+\.py|[A-Z][A-Z0-9_]*\.json)"
+            r"(?![\w.])", span))
+    assert "tools/mxlint.py" in named and "BENCHMARK.json" in named
+    missing = sorted(n for n in named if not (
+        os.path.exists(os.path.join(REPO, n)) if "/" in n else n in here))
+    assert not missing, f"README.md names files that are gone: {missing}"
 
 
 def test_tpu_context_needs_an_accelerator_unless_cpu_was_named():

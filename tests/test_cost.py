@@ -1,6 +1,6 @@
 """mxcost static cost & communication analysis (ISSUE-13 acceptance).
 
-Gates: the dequantize-before-dot chain in the BENCH_OPS int8 convnet is
+Gates: the dequantize-before-dot chain in the bench int8 convnet is
 flagged with exact node names and the fp32/bf16 bench models produce
 zero false positives; the static collective enumeration for a dp=8
 bucketed plan matches `KVStore.stats()` measured bytes/dispatches
@@ -63,6 +63,28 @@ def test_int8_bench_convnet_dequant_chain_flagged_with_exact_nodes():
         ["contrib_quantized_fully_connected_0"]
     assert prog.counters["dequant_fp32_dot"] == 1
     assert prog.counters["quantized_fp32_compute"] == 1
+
+
+def test_bench_graph_names_do_not_depend_on_what_was_composed_before():
+    def names():
+        qsym, _, _ = mxcost.build_bench_quantized_convnet()
+        return [n["name"] for n in json.loads(qsym.tojson())["nodes"]]
+
+    def suffix(s):
+        return int(s.name.rsplit("_", 1)[1])
+
+    # auto-named nodes of the kinds the builder makes, composed outside
+    # it: they move the thread's counts and must not move the builder's
+    x, lo, hi = sym.Variable("x"), sym.Variable("lo"), sym.Variable("hi")
+    before = sym.contrib.dequantize(x, lo, hi)
+    sym.Flatten(x)
+    first = names()
+    assert "contrib_dequantize_0" in first
+    assert "contrib_quantized_fully_connected_0" in first
+    # ... and the builder leaves the thread's counts as it found them
+    after = sym.contrib.dequantize(x, lo, hi)
+    assert suffix(after) == suffix(before) + 1
+    assert names() == first
 
 
 def test_fp32_and_bf16_bench_models_zero_false_positives():
@@ -329,7 +351,7 @@ def test_budget_check_regression_slack_missing_and_demotion():
 
 def test_committed_budgets_match_head_analysis():
     """The committed COST_BUDGETS.json is in sync with HEAD: zero
-    budget regressions (the parity cost stage gates on exactly this)."""
+    budget regressions."""
     budgets = mxbudgets.load(BUDGETS_PATH)
     results = mxcost.analyze_bench_set(dp=8)
     report, _ = mxbudgets.check(results, budgets)

@@ -307,8 +307,7 @@ def test_cache_concurrent_lookups_return_correct_rows():
 
 def test_cache_steady_state_has_zero_recompiles():
     """Fixed batch shape in steady state replays ONE executable: the
-    padded gather/scatter signature set stops growing (the
-    run_embed_bench zero-recompile gate)."""
+    padded gather/scatter signature set stops growing."""
     rng = np.random.RandomState(0)
 
     def pull(ids):

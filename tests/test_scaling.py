@@ -1,7 +1,7 @@
 """Pod-scale SPMD fast path: bucketed gradient exchange, composed
 meshes, distributed BatchNorm (ISSUE 11).
 
-The contracts certified here are the ones BENCH_SCALING.json benches:
+The contracts certified here:
 
 * bucket boundaries are a pure scheduling choice — bucketed,
   single-bucket, streaming, and per-key exchanges produce bit-identical
@@ -154,7 +154,7 @@ def test_nonfinite_bucket_does_not_poison_neighbors(monkeypatch):
 def test_kvstore_stats_and_runtime_report(monkeypatch):
     """`KVStore.stats()` exposes the communication economy (dispatches,
     bytes, bucket fill, overlap) and `analysis.runtime_report()` carries
-    it as a kvstore.buckets finding — the BENCH_SCALING read path."""
+    it as a kvstore.buckets finding."""
     kv, _ = _push_with_cap(0.0005, monkeypatch)
     st = kv.stats()
     for field in ("allreduce_dispatches", "bytes_reduced", "buckets",

@@ -341,20 +341,19 @@ def test_cli_seeded_coverage_gap_exits_nonzero(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# scaling-lane static block (BENCH_SCALING.json `shard_static`)
+# shard_collectives: the per-step economy of a dp-sharded step
 # ---------------------------------------------------------------------------
 
-def test_run_scaling_shard_static_block():
-    spec = importlib.util.spec_from_file_location(
-        "_run_scaling_shard", os.path.join(REPO, "tools",
-                                           "run_scaling.py"))
-    rs = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rs)
-    block = rs._shard_static(2)
-    for lane in ("img", "tok"):
-        ent = block[lane]
-        assert ent["per_device_peak_hbm_bytes"] > 0
-        assert ent["per_device_peak_hbm_bytes"] < \
-            ent["replicated_peak_hbm_bytes"]
-        assert ent["dp_collectives_per_step"] >= 1
-        assert ent["dp_ici_bytes_per_step"] > 0
+def test_shard_collectives_dp_static_block():
+    h = sym.FullyConnected(sym.Variable("data"), num_hidden=256, name="fc1")
+    h = sym.Activation(h, act_type="relu")
+    net = sym.SoftmaxOutput(
+        sym.FullyConnected(h, num_hidden=10, name="head"), name="softmax")
+    stats = mxshard.shard_collectives(
+        net, shapes={"data": (64, 128), "softmax_label": (64,)},
+        mesh={"dp": 2}, name="dp2.mlp")
+    rep, dp_plan = stats["report"], stats["dp"]
+    assert rep.per_device_peak_hbm_bytes > 0
+    assert rep.per_device_peak_hbm_bytes < rep.replicated_peak_hbm_bytes
+    assert dp_plan["collectives_per_step"] >= 1
+    assert dp_plan["bytes_per_step"] > 0

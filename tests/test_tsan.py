@@ -47,7 +47,7 @@ def _by_code(code):
 def test_shims_are_plain_threading_objects_when_off():
     """With the sanitizer off, make_lock/make_rlock/make_condition hand
     back the stock threading primitives — not wrappers."""
-    if tsan.enabled():   # the flag is on under the parity tsan stage
+    if tsan.enabled():   # MXNET_TSAN=1 came in with the environment
         pytest.skip("MXNET_TSAN=1 in this process")
     lk = alocks.make_lock("x")
     assert type(lk) is type(threading.Lock())
@@ -282,8 +282,8 @@ def drain(lock):
 
 
 def test_package_is_clean_under_concurrency_lints():
-    """The sweep the parity tsan stage gates on: zero findings over the
-    package source."""
+    """Zero findings over the package source (what `mxlint
+    --tsan-report` exits 1 on)."""
     from incubator_mxnet_tpu.analysis.source_lint import CONCURRENCY_CODES
     pkg = os.path.dirname(analysis.__file__)
     pkg = os.path.dirname(pkg)   # incubator_mxnet_tpu/

@@ -178,8 +178,8 @@ def cost_report(paths, as_json=False, budgets_path=None,
                 suppress=(), fail_on="warn", shapes=None):
     """mxcost stage: analyze the canonical bench program set (plus any
     symbol-JSON PATHS) with analysis/cost.py, optionally gate against a
-    COST_BUDGETS baseline, and exit per --fail-on.  This is the CI
-    entry `run_tpu_parity.py`'s cost stage runs: a new dequant chain,
+    COST_BUDGETS baseline, and exit per --fail-on (tests/test_cost.py
+    drives it): a new dequant chain,
     f32 upcast, extra collective, +bytes/step or +peak-HBM beyond the
     committed budget exits 1."""
     from incubator_mxnet_tpu.analysis import Report
@@ -275,8 +275,8 @@ def shard_report(paths, as_json=False, budgets_path=None,
     analysis/sharding.py, optionally gate per-device peak HBM and
     per-step ICI bytes against the COST_BUDGETS "sharding" section,
     and (with --measured) cross-check the static dp plan against a
-    real KVStore push.  This is what `run_tpu_parity.py`'s sharding
-    stage runs: a new hidden reshard, a silently-replicated matrix
+    real KVStore push (tests/test_sharding.py drives it): a new
+    hidden reshard, a silently-replicated matrix
     param, a rule-coverage gap, or +ICI/+HBM beyond budget exits 1."""
     from incubator_mxnet_tpu.analysis import Report
     from incubator_mxnet_tpu.analysis import sharding as mxshard
@@ -397,8 +397,7 @@ def tsan_report(paths, as_json=False):
     given ``.py`` paths (default: the package), plus a render of any
     ``MXNET_TSAN_LOG`` JSON dumps passed in — the runtime sanitizer's
     findings and its lock-acquisition-order graph.  Exit 1 when any
-    lint or runtime finding survives: the run_tpu_parity ``tsan`` stage
-    gates on exactly this."""
+    lint or runtime finding survives."""
     from incubator_mxnet_tpu import analysis
     from incubator_mxnet_tpu.analysis.source_lint import CONCURRENCY_CODES
 

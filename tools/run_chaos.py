@@ -72,7 +72,7 @@ be invisible and a clean re-publish must promote).  The artifact is
 
 Usage: python tools/run_chaos.py [--quick] [--pod] [--serving] [--train]
                                  [--decode] [--loop] [--json] [--out PATH]
-    --quick   bounded test selection (the run_tpu_parity.py stage)
+    --quick   bounded test selection
     --pod     run the elastic pod schedules (writes CHAOS_POD.json)
     --serving run the multi-replica router schedules
               (writes CHAOS_SERVING.json)
@@ -787,7 +787,7 @@ def run_fleet_schedule(tmp, quiet=False, slo_ms=150.0):
         hosts.append(AgentHost.launch_local("host-a", env=env))
         hosts.append(AgentHost.launch_local("host-b", env=env))
         # max == target: this schedule certifies host-loss BACKFILL
-        # (the autoscale-growth story is the bench's), and every extra
+        # (autoscale growth is tests/test_fleet.py's), and every extra
         # breach-driven cold spawn during the measured window is a
         # python+jax import storm polluting the p99 the gate is about
         fleet = FleetManager(
@@ -810,8 +810,8 @@ def run_fleet_schedule(tmp, quiet=False, slo_ms=150.0):
             e.get("spinup_compiles") == 0 for e in ups[1:])
 
         # phase 0 — flood-free interactive baseline (the SLO band is
-        # relative to what THIS machine can deliver, like the serving
-        # bench's degradation gate)
+        # relative to what THIS machine can deliver, not an absolute
+        # latency)
         def interactive_client(n, out):
             for _ in range(n):
                 t1 = time.monotonic()
@@ -867,8 +867,8 @@ def run_fleet_schedule(tmp, quiet=False, slo_ms=150.0):
                     inter["errors"].append(repr(exc))
 
         def best_effort_flood():
-            # PIPELINED (open-loop) flood, the serving bench's
-            # degradation pattern: a deep async window per client is
+            # PIPELINED (open-loop) flood: a deep async window per
+            # client is
             # what builds real queue pressure on a fast model — a
             # closed-loop client could never push est-wait over the
             # best-effort shed threshold

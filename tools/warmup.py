@@ -4,7 +4,7 @@
 Compile a model's program set ahead of traffic and persist the XLA
 executables into the on-disk program cache, so the NEXT process — a
 serving replica, a resumed training job, a c_predict embedder — loads
-compiled programs instead of paying the 28–105 s cold-start compile.
+compiled programs instead of paying the cold-start compile.
 
 Usage:
 
@@ -34,52 +34,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
-def coldstart_probe(timeout=600):
-    """Run the built-in warmup selftest TWICE in fresh subprocesses
-    against a throwaway cache dir: the first pays the XLA compiles, the
-    second must load every executable from the disk tier.  Returns
-    {cold_compile_s, warm_compile_s, *_compiles, *_disk_hits,
-    warm_cold_ratio, zero_compile_warm_start} or {"error": ...}.
-
-    Shared by bench.py's coldstart lane and run_tpu_parity.py's
-    coldstart stage.  Each phase is its OWN process, so the caller must
-    not be holding an exclusively-locked accelerator (on TPU, run this
-    before the parent initializes jax — libtpu locks the chip)."""
-    import json as _json
-    import shutil
-    import subprocess
-    import tempfile
-    # a deliberately COLD probe: the first phase must find nothing, so
-    # this program-cache dir is new every time (the one place a temporary
-    # cache name is right)
-    cache = tempfile.mkdtemp(prefix="mxnet-coldstart-")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, os.path.abspath(__file__),
-           "--cache-dir", cache, "--selftest", "--json"]
-    out = {}
-    try:
-        for phase in ("cold", "warm"):
-            r = subprocess.run(cmd, cwd=repo, capture_output=True,
-                               text=True, timeout=timeout)
-            if r.returncode != 0:
-                return {"error": "%s warmup rc=%d" % (phase, r.returncode),
-                        "tail": r.stderr.strip()[-500:]}
-            d = _json.loads(r.stdout.strip().splitlines()[-1])
-            out[phase + "_compile_s"] = d["compile_s"]
-            out[phase + "_compiles"] = d["compiles"]
-            out[phase + "_disk_hits"] = d["disk_hits"]
-        if out["cold_compile_s"]:
-            out["warm_cold_ratio"] = round(
-                out["warm_compile_s"] / out["cold_compile_s"], 3)
-        out["zero_compile_warm_start"] = out["warm_compiles"] == 0 and \
-            out["warm_disk_hits"] > 0
-        return out
-    except Exception as exc:
-        return {"error": f"coldstart probe failed: {exc!r}"}
-    finally:
-        shutil.rmtree(cache, ignore_errors=True)
 
 
 def _fused_vs_jax_compile():
@@ -254,8 +208,8 @@ def measure_coldstart_budgets():
 
 
 # the measured programs the coldstart budget gate REQUIRES baselined
-# entries for (run_tpu_parity's coldstart stage fails when one is
-# missing from COST_BUDGETS.json's "measured" section)
+# entries for (--measure-budgets fails when one is missing from
+# COST_BUDGETS.json's "measured" section)
 REQUIRED_MEASURED = ("quantization.convnet_fp32",
                      "quantization.convnet_bf16",
                      "quantization.convnet_int8",
