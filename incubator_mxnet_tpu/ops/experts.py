@@ -12,20 +12,41 @@ for the tokens routed to an expert it holds: the part of the layer's result
 that this share gives.  What the experts held elsewhere would add is left
 out; on one chip nothing stands in for them or for their exchange.
 
-Dispatch is sort-and-group: the assignments to experts held here are sorted
-by expert, every expert's group padded to whole blocks of rows, and the
-grouped matrix product is one batched product over the blocks, each block
-against its expert's weights; the combine scatters the weighted rows back.
-Shapes are static, so the rows are sized for a capacity, twice the mean
-load; a step whose load passes it runs the exact dense form over the experts
-held, slowly.  No token is ever dropped.  The auxiliary states count, on the
-device, the assignments each expert held got (`load`), and in `dropped` those
-whose row fell outside the rows there were (counted where the rows are
-placed, so a fault in the sizing would show) beside the tokens routed.
+Dispatch is plan-and-group.  The PLAN of a call (`_plan`) is made from the
+(tokens, experts held) mask alone, without a sort and without a scatter
+over the assignments that go elsewhere: a cumulative sum down each held
+expert's column ranks its tokens, every expert's group is padded to whole
+blocks of rows (at least one), and a row finds its token by counting the
+column's entries below its rank.  The grouped product runs a block of rows
+against the weights of the block's expert (`_block_forward`,
+`_block_backward`: the algebra, stated once on values), under one of two
+drivers:
+
+* the KERNEL (compiled, on ``tpu``, where hidden and intermediate size are
+  multiples of 128 and the block is 128 rows): one Pallas kernel a pass
+  whose grid walks the row blocks with the block-to-expert map as scalar
+  prefetch, so that an expert's weights are read where they lie, once for
+  its consecutive blocks, blocks past the live ones are skipped, and the
+  weights' gradients are summed per expert in VMEM;
+* XLA (anywhere else, and what tier-1 on the CPU runs): the same block
+  algebra as one batched product over the blocks against gathered weights.
+
+The whole operator, routing included, stands under one custom VJP whose
+backward pass is written, not derived: it keeps the inputs, the plan, the
+router's probabilities and the rows' pre-activations, and runs no forward
+product again.  Shapes are static, so the rows are sized for a capacity,
+twice the mean load; a step whose load passes it runs the exact dense form
+over the experts held, slowly (and derived).  No token is ever dropped.  The
+auxiliary states count, on the device, the assignments each expert held got
+(`load`), and in `dropped` those left without a row among the rows there
+were (counted where the rows are placed, so a fault in the sizing would
+show) beside the tokens routed.  Which driver a traced call took is
+counted: `ops.experts.lowered.kernel` / `.xla`.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -35,62 +56,461 @@ from jax import lax
 from .registry import register, REQUIRED
 from ..base import MXNetError
 
+F32 = jnp.float32
+I32 = jnp.int32
+LANES = 128     # the kernel tiles hidden and intermediate size by this,
+                # and takes blocks of as many rows
+
+
+def _routing(x2, router_weight, top_k, norm_topk):
+    """(probabilities (N, E) float32, the top_k of them (N, k), the weights
+    made of those, their experts (N, k) int32)."""
+    logits = jnp.dot(x2, router_weight.astype(x2.dtype).T,
+                     preferred_element_type=F32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, experts = lax.top_k(probs, top_k)
+    weights = top_p
+    if norm_topk:
+        weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, top_p, weights, experts
+
 
 def route(x2, router_weight, top_k, norm_topk=True):
     """(weights (N, k) float32, experts (N, k) int32) of every token."""
-    logits = jnp.dot(x2, router_weight.astype(x2.dtype).T,
-                     preferred_element_type=jnp.float32)
-    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return _routing(x2, router_weight, top_k, norm_topk)[2:]
+
+
+def _routing_bwd(x2, router_weight, probs, top_p, top_e, norm_topk, dw):
+    """The transpose of `_routing` at weights = dw: (dx2, drouter_weight).
+    `top_k` is transposed with compares against `top_e`, not a scatter."""
     if norm_topk:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, experts
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        dw = (dw - jnp.sum(dw * top_p, axis=-1, keepdims=True) / total) / \
+            total
+    experts = jnp.arange(probs.shape[1], dtype=top_e.dtype)
+    dprobs = jnp.sum(jnp.where(top_e[:, :, None] == experts, dw[:, :, None],
+                               F32(0)), axis=1)
+    dlogits = probs * (dprobs - jnp.sum(probs * dprobs, axis=-1,
+                                        keepdims=True))
+    dlogits = dlogits.astype(x2.dtype)
+    dx2 = jnp.dot(dlogits, router_weight.astype(x2.dtype),
+                  preferred_element_type=F32)
+    drouter = lax.dot_general(dlogits, x2, _TN, preferred_element_type=F32)
+    return dx2, drouter.astype(router_weight.dtype)
 
 
-def _expert(x, gate, up, down):
-    h = jax.nn.silu(jnp.matmul(x, jnp.swapaxes(gate, -1, -2))) * \
-        jnp.matmul(x, jnp.swapaxes(up, -1, -2))
-    return jnp.matmul(h, jnp.swapaxes(down, -1, -2))
+# ---------------------------------------------------------------------------
+# The plan of a call
+# ---------------------------------------------------------------------------
+
+class Plan(NamedTuple):
+    """Integers, and one float32 weight a row.  `rows` rows in blocks of
+    `block`; row r of a block of expert e is the (r - start of e's group)-th
+    token of e's column, or no token's."""
+    counts: jax.Array        # (count,) assignments each expert held got
+    cum: jax.Array           # (count, N) tokens of a column up to each one
+    block_expert: jax.Array  # (blocks,) non-decreasing
+    live: jax.Array          # (1,) blocks that hold a group
+    rank: jax.Array          # (blocks, block) a row's rank in its column
+    row_token: jax.Array     # (rows,) N where a row holds no token
+    row_weight: jax.Array    # (rows,) float32
+    dropped: jax.Array       # () assignments left without a row
+    # the same rows in the order of their tokens, for the combine: which
+    # row stands there, its token and weight as lane-dense rows of a
+    # block, and the combine's walk: (block of tokens, block of rows) pairs
+    by_token: jax.Array      # (rows,) the row that stands at each place
+    token_rows: jax.Array    # (blocks, 1, block) the tokens in that order
+    weight_rows: jax.Array   # (blocks, 1, block) float32
+    walk_tokens: jax.Array   # (steps,) non-decreasing
+    walk_rows: jax.Array     # (steps,)
+    walk_live: jax.Array     # (1,) steps that hold a pair
 
 
-def _grouped(rows, block, x2, gate, up, down, top_w, order, sorted_e, counts):
-    """(result, assignments left without a row) of sort-and-group over
-    `rows` rows in blocks of `block`: exact while the padded groups fit,
-    which the caller's look at the load guarantees."""
-    n, k = top_w.shape
-    count = gate.shape[0]
-    held = sorted_e < count
-    e = jnp.minimum(sorted_e, count - 1)
-    padded = (counts + block - 1) // block * block
+def _plan(top_w, top_e, offset, count, rows, block):
+    n = top_e.shape[0]
+    blocks = rows // block
+    held = offset + jnp.arange(count, dtype=top_e.dtype)
+    match = top_e[:, :, None] == held                   # (N, k, count)
+    weight = jnp.sum(jnp.where(match, top_w[:, :, None], F32(0)), axis=1)
+    cum = jnp.cumsum(jnp.any(match, axis=1).T.astype(I32), axis=1)
+    counts = cum[:, -1]
+    # every group in whole blocks, an empty one in one: each expert's
+    # weight gradient is written by a block of its own
+    padded = jnp.maximum((counts + (block - 1)) // block, 1) * block
     ends = jnp.cumsum(padded)
-    rank = jnp.arange(n * k, dtype=jnp.int32) - (jnp.cumsum(counts) -
-                                                 counts)[e]
-    dest = jnp.where(held, (ends - padded)[e] + rank, rows)
-    row_token = jnp.full((rows,), n, jnp.int32).at[dest].set(
-        order // k, mode="drop")
-    row_weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
-        top_w.reshape(-1)[order], mode="drop")
-    block_expert = jnp.minimum(jnp.searchsorted(
-        ends // block, jnp.arange(rows // block), side="right"), count - 1)
-    xg = jnp.take(x2, row_token, axis=0, mode="fill", fill_value=0)
-    y = _expert(xg.reshape(rows // block, block, -1), gate[block_expert],
-                up[block_expert], down[block_expert])
-    y = y.reshape(rows, -1).astype(jnp.float32) * row_weight[:, None]
-    out = jnp.zeros((n, x2.shape[1]), jnp.float32).at[row_token].add(
-        y, mode="drop")
-    return out, jnp.sum(held & (dest >= rows), dtype=jnp.int32)
+    at = jnp.arange(blocks, dtype=I32)
+    block_expert = jnp.minimum(
+        jnp.sum((ends // block)[None, :] <= at[:, None], axis=1,
+                dtype=I32), count - 1)
+    live = jnp.minimum(ends[-1] // block, blocks).astype(I32).reshape(1)
+    rank = at[:, None] * block + jnp.arange(block, dtype=I32)[None, :] - \
+        (ends - padded)[block_expert][:, None]
+    col_cum = cum[block_expert][:, None, :]             # (blocks, 1, N)
+    col_w = weight.T[block_expert][:, None, :]
+    # the rank-th token of a column stands after as many entries that have
+    # counted up to `rank` at most; at it the count reaches rank + 1
+    row_token = jnp.sum(col_cum <= rank[:, :, None], axis=-1, dtype=I32)
+    row_weight = jnp.sum(jnp.where(col_cum == rank[:, :, None] + 1, col_w,
+                                   F32(0)), axis=-1)
+    row_token, row_weight = row_token.reshape(rows), row_weight.reshape(rows)
+    dropped = jnp.sum(counts) - jnp.sum(row_token < n, dtype=I32)
+    return Plan(counts, cum, block_expert, live, rank, row_token,
+                row_weight, dropped,
+                *_by_token(row_token, row_weight, n, blocks, block))
 
+
+def _by_token(row_token, row_weight, n, blocks, block):
+    """The rows sorted by token (7,168 keys, not the 81,920 assignments),
+    and the walk of the combine over them: a block of `block` tokens takes
+    the blocks of sorted rows that hold a row of its tokens, at least one,
+    so every block of tokens is written."""
+    rows = blocks * block
+    tokens, by_token, weights = lax.sort(
+        (row_token, jnp.arange(rows, dtype=I32), row_weight), num_keys=1)
+    groups = -(-n // block)
+    edges = jnp.arange(groups + 1, dtype=I32) * block
+    before = jnp.sum(tokens[None, :] < edges[:, None], axis=1, dtype=I32)
+    lo, hi = before[:-1], before[1:]
+    first = jnp.minimum(lo // block, blocks - 1)
+    takes = jnp.minimum(jnp.maximum(hi - 1, lo) // block, blocks - 1) - \
+        first + 1
+    ends = jnp.cumsum(takes)
+    step = jnp.arange(groups + blocks, dtype=I32)
+    walk_tokens = jnp.minimum(
+        jnp.sum(ends[None, :] <= step[:, None], axis=1, dtype=I32),
+        groups - 1)
+    walk_rows = jnp.minimum(
+        first[walk_tokens] + step - (ends - takes)[walk_tokens], blocks - 1)
+    return (by_token, tokens.reshape(blocks, 1, block),
+            weights.reshape(blocks, 1, block), walk_tokens, walk_rows,
+            ends[-1:].astype(I32))
+
+
+def _rows_of(per_token, plan):
+    """(rows, C): every row its token's entry.  A row that holds no token
+    takes the last token's: its weight is 0, so nothing of it reaches a
+    result or a gradient, and a gather that fills would pass over the rows
+    once more."""
+    return jnp.take(per_token, plan.row_token, axis=0, mode="clip")
+
+
+def _rows_to_tokens(plan, per_row):
+    """What every row holds (rows,), at its (token, expert held): (N,
+    count), 0 where a token is not routed to an expert."""
+    blocks, block = plan.rank.shape
+    count, n = plan.cum.shape
+    col_cum = plan.cum[plan.block_expert][:, None, :]
+    at_token = jnp.sum(jnp.where(
+        col_cum == plan.rank[:, :, None] + 1,
+        per_row.reshape(blocks, block)[:, :, None], F32(0)), axis=1)
+    experts = jnp.arange(count, dtype=I32)[:, None, None]
+    per_expert = jnp.sum(jnp.where(plan.block_expert[None, :, None] ==
+                                   experts, at_token[None], F32(0)), axis=1)
+    hit = jnp.diff(plan.cum, axis=1, prepend=0) > 0
+    return jnp.where(hit, per_expert, F32(0)).T
+
+
+# ---------------------------------------------------------------------------
+# A block of rows against its expert's weights, on values
+# ---------------------------------------------------------------------------
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=F32)
+
+
+def _block_forward(x, gate, up, down):
+    """x (B, C); gate, up (I, C); down (C, I), all of x's type.  (y (B, C),
+    the pre-activations g and u (B, I)) in x's type, sums in float32."""
+    g = _dot(x, gate, _NT).astype(x.dtype)
+    u = _dot(x, up, _NT).astype(x.dtype)
+    h = jax.nn.silu(g.astype(F32)) * u.astype(F32)
+    return _dot(h.astype(x.dtype), down, _NT).astype(x.dtype), g, u
+
+
+def _block_backward(x, dy, g, u, w, gate, up, down):
+    """The transpose of `w * _block_forward(x, ...)[0]` at dy, from the
+    kept pre-activations: (dx (B, C) in x's type, dw (B, 1), dgate, dup (I,
+    C), ddown (C, I) float32).  w (B, 1) float32."""
+    g, u = g.astype(F32), u.astype(F32)
+    sig = jax.nn.sigmoid(g)
+    act = g * sig
+    h = act * u
+    dh = _dot(dy, down, _NN)                              # (B, I)
+    dw = jnp.sum(dh * h, axis=1, keepdims=True)
+    dh = dh * w
+    dg = (dh * u * (sig * (F32(1) + g * (F32(1) - sig)))).astype(x.dtype)
+    du = (dh * act).astype(x.dtype)
+    dx = _dot(dg, gate, _NN) + _dot(du, up, _NN)
+    dyw = (dy.astype(F32) * w).astype(x.dtype)
+    return (dx.astype(x.dtype), dw, _dot(dg, x, _TN), _dot(du, x, _TN),
+            _dot(dyw, h.astype(x.dtype), _TN))
+
+
+# ---------------------------------------------------------------------------
+# The XLA driver: one batched product over the blocks
+# ---------------------------------------------------------------------------
+
+def _xla_forward(xg, gate, up, down, plan, save):
+    """(y, g, u) of all rows; what is not kept (`save`) XLA drops itself."""
+    blocks, block = plan.rank.shape
+    e = plan.block_expert
+    y, g, u = jax.vmap(_block_forward)(
+        xg.reshape(blocks, block, -1), gate[e], up[e], down[e])
+    return tuple(a.reshape(blocks * block, -1) for a in (y, g, u))
+
+
+def _xla_backward(xg, dy, g, u, gate, up, down, plan):
+    blocks, block = plan.rank.shape
+    e = plan.block_expert
+    dx, dw, dgate, dup, ddown = jax.vmap(_block_backward)(
+        *(a.reshape(blocks, block, -1)
+          for a in (xg, dy, g, u, plan.row_weight)), gate[e], up[e], down[e])
+    dgate, dup, ddown = (
+        jax.ops.segment_sum(a, e, num_segments=gate.shape[0],
+                            indices_are_sorted=True).astype(gate.dtype)
+        for a in (dgate, dup, ddown))
+    return dx.reshape(xg.shape), dw.reshape(-1), dgate, dup, ddown
+
+
+# ---------------------------------------------------------------------------
+# The kernel driver: one Pallas kernel a pass
+# ---------------------------------------------------------------------------
+
+def _fwd_kernel(expert_ref, live_ref, x_ref, gate_ref, up_ref, down_ref,
+                y_ref, *kept):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        y, g, u = _block_forward(x_ref[...], gate_ref[0], up_ref[0],
+                                 down_ref[0])
+        y_ref[...] = y
+        if kept:
+            kept[0][...] = g
+            kept[1][...] = u
+
+
+def _run_edges(group_ref, at, live):
+    """Whether live step `at` is (the first, the last) of its run of equal
+    entries of a prefetched non-decreasing map."""
+    here = group_ref[at]
+    last_step = np.int32(group_ref.shape[0] - 1)
+    first = jnp.logical_or(
+        at == 0, group_ref[jnp.maximum(at - 1, np.int32(0))] != here)
+    last = jnp.logical_or(
+        at == live - 1, group_ref[jnp.minimum(at + 1, last_step)] != here)
+    return first, last
+
+
+def _sum_over_run(first, last, sums):
+    """(float32 scratch, this step's part, output block) each: the parts
+    of a run summed in the scratch, the output written at its last step."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(first)
+    def _():
+        for acc, part, _ in sums:
+            acc[...] = part
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        for acc, part, _ in sums:
+            acc[...] += part
+
+    @pl.when(last)
+    def _():
+        for acc, _, out in sums:
+            out[...] = acc[...].reshape(out.shape).astype(out.dtype)
+
+
+def _bwd_kernel(expert_ref, live_ref, x_ref, dy_ref, g_ref, u_ref, w_ref,
+                gate_ref, up_ref, down_ref, dx_ref, dw_ref, dgate_ref,
+                dup_ref, ddown_ref, gate_acc, up_acc, down_acc):
+    from jax.experimental import pallas as pl
+    at, live = pl.program_id(0), live_ref[0]
+
+    @pl.when(at < live)
+    def _():
+        dx, dw, dgate, dup, ddown = _block_backward(
+            x_ref[...], dy_ref[...], g_ref[...], u_ref[...], w_ref[...],
+            gate_ref[0], up_ref[0], down_ref[0])
+        dx_ref[...] = dx
+        dw_ref[...] = dw
+        _sum_over_run(*_run_edges(expert_ref, at, live), (
+            (gate_acc, dgate, dgate_ref), (up_acc, dup, dup_ref),
+            (down_acc, ddown, ddown_ref)))
+
+
+def _kernel_specs(plan, c, inter):
+    """Row blocks and the expert's weights of a row block, through the
+    prefetched plan: a block past the live ones stays at the last live
+    one's, so nothing is fetched or written for it."""
+    from jax.experimental import pallas as pl
+    block = plan.rank.shape[1]
+    # int32 throughout: under jax_enable_x64 a Python 0 in an index map is
+    # 64 bits wide, which Mosaic cannot lower
+    zero, one = np.int32(0), np.int32(1)
+
+    def at(i, live):
+        return jnp.minimum(i, live[0] - one)
+    return {
+        "rows": lambda width: pl.BlockSpec(
+            (block, width), lambda i, e, live: (at(i, live), zero)),
+        "gate": pl.BlockSpec(
+            (1, inter, c), lambda i, e, live: (e[at(i, live)], zero, zero)),
+        "down": pl.BlockSpec(
+            (1, c, inter), lambda i, e, live: (e[at(i, live)], zero, zero)),
+    }
+
+
+VMEM_BYTES = 96 << 20   # of the v5e's 128 MiB: an expert's three matrices,
+                        # their gradients and float32 sums, double-buffered
+
+
+def _pallas(kernel, plan, name, interpret, out_shape, **kwargs):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(plan.rank.shape[0],), **kwargs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_BYTES),
+        interpret=interpret, name=name)
+
+
+# Jitted, so that a program that traces the operator again (the primal, the
+# recomputed forward, each fit's shape inference) finds the kernels traced.
+@functools.partial(jax.jit, static_argnames=("save", "interpret"))
+def _kernel_forward(xg, gate, up, down, plan, save, interpret=False):
+    rows, c = xg.shape
+    inter = gate.shape[1]
+    spec = _kernel_specs(plan, c, inter)
+    shapes = [jax.ShapeDtypeStruct((rows, c), xg.dtype)]
+    out_specs = [spec["rows"](c)]
+    if save:
+        shapes += [jax.ShapeDtypeStruct((rows, inter), xg.dtype)] * 2
+        out_specs += [spec["rows"](inter)] * 2
+    out = _pallas(
+        _fwd_kernel, plan, "routed_experts_fwd", interpret, shapes,
+        in_specs=[spec["rows"](c), spec["gate"], spec["gate"], spec["down"]],
+        out_specs=out_specs,
+    )(plan.block_expert, plan.live, xg, gate, up, down)
+    return tuple(out) if save else (out[0], None, None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kernel_backward(xg, dy, g, u, gate, up, down, plan, interpret=False):
+    from jax.experimental.pallas import tpu as pltpu
+    rows, c = xg.shape
+    inter = gate.shape[1]
+    spec = _kernel_specs(plan, c, inter)
+    shapes = [jax.ShapeDtypeStruct((rows, c), xg.dtype),
+              jax.ShapeDtypeStruct((rows, 1), F32)] + \
+        [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (gate, up, down)]
+    dx, dw, dgate, dup, ddown = _pallas(
+        _bwd_kernel, plan, "routed_experts_bwd", interpret, shapes,
+        in_specs=[spec["rows"](c), spec["rows"](c), spec["rows"](inter),
+                  spec["rows"](inter), spec["rows"](1), spec["gate"],
+                  spec["gate"], spec["down"]],
+        out_specs=[spec["rows"](c), spec["rows"](1), spec["gate"],
+                   spec["gate"], spec["down"]],
+        scratch_shapes=[pltpu.VMEM((inter, c), F32),
+                        pltpu.VMEM((inter, c), F32),
+                        pltpu.VMEM((c, inter), F32)],
+    )(plan.block_expert, plan.live, xg, dy, g, u,
+      plan.row_weight.reshape(rows, 1), gate, up, down)
+    return dx, dw.reshape(rows), dgate, dup, ddown
+
+
+def _exact_dot(p, y, terms):
+    """p (float32, a weight or 0 an entry) times y with nothing of p
+    rounded away: against bfloat16 rows p goes in `terms` bfloat16 parts
+    (three hold a float32, one a 0 or 1), each product exact, the sums
+    float32; against float32 rows as `HIGHEST`."""
+    if y.dtype != jnp.bfloat16:
+        return lax.dot_general(p, y.astype(F32), _NN,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=F32)
+    out = None
+    for _ in range(terms):
+        part = p.astype(jnp.bfloat16)
+        p = p - part.astype(F32)
+        out = _dot(part, y, _NN) if out is None else out + _dot(part, y, _NN)
+    return out
+
+
+def _combine_kernel(group_ref, block_ref, live_ref, y_ref, tokens_ref,
+                    weights_ref, out_ref, acc, *, weighted):
+    """A block of tokens as the sum of the rows of its tokens, a block of
+    sorted rows a step: the rows go through the matrix unit against the
+    (token, row) matrix of their weights (of ones)."""
+    from jax.experimental import pallas as pl
+    at, live = pl.program_id(0), live_ref[0]
+
+    @pl.when(at < live)
+    def _():
+        block = y_ref.shape[0]
+        ids = group_ref[at] * np.int32(block) + lax.broadcasted_iota(
+            I32, (block, block), 0)
+        here = jnp.where(tokens_ref[0] == ids,
+                         weights_ref[0] if weighted else F32(1), F32(0))
+        part = _exact_dot(here, y_ref[...], 3 if weighted else 1)
+        _sum_over_run(*_run_edges(group_ref, at, live),
+                      ((acc, part, out_ref),))
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "weighted", "dtype",
+                                             "interpret"))
+def _kernel_combine(rows, plan, tokens, weighted, dtype, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    block, c = plan.rank.shape[1], rows.shape[1]
+    zero, one = np.int32(0), np.int32(1)
+
+    def at(i, live):
+        return jnp.minimum(i, live[0] - one)
+    lanes = pl.BlockSpec(
+        (1, 1, block), lambda i, g, b, live: (b[at(i, live)], zero, zero))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, weighted=weighted),
+        out_shape=jax.ShapeDtypeStruct((tokens, c), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(plan.walk_tokens.shape[0],),
+            in_specs=[pl.BlockSpec((block, c), lambda i, g, b, live:
+                                   (b[at(i, live)], zero)), lanes, lanes],
+            out_specs=pl.BlockSpec((block, c), lambda i, g, b, live:
+                                   (g[at(i, live)], zero)),
+            scratch_shapes=[pltpu.VMEM((block, c), F32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="routed_experts_combine",
+    )(plan.walk_tokens, plan.walk_rows, plan.walk_live,
+      jnp.take(rows, plan.by_token, axis=0, mode="clip"), plan.token_rows,
+      plan.weight_rows)
+
+
+# ---------------------------------------------------------------------------
+# The dense form, for a load above the capacity
+# ---------------------------------------------------------------------------
 
 DENSE_BLOCK = 1024      # tokens the dense form holds at once
 
 
-def _dense(x2, gate, up, down, top_w, order, sorted_e, counts):
+def _dense(x2, gate, up, down, top_w, local):
     """Every expert held against every token, weighted by the routing: the
     exact form for any load, `count` times the work.  A block of tokens at
     a time, made again in the backward pass, so that it asks for little
-    memory (the program reserves room for every branch, taken or not)."""
-    n, k = top_w.shape
-    local = jnp.zeros((n * k,), jnp.int32).at[order].set(sorted_e) \
-        .reshape(n, k)
+    memory (the program reserves room for every branch, taken or not).
+    `local` (N, k): the expert held that an assignment goes to, `count`
+    for one that goes elsewhere."""
+    n = x2.shape[0]
     experts = jnp.arange(gate.shape[0])
 
     @jax.checkpoint
@@ -107,7 +527,7 @@ def _dense(x2, gate, up, down, top_w, order, sorted_e, counts):
     rows = next(d for d in range(min(DENSE_BLOCK, n), 0, -1) if n % d == 0)
     out = lax.map(block, tuple(a.reshape((n // rows, rows) + a.shape[1:])
                                for a in (x2, top_w, local)))
-    return out.reshape(n, x2.shape[1]), jnp.zeros((), jnp.int32)
+    return out.reshape(n, x2.shape[1]).astype(x2.dtype)
 
 
 def capacity(tokens, top_k, num_experts, count):
@@ -122,62 +542,151 @@ def capacity(tokens, top_k, num_experts, count):
     return cap, cap + count * block, block
 
 
+# ---------------------------------------------------------------------------
+# One custom VJP over routing, both forms and both drivers
+# ---------------------------------------------------------------------------
+
+def _combine(driver, rows, plan, tokens, weighted, dtype):
+    """(tokens, C) of `dtype`: every token the float32 sum of its rows,
+    each times its weight where `weighted`."""
+    if driver != "xla":
+        return _kernel_combine(rows, plan, tokens, weighted, dtype,
+                               interpret=driver == "interpret")
+    rows = rows.astype(F32)
+    if weighted:
+        rows = rows * plan.row_weight[:, None]
+    return jnp.zeros((tokens, rows.shape[1]), F32).at[plan.row_token].add(
+        rows, mode="drop").astype(dtype)
+
+
+def _tiles(tokens, c, inter, block):
+    """Whether the kernels can tile these shapes."""
+    return tokens % LANES == 0 and c % LANES == 0 and inter % LANES == 0 \
+        and block == LANES
+
+
+def _driver(tokens, c, inter, block, interpret):
+    """"kernel", "interpret" or "xla" for a call: the compiled kernel on
+    ``tpu`` where the shapes tile, XLA's products anywhere else.  Interpret
+    mode is never chosen for the caller."""
+    if interpret:
+        if not _tiles(tokens, c, inter, block):
+            raise MXNetError(
+                "routed_experts: the kernels tile tokens, hidden and "
+                "intermediate sizes of multiples of %d in blocks of %d "
+                "rows, not %d, %d and %d in blocks of %d"
+                % (LANES, LANES, tokens, c, inter, block))
+        return "interpret"
+    if jax.default_backend() == "tpu" and _tiles(tokens, c, inter, block):
+        return "kernel"
+    return "xla"
+
+
+def _count(driver):
+    from .. import obs
+    obs.counter("ops.experts.lowered." +
+                ("xla" if driver == "xla" else "kernel")).inc()
+
+
 @functools.lru_cache(maxsize=None)
-def _apply_fn(cap, rows, block):
-    """The custom-VJP `(x2, gate, up, down, top_w, order, sorted_e, counts)
-    -> ((N, C) float32, assignments left without a row)`: the forward pass
-    takes the grouped form while the load is within `cap` and the dense form
-    above it, the backward pass differentiates the form the load selects
-    again, so that only the inputs are kept between the two."""
-    branches = [functools.partial(_grouped, rows, block), _dense]
+def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
+    """The custom-VJP `(x2, router_weight, gate, up, down) -> ((N, C), the
+    assignments each expert held got, those left without a row)`.  Either
+    pass takes the grouped form while the load is within `cap` and the
+    dense form above it."""
+    products = (_xla_forward, _xla_backward) if driver == "xla" else tuple(
+        functools.partial(f, interpret=driver == "interpret")
+        for f in (_kernel_forward, _kernel_backward))
 
     def form(counts):
-        return (jnp.sum(counts) > cap).astype(jnp.int32)
+        return (jnp.sum(counts) > cap).astype(I32)
+
+    def local_of(top_e, count):
+        local = top_e - offset
+        return jnp.where((local >= 0) & (local < count), local, count)
+
+    def forward(save, x2, router_weight, gate, up, down):
+        count, inter = gate.shape[0], gate.shape[1]
+        probs, top_p, top_w, top_e = _routing(x2, router_weight, top_k,
+                                              norm_topk)
+        plan = _plan(top_w, top_e, offset, count, rows, block)
+        weights = tuple(w.astype(x2.dtype) for w in (gate, up, down))
+
+        def grouped(x2, gate, up, down):
+            y, g, u = products[0](_rows_of(x2, plan), gate, up, down, plan,
+                                  save)
+            out = _combine(driver, y, plan, x2.shape[0], True, x2.dtype)
+            return (out, g, u) if save else (out,)
+
+        def dense(x2, gate, up, down):
+            out = _dense(x2, gate, up, down, top_w, local_of(top_e, count))
+            kept = jnp.zeros((rows, inter), x2.dtype)
+            return (out, kept, kept) if save else (out,)
+        which = form(plan.counts)
+        out = lax.switch(which, (grouped, dense), x2, *weights)
+        dropped = jnp.where(which == 0, plan.dropped, 0)
+        return (out[0], plan.counts, dropped), \
+            (probs, top_p, top_w, top_e, plan) + tuple(out[1:])
 
     @jax.custom_vjp
     def apply(*args):
-        return lax.switch(form(args[-1]), branches, *args)
+        return forward(False, *args)[0]
 
     def fwd(*args):
-        return apply(*args), args
+        out, kept = forward(True, *args)
+        return out, (args, kept)
 
-    def bwd(args, ct):
-        floats, ints = args[:5], args[5:]
+    def bwd(res, cts):
+        (x2, router_weight, gate, up, down), \
+            (probs, top_p, top_w, top_e, plan, g, u) = res
+        ct = cts[0]
+        count = gate.shape[0]
+        weights = tuple(w.astype(x2.dtype) for w in (gate, up, down))
+        held = offset + jnp.arange(count, dtype=top_e.dtype)
 
-        def grads(branch):
-            def run(*operands):
-                *fl, cot = operands[:6]
-                return jax.vjp(lambda *f: branch(*f, *operands[6:])[0],
-                               *fl)[1](cot)
-            return run
-        out = lax.switch(form(ints[-1]), [grads(b) for b in branches],
-                         *floats, ct[0], *ints)
-        return tuple(out) + tuple(
-            np.zeros(i.shape, jax.dtypes.float0) for i in ints)
+        def grouped(x2, ct, g, u, gate, up, down):
+            dxg, dw, dgate, dup, ddown = products[1](
+                _rows_of(x2, plan), _rows_of(ct, plan), g, u, gate, up, down,
+                plan)
+            dx = _combine(driver, dxg, plan, x2.shape[0], False, F32)
+            dw = _rows_to_tokens(plan, dw)               # (N, count)
+            dtop_w = jnp.sum(jnp.where(top_e[:, :, None] == held,
+                                       dw[:, None, :], F32(0)), axis=-1)
+            return dx, dgate, dup, ddown, dtop_w
+
+        def dense(x2, ct, g, u, gate, up, down):
+            local = local_of(top_e, count)
+            dx, dgate, dup, ddown, dtop_w = jax.vjp(
+                lambda *f: _dense(*f, local), x2, gate, up, down,
+                top_w)[1](ct)
+            return dx.astype(F32), dgate, dup, ddown, dtop_w
+        dx, dgate, dup, ddown, dtop_w = lax.switch(
+            form(plan.counts), (grouped, dense), x2, ct, g, u, *weights)
+        dx_router, drouter = _routing_bwd(x2, router_weight, probs, top_p,
+                                          top_e, norm_topk, dtop_w)
+        return ((dx + dx_router).astype(x2.dtype), drouter) + tuple(
+            d.astype(w.dtype) for d, w in zip((dgate, dup, ddown),
+                                              (gate, up, down)))
 
     apply.defvjp(fwd, bwd)
     return apply
 
 
 def routed_experts(x, router_weight, gate, up, down, num_experts, top_k,
-                   offset, norm_topk=True):
+                   offset, norm_topk=True, interpret=False):
     """(partial output of x's shape and type, assignments per expert held
-    (count,) int32, assignments left without a row, scalar int32)."""
+    (count,) int32, assignments left without a row, scalar int32).
+    `interpret` is for tests: the kernel, interpreted, on any backend."""
     count = gate.shape[0]
     x2 = x.reshape(-1, x.shape[-1])
-    n = x2.shape[0]
-    top_w, top_e = route(x2, router_weight, top_k, norm_topk)
-    local = top_e - offset
-    local = jnp.where((local >= 0) & (local < count), local, count) \
-        .reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    sorted_e = local[order]
-    counts = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
-                     dtype=jnp.int32)
-    apply = _apply_fn(*capacity(n, top_k, num_experts, count))
-    weights = [w.astype(x.dtype) for w in (gate, up, down)]
-    out, dropped = apply(x2, *weights, top_w, order, sorted_e, counts)
-    return out.astype(x.dtype).reshape(x.shape), counts, dropped
+    cap, rows, block = capacity(x2.shape[0], top_k, num_experts, count)
+    driver = _driver(x2.shape[0], x2.shape[1], gate.shape[1], block,
+                     interpret)
+    _count(driver)
+    apply = _apply_fn(cap, rows, block, int(top_k), int(offset),
+                      bool(norm_topk), driver)
+    out, counts, dropped = apply(x2, router_weight, gate, up, down)
+    return out.reshape(x.shape), counts, dropped
 
 
 def _experts_flops(params, in_avals, out_avals):

@@ -116,12 +116,13 @@ class OpDef:
         # the int8-slower-than-fp32 defect's static signature);
         # "quantized" — marks an int8-family op for the dtype-flow pass.
         self.cost_meta = dict(cost_meta) if cost_meta else None
-        # scan_remat: the op's residuals for the backward pass are far
-        # larger than its inputs (routed experts; a chunked recurrence,
-        # whose written backward pass still keeps a float32 state per
-        # chunk), so a scanned layer holding it recomputes its activations
-        # in the backward pass instead of stacking them
-        # (`symbol.graph_eval_fn`)
+        # scan_remat: the op keeps more for its backward pass than a
+        # layer's stacked activations have room for (a chunked recurrence,
+        # whose written backward pass keeps a float32 state per chunk;
+        # routed experts, whose written backward pass keeps the router's
+        # float32 probabilities, the plan and the rows' pre-activations),
+        # so a scanned layer holding it recomputes its activations in the
+        # backward pass instead of stacking them (`symbol.graph_eval_fn`)
         self.scan_remat = bool(scan_remat)
         # counters: the op's auxiliary states are counters it adds to in
         # every training step.  `counters(deltas)`, `deltas` one {aux slot

@@ -244,26 +244,26 @@ def test_delta_rule_driver_follows_backend_and_shape(monkeypatch):
 E, TOPK, C, I, N = 16, 4, 24, 12, 40
 
 
-def _moe_leaves(seed=20):
-    return {"moe.router.w": _rand(seed, E, C, scale=0.5),
-            "moe.gate.w": _rand(seed + 1, E, I, C, scale=0.3),
-            "moe.up.w": _rand(seed + 2, E, I, C, scale=0.3),
-            "moe.down.w": _rand(seed + 3, E, C, I, scale=0.3),
-            "moe.shared_gate.w": jnp.zeros((I, C)),
-            "moe.shared_up.w": jnp.zeros((I, C)),
-            "moe.shared_down.w": jnp.zeros((C, I)),
-            "moe.shared_sigmoid.w": jnp.zeros((1, C))}
+def _moe_leaves(seed=20, e=E, c=C, i=I, scales=(0.5, 0.3)):
+    return {"moe.router.w": _rand(seed, e, c, scale=scales[0]),
+            "moe.gate.w": _rand(seed + 1, e, i, c, scale=scales[1]),
+            "moe.up.w": _rand(seed + 2, e, i, c, scale=scales[1]),
+            "moe.down.w": _rand(seed + 3, e, c, i, scale=scales[1]),
+            "moe.shared_gate.w": jnp.zeros((i, c)),
+            "moe.shared_up.w": jnp.zeros((i, c)),
+            "moe.shared_down.w": jnp.zeros((c, i)),
+            "moe.shared_sigmoid.w": jnp.zeros((1, c))}
 
 
 _MOE_CFG = {"num_experts_per_tok": TOPK, "norm_topk_prob": True}
 
 
-def _ref_routed(p, x, offset, count):
+def _ref_routed(p, x, offset, count, cfg=_MOE_CFG):
     """The reference's dense mask over experts [offset, offset + count);
     the shared expert's weights are zero, so only the routed part is left."""
     share = dict(p, **{n: p[n][offset:offset + count]
                        for n in ("moe.gate.w", "moe.up.w", "moe.down.w")})
-    return REF.moe(share, x, _MOE_CFG, "float32", held=(offset, count))
+    return REF.moe(share, x, cfg, "float32", held=(offset, count))
 
 
 def _program_routed(p, x, offset, count, train=True):
@@ -349,6 +349,109 @@ def test_dropped_counts_the_assignments_left_without_a_row(monkeypatch):
     _, load, dropped = _program_routed(p, x, offset, count)
     assert 0 < float(dropped[0]) <= float(load.sum()) - 16 + count * 7
     assert float(dropped[1]) == N
+
+
+# the kernel driver, interpreted, at a size it tiles: hidden and intermediate
+# size 128, a mean group of 128 rows, so blocks of 128
+KE, KTOPK, KC, KI, KN = 8, 2, 128, 128, 512
+_KERNEL_CFG = {"num_experts_per_tok": KTOPK, "norm_topk_prob": True}
+
+
+def _kernel_leaves(seed=60):
+    return _moe_leaves(seed, KE, KC, KI, scales=(0.2, 0.1))
+
+
+_KERNEL_NAMES = ("moe.router.w", "moe.gate.w", "moe.up.w", "moe.down.w")
+
+
+def _kernel_pair(p, offset, count, interpret=True):
+    """(the operator through `routed_experts`, the reference's dense mask)
+    as functions of (x, router, gate, up, down), all experts' weights given."""
+    def program(x, router, gate, up, down):
+        held = slice(offset, offset + count)
+        return experts_ops.routed_experts(
+            x, router, gate[held], up[held], down[held], KE, KTOPK, offset,
+            interpret=interpret)[0]
+
+    def reference(x, *ws):
+        return _ref_routed(dict(p, **dict(zip(_KERNEL_NAMES, ws))), x,
+                           offset, count, _KERNEL_CFG)[0]
+    return program, reference
+
+
+@pytest.mark.parametrize("load", ["even", "one_expert"])
+def test_experts_kernel_interpreted_against_the_dense_mask(load):
+    """The KERNEL driver, interpreted, against the reference, the output and
+    every gradient (the router's among them): an even load, and every token
+    on one held expert, whose group then fills four blocks while the others'
+    stay short of one."""
+    offset, count = 2, 4
+    assert experts_ops.capacity(KN, KTOPK, KE, count) == (1024, 1536, 128)
+    p, x = _kernel_leaves(), _rand(70, 2, KN // 2, KC)
+    if load == "one_expert":
+        x = jnp.abs(x) + 0.5
+        p["moe.router.w"] = p["moe.router.w"].at[3].set(1.0)
+    program, reference = _kernel_pair(p, offset, count)
+    args = (x,) + tuple(p[n] for n in _KERNEL_NAMES)
+    counts = experts_ops.routed_experts(
+        x, *(a[offset:offset + count] if i else a
+             for i, a in enumerate(args[1:])), KE, KTOPK, offset)[1]
+    if load == "one_expert":
+        assert int(counts[1]) == KN
+    _same_with_grads(program, reference, args)
+
+
+def test_experts_driver_follows_backend_and_shape(monkeypatch):
+    """The compiled kernel on ``tpu`` where the shapes tile, XLA's products
+    anywhere else; each traced call counts the driver it took."""
+    assert experts_ops._driver(512, 128, 128, 128, False) == "xla"   # CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert experts_ops._driver(512, 128, 128, 128, False) == "kernel"
+    assert experts_ops._driver(8192, 2048, 512, 128, False) == "kernel"
+    assert experts_ops._driver(40, 128, 128, 128, False) == "xla"    # tokens
+    assert experts_ops._driver(512, 24, 128, 128, False) == "xla"    # hidden
+    assert experts_ops._driver(512, 128, 12, 128, False) == "xla"    # inner
+    assert experts_ops._driver(512, 128, 128, 8, False) == "xla"     # block
+    monkeypatch.undo()
+    with pytest.raises(mx.MXNetError, match="tile"):
+        experts_ops._driver(512, 24, 128, 128, True)
+
+    def counts():
+        return tuple(mx.obs.counter("ops.experts.lowered." + d).value
+                     for d in ("kernel", "xla"))
+    p, x = _moe_leaves(), _rand(30, 2, N // 2, C)
+    before = counts()
+    jax.jit(lambda x: _program_routed(p, x, 4, 4)[0]).lower(x)
+    assert counts() == (before[0], before[1] + 1)
+    p, x = _kernel_leaves(), _rand(70, 2, KN // 2, KC)
+    program, _ = _kernel_pair(p, 2, 4)
+    jax.jit(program).lower(x, *(p[n] for n in _KERNEL_NAMES))
+    assert counts() == (before[0] + 1, before[1] + 1)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_experts_that_receive_nothing_get_exact_zeros(interpret):
+    """Two of the four experts held are never chosen: their groups are one
+    block of no rows each, and the gradients of their weights are zeros, not
+    what was left in a buffer."""
+    offset, count = 2, 4
+    p = _kernel_leaves()
+    x = jnp.abs(_rand(71, 2, KN // 2, KC)) + 0.5
+    p["moe.router.w"] = p["moe.router.w"].at[jnp.asarray([2, 5])].set(-1.0)
+    program, _ = _kernel_pair(p, offset, count, interpret)
+    args = (x,) + tuple(p[n] for n in _KERNEL_NAMES)
+    held = tuple(a[offset:offset + count] for a in args[2:])
+    out, counts, dropped = experts_ops.routed_experts(
+        x, args[1], *held, KE, KTOPK, offset, interpret=interpret)
+    assert np.asarray(counts).tolist()[0] == 0 == np.asarray(counts)[3]
+    assert int(counts.sum()) > 0 and int(dropped) == 0
+    grads = jax.grad(lambda *a: jnp.sum(jnp.square(program(*a))),
+                     argnums=(2, 3, 4))(*args)
+    for g in grads:
+        g = np.asarray(g)
+        assert np.all(g[[2, 5]] == 0.0) and np.all(np.isfinite(g))
+        assert np.abs(g[3]).max() > 0 and np.abs(g[4]).max() > 0
+        assert np.all(g[:2] == 0.0) and np.all(g[6:] == 0.0)    # not held
 
 
 def test_expert_share():

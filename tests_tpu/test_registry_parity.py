@@ -94,6 +94,21 @@ CASES = {
     "BlockwiseAttention": _case({"query": (2, 8, 8), "key": (2, 8, 8),
                                  "value": (2, 8, 8)}, num_heads=2),
     "topk": _case({"data": (4, 6)}, grad_req="null", k=2),
+    # the hybrid LM's operators (PR 30), at sizes no kernel tiles: the
+    # chip takes the same XLA drivers the CPU takes
+    "RMSNorm": _case({"data": (4, 6), "gamma": (6,)}),
+    "RotaryEmbedding": _case({"data": (2, 5, 3, 8)}, rotary_dim=4),
+    "CausalConv1D": _case({"data": (2, 9, 6), "weight": (6, 4)}, kernel=4),
+    "GatedDeltaRule": _case({"data": None}),    # built below: g < 0
+    # 2 of 8 experts a token, experts 2-5 held: the routing is a choice,
+    # so the weights are small enough that no two probabilities of a
+    # token come within a rounding of each other
+    "RoutedExperts": _case(
+        {"data": (2, 8, 16), "router_weight": (8, 16),
+         "gate_weight": (4, 12, 16), "up_weight": (4, 12, 16),
+         "down_weight": (4, 16, 12), "load": (4,), "dropped": (2,)},
+        data_scale=0.5, num_experts=8, top_k=2, experts_offset=2,
+        experts_count=4),
     # scalar-op family: one representative shape, scalar=2.5
     **{n: _case({"data": V}, scalar=2.5) for n in (
         "_div_scalar", "_maximum_scalar", "_minimum_scalar",
@@ -343,6 +358,19 @@ def _run_case(name):
         shapes = {"a": (3,), "b": (3,)}
         ctxs = [dict(shapes, ctx=mx.cpu()), dict(shapes, ctx=mx.tpu())]
         check_consistency(s, ctxs, grad_req="null")
+        return
+    if name == "GatedDeltaRule":
+        s = S.GatedDeltaRule(*(S.Variable(n) for n in (
+            "query", "key", "value", "g", "beta")), num_heads=2,
+            num_v_heads=4, chunk_size=8)
+        shapes = {"query": (2, 20, 16), "key": (2, 20, 16),
+                  "value": (2, 20, 24), "g": (2, 20, 4), "beta": (2, 20, 4)}
+        rng = np.random.RandomState(0)
+        ctxs = [dict(shapes, ctx=mx.cpu()), dict(shapes, ctx=mx.tpu())]
+        with _strict_matmul():
+            check_consistency(s, ctxs, grad_req="write", arg_params={
+                "g": -np.abs(rng.normal(size=shapes["g"])) - 0.05,
+                "beta": 1 / (1 + np.exp(-rng.normal(size=shapes["beta"])))})
         return
     if name == "Embedding":
         data = S.Variable("data")
