@@ -159,23 +159,27 @@ class GatedDeltaNetMixer(HybridBlock):
 
 
 class GatedAttentionMixer(HybridBlock):
-    """Grouped-query softmax attention with per-head q/k RMS norms, a
-    partial rotary embedding and a sigmoid gate on its output."""
+    """Grouped-query softmax attention with per-head q/k RMS norms
+    (`zero_centered` or plain), a rotary embedding on
+    `cfg.partial_rotary_factor` of a head and, with `gate`, a sigmoid gate
+    on its output, projected beside q.  `llm/lfm2.py` takes it without the
+    gate, with plain norms and the rotary embedding on the whole head."""
 
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, gate=True, zero_centered=True, **kwargs):
         super().__init__(**kwargs)
         c, dt = cfg.hidden_size, cfg.param_dtype
-        self._cfg = cfg
+        self._cfg, self._gate = cfg, gate
         h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         with self.name_scope():
-            self.q_proj = _dense(2 * h * d, c, dt, "q_proj_")
+            self.q_proj = _dense((2 if gate else 1) * h * d, c, dt,
+                                 "q_proj_")
             self.k_proj = _dense(kv * d, c, dt, "k_proj_")
             self.v_proj = _dense(kv * d, c, dt, "v_proj_")
-            self.q_norm = RMSNorm(d, cfg.rms_norm_eps, dtype=dt,
-                                  prefix="q_norm_")
-            self.k_norm = RMSNorm(d, cfg.rms_norm_eps, dtype=dt,
-                                  prefix="k_norm_")
+            self.q_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
+                                  dtype=dt, prefix="q_norm_")
+            self.k_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
+                                  dtype=dt, prefix="k_norm_")
             self.out_proj = _dense(c, h * d, dt, "out_proj_")
 
     def hybrid_forward(self, F, x):
@@ -184,9 +188,10 @@ class GatedAttentionMixer(HybridBlock):
             cfg.head_dim
         rope = {"rotary_dim": int(d * cfg.partial_rotary_factor),
                 "base": cfg.rope_theta}
-        qg = self.q_proj(x)
-        q = F.slice_axis(qg, axis=-1, begin=0, end=h * d)
-        gate = F.slice_axis(qg, axis=-1, begin=h * d, end=2 * h * d)
+        q = qg = self.q_proj(x)
+        if self._gate:
+            q = F.slice_axis(qg, axis=-1, begin=0, end=h * d)
+            gate = F.slice_axis(qg, axis=-1, begin=h * d, end=2 * h * d)
         q = F.RotaryEmbedding(
             self.q_norm(F.Reshape(q, shape=(0, 0, h, d))), **rope)
         k = F.RotaryEmbedding(
@@ -196,18 +201,25 @@ class GatedAttentionMixer(HybridBlock):
             F.Reshape(q, shape=(0, 0, -1)), F.Reshape(k, shape=(0, 0, -1)),
             self.v_proj(x), name="attention", num_heads=h, num_kv_heads=kv,
             causal=True)
-        return self.out_proj(attn * F.Activation(gate, act_type="sigmoid"))
+        if self._gate:
+            attn = attn * F.Activation(gate, act_type="sigmoid")
+        return self.out_proj(attn)
 
 
 class SparseMoE(HybridBlock):
-    """The experts this chip holds of a routed layer, plus the shared
-    expert every chip computes, behind its scalar sigmoid gate."""
+    """The experts this chip holds of a routed layer, plus (with `shared`)
+    the shared expert every chip computes, behind its scalar sigmoid gate.
+    `router`: `RoutedExperts`' router parameters beyond the softmax
+    router's (`scoring`, `select_bias`, `norm_eps`, `capacity_factor`);
+    with `select_bias` the layer holds the selection bias as an auxiliary
+    state, which no gradient and no optimizer reaches."""
 
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, shared=True, router=None, **kwargs):
         super().__init__(**kwargs)
         c, dt = cfg.hidden_size, cfg.param_dtype
         inter, held = cfg.moe_intermediate_size, cfg.experts_held
-        self._cfg = cfg
+        self._cfg, self._shared = cfg, shared
+        self._router = dict(router or {})
         with self.name_scope():
             def get(name, shape, **kw):
                 return self.params.get(name, shape=shape, dtype=dt,
@@ -219,27 +231,34 @@ class SparseMoE(HybridBlock):
                                          (held.count, inter, c))
             self.experts_down_weight = get("experts_down_weight",
                                            (held.count, c, inter))
-            self.load = self.params.get(
-                "load", shape=(held.count,), grad_req="null", init="zeros",
-                allow_deferred_init=True, differentiable=False)
-            self.dropped = self.params.get(
-                "dropped", shape=(2,), grad_req="null", init="zeros",
-                allow_deferred_init=True, differentiable=False)
-            shared = cfg.shared_expert_intermediate_size
-            self.shared_gate = _dense(shared, c, dt, "shared_gate_")
-            self.shared_up = _dense(shared, c, dt, "shared_up_")
-            self.shared_down = _dense(c, shared, dt, "shared_down_")
-            self.shared_sigmoid = _dense(1, c, dt, "shared_sigmoid_")
+            def state(name, count):
+                return self.params.get(
+                    name, shape=(count,), grad_req="null", init="zeros",
+                    allow_deferred_init=True, differentiable=False)
+            if self._router.get("select_bias"):
+                self.select_bias = state("select_bias", cfg.num_experts)
+            self.load = state("load", held.count)
+            self.dropped = state("dropped", 2)
+            if shared:
+                width = cfg.shared_expert_intermediate_size
+                self.shared_gate = _dense(width, c, dt, "shared_gate_")
+                self.shared_up = _dense(width, c, dt, "shared_up_")
+                self.shared_down = _dense(c, width, dt, "shared_down_")
+                self.shared_sigmoid = _dense(1, c, dt, "shared_sigmoid_")
 
     def hybrid_forward(self, F, x, router_weight, experts_gate_weight,
                        experts_up_weight, experts_down_weight, load,
-                       dropped):
+                       dropped, select_bias=None):
         cfg = self._cfg
+        states = (load, dropped) if select_bias is None else \
+            (select_bias, load, dropped)
         routed = F.RoutedExperts(
             x, router_weight, experts_gate_weight, experts_up_weight,
-            experts_down_weight, load, dropped, name="experts",
+            experts_down_weight, *states, name="experts",
             top_k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-            **cfg.experts_held.op_params())
+            **cfg.experts_held.op_params(), **self._router)
+        if not self._shared:
+            return routed
         shared = self.shared_down(
             F.Activation(self.shared_gate(x), act_type="silu") *
             self.shared_up(x))
@@ -302,20 +321,26 @@ _VARIABLE_INIT = (("a_log", '["loguniform", {}]'), ("dt_bias", "ones"),
                   ("moe_load", "zeros"), ("moe_dropped", "zeros"))
 
 
-def qwen3_next_symbol(cfg, prefix="lm_"):
-    """`Module.fit`-ready training graph: next-token cross-entropy, as
-    `lm_symbol` builds it for `TransformerLM`.  data (B, T) tokens;
-    softmax_label (B, T) targets (the caller shifts)."""
+def _loss_symbol(model, vocab_size, prefix, variable_init):
+    """`model` (tokens -> logits) under a next-token cross-entropy head,
+    its variables told how a fresh `Module.fit` initialises them."""
     from .. import symbol as sym
-    model = Qwen3NextLM(cfg, prefix=prefix)
     logits = model(sym.Variable("data"))                 # (B, T, V)
-    pred = sym.Reshape(logits, shape=(-1, cfg.vocab_size))
+    pred = sym.Reshape(logits, shape=(-1, vocab_size))
     label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
     out = sym.SoftmaxOutput(pred, label, name="softmax")
     for node in out._topo():
         if node.is_variable and node.name.startswith(prefix):
-            init = next((i for suffix, i in _VARIABLE_INIT
+            init = next((i for suffix, i in variable_init
                          if node.name.endswith(suffix)), None)
             if init is not None:
                 node._extra_attrs.setdefault("__init__", init)
     return out
+
+
+def qwen3_next_symbol(cfg, prefix="lm_"):
+    """`Module.fit`-ready training graph: next-token cross-entropy, as
+    `lm_symbol` builds it for `TransformerLM`.  data (B, T) tokens;
+    softmax_label (B, T) targets (the caller shifts)."""
+    return _loss_symbol(Qwen3NextLM(cfg, prefix=prefix), cfg.vocab_size,
+                        prefix, _VARIABLE_INIT)

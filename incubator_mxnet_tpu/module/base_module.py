@@ -868,9 +868,9 @@ class BaseModule:
         if nodes is None:
             # the auxiliary states are an op's last inputs
             nodes = self._counter_nodes = [
-                (n.op, [(slot, var.name) for slot, (var, _) in list(zip(
-                    n.op.list_input_names(n.attrs),
-                    n.inputs))[-n.op.num_aux(n.attrs):]])
+                (n.op, n.attrs, [(slot, var.name) for slot, (var, _) in list(
+                    zip(n.op.list_input_names(n.attrs),
+                        n.inputs))[-n.op.num_aux(n.attrs):]])
                 for n in self.symbol._topo()
                 if not n.is_variable and n.op.counters is not None]
         seen = self.__dict__.setdefault("_counters_seen", {})
@@ -883,9 +883,9 @@ class BaseModule:
             return now if _np.any(now < before) else now - before
 
         deltas = {}
-        for op, slots in nodes:
-            deltas.setdefault(op, []).append(
-                {slot: since(name) for slot, name in slots})
+        for op, params, slots in nodes:
+            deltas.setdefault(op, []).append(dict(
+                {slot: since(name) for slot, name in slots}, params=params))
         from ..obs import metrics as _obs_metrics
         for op, per_node in deltas.items():
             note = op.counters(per_node)

@@ -2,8 +2,11 @@
 told which experts it holds (`parallel.ExpertShare`: offset and count out
 of `num_experts`).
 
-It routes over ALL `num_experts` (softmax in float32, the `top_k` largest,
-weights renormalised over the chosen ones with `norm_topk`), and computes
+It routes over ALL `num_experts`, in float32.  The router (`Router`) scores
+with a softmax or a sigmoid, CHOOSES the `top_k` largest of score + bias
+where a selection bias is given (an input that takes no gradient), WEIGHTS
+by the score without the bias, renormalises over the chosen ones with
+`norm_topk` (their sum plus `eps`).  The layer computes
 
     sum over the chosen experts e that are held here of  w_e * E_e(x),
     E(x) = W_down (SiLU(W_gate x) * W_up x)
@@ -27,7 +30,12 @@ drivers:
   whose grid walks the row blocks with the block-to-expert map as scalar
   prefetch, so that an expert's weights are read where they lie, once for
   its consecutive blocks, blocks past the live ones are skipped, and the
-  weights' gradients are summed per expert in VMEM;
+  weights' gradients are summed per expert in VMEM.  Where an expert's
+  whole matrices (in the backward pass beside their gradients and float32
+  sums) pass the VMEM a grid step may ask for, the intermediate width is
+  cut into tiles (`_tile`, from the shapes): the grid walks the row blocks
+  once a tile, every weight is still read once, and the rows' outputs come
+  out a float32 part a tile, summed outside;
 * XLA (anywhere else, and what tier-1 on the CPU runs): the same block
   algebra as one batched product over the blocks against gathered weights.
 
@@ -35,8 +43,10 @@ The whole operator, routing included, stands under one custom VJP whose
 backward pass is written, not derived: it keeps the inputs, the plan, the
 router's probabilities and the rows' pre-activations, and runs no forward
 product again.  Shapes are static, so the rows are sized for a capacity,
-twice the mean load; a step whose load passes it runs the exact dense form
-over the experts held, slowly (and derived).  No token is ever dropped.  The
+`capacity_factor` (2 unless told) times the mean load; a step whose load
+passes it runs the exact dense form over the experts held, slowly (and
+derived: at 16,384 tokens and 8 experts of width 1,536 it costs 64 ms a
+layer where the grouped form takes 6).  No token is ever dropped.  The
 auxiliary states count, on the device, the assignments each expert held got
 (`load`), and in `dropped` those left without a row among the rows there
 were (counted where the rows are placed, so a fault in the sizing would
@@ -62,36 +72,64 @@ LANES = 128     # the kernel tiles hidden and intermediate size by this,
                 # and takes blocks of as many rows
 
 
-def _routing(x2, router_weight, top_k, norm_topk):
-    """(probabilities (N, E) float32, the top_k of them (N, k), the weights
-    made of those, their experts (N, k) int32)."""
+class Router(NamedTuple):
+    """How the scores of all experts are made and turned into weights."""
+    scoring: str = "softmax"    # or "sigmoid"
+    norm_topk: bool = True      # weights over the sum of the chosen scores
+    eps: float = 0.0            # added to that sum
+
+
+def _chosen(per_expert, top_e):
+    """(N, E) values at the (N, k) chosen experts, by compares: a gather
+    over the assignments costs more than the 64 compares an entry."""
+    experts = jnp.arange(per_expert.shape[1], dtype=top_e.dtype)
+    return jnp.sum(jnp.where(top_e[:, :, None] == experts,
+                             per_expert[:, None, :], F32(0)), axis=-1)
+
+
+def _chosen_total(top_s, router):
+    """What `norm_topk` divides by: the chosen scores' sum, plus `eps`."""
+    total = jnp.sum(top_s, axis=-1, keepdims=True)
+    return total + F32(router.eps) if router.eps else total
+
+
+def _routing(x2, router_weight, bias, top_k, router):
+    """(scores (N, E) float32, the chosen ones (N, k), the weights made of
+    those, their experts (N, k) int32).  `bias` (E,) or None moves the
+    choice alone."""
     logits = jnp.dot(x2, router_weight.astype(x2.dtype).T,
                      preferred_element_type=F32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, experts = lax.top_k(probs, top_k)
-    weights = top_p
-    if norm_topk:
-        weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    return probs, top_p, weights, experts
+    if router.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    if bias is None:
+        top_s, experts = lax.top_k(scores, top_k)
+    else:
+        experts = lax.top_k(scores + bias.astype(F32), top_k)[1]
+        top_s = _chosen(scores, experts)
+    weights = top_s
+    if router.norm_topk:
+        weights = top_s / _chosen_total(top_s, router)
+    return scores, top_s, weights, experts
 
 
-def route(x2, router_weight, top_k, norm_topk=True):
-    """(weights (N, k) float32, experts (N, k) int32) of every token."""
-    return _routing(x2, router_weight, top_k, norm_topk)[2:]
-
-
-def _routing_bwd(x2, router_weight, probs, top_p, top_e, norm_topk, dw):
-    """The transpose of `_routing` at weights = dw: (dx2, drouter_weight).
-    `top_k` is transposed with compares against `top_e`, not a scatter."""
-    if norm_topk:
-        total = jnp.sum(top_p, axis=-1, keepdims=True)
-        dw = (dw - jnp.sum(dw * top_p, axis=-1, keepdims=True) / total) / \
+def _routing_bwd(x2, router_weight, scores, top_s, top_e, router, dw):
+    """The transpose of `_routing` at weights = dw: (dx2, drouter_weight);
+    the bias moves no weight, so it gets nothing.  `top_k` is transposed
+    with compares against `top_e`, not a scatter."""
+    if router.norm_topk:
+        total = _chosen_total(top_s, router)
+        dw = (dw - jnp.sum(dw * top_s, axis=-1, keepdims=True) / total) / \
             total
-    experts = jnp.arange(probs.shape[1], dtype=top_e.dtype)
-    dprobs = jnp.sum(jnp.where(top_e[:, :, None] == experts, dw[:, :, None],
-                               F32(0)), axis=1)
-    dlogits = probs * (dprobs - jnp.sum(probs * dprobs, axis=-1,
-                                        keepdims=True))
+    experts = jnp.arange(scores.shape[1], dtype=top_e.dtype)
+    dscores = jnp.sum(jnp.where(top_e[:, :, None] == experts, dw[:, :, None],
+                                F32(0)), axis=1)
+    if router.scoring == "softmax":
+        dlogits = scores * (dscores - jnp.sum(scores * dscores, axis=-1,
+                                              keepdims=True))
+    else:
+        dlogits = dscores * scores * (F32(1) - scores)
     dlogits = dlogits.astype(x2.dtype)
     dx2 = jnp.dot(dlogits, router_weight.astype(x2.dtype),
                   preferred_element_type=F32)
@@ -223,19 +261,22 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=F32)
 
 
-def _block_forward(x, gate, up, down):
+def _block_forward(x, gate, up, down, part=None):
     """x (B, C); gate, up (I, C); down (C, I), all of x's type.  (y (B, C),
-    the pre-activations g and u (B, I)) in x's type, sums in float32."""
+    the pre-activations g and u (B, I)) in x's type, sums in float32.  With
+    I a tile of the intermediate width, y is a part of the sum over the
+    tiles and comes in the type `part`."""
     g = _dot(x, gate, _NT).astype(x.dtype)
     u = _dot(x, up, _NT).astype(x.dtype)
     h = jax.nn.silu(g.astype(F32)) * u.astype(F32)
-    return _dot(h.astype(x.dtype), down, _NT).astype(x.dtype), g, u
+    return _dot(h.astype(x.dtype), down, _NT).astype(part or x.dtype), g, u
 
 
-def _block_backward(x, dy, g, u, w, gate, up, down):
+def _block_backward(x, dy, g, u, w, gate, up, down, part=None):
     """The transpose of `w * _block_forward(x, ...)[0]` at dy, from the
     kept pre-activations: (dx (B, C) in x's type, dw (B, 1), dgate, dup (I,
-    C), ddown (C, I) float32).  w (B, 1) float32."""
+    C), ddown (C, I) float32).  w (B, 1) float32.  With I a tile, dx (in
+    the type `part`) and dw are parts of the sums over the tiles."""
     g, u = g.astype(F32), u.astype(F32)
     sig = jax.nn.sigmoid(g)
     act = g * sig
@@ -247,8 +288,8 @@ def _block_backward(x, dy, g, u, w, gate, up, down):
     du = (dh * act).astype(x.dtype)
     dx = _dot(dg, gate, _NN) + _dot(du, up, _NN)
     dyw = (dy.astype(F32) * w).astype(x.dtype)
-    return (dx.astype(x.dtype), dw, _dot(dg, x, _TN), _dot(du, x, _TN),
-            _dot(dyw, h.astype(x.dtype), _TN))
+    return (dx.astype(part or x.dtype), dw, _dot(dg, x, _TN),
+            _dot(du, x, _TN), _dot(dyw, h.astype(x.dtype), _TN))
 
 
 # ---------------------------------------------------------------------------
@@ -282,13 +323,13 @@ def _xla_backward(xg, dy, g, u, gate, up, down, plan):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(expert_ref, live_ref, x_ref, gate_ref, up_ref, down_ref,
-                y_ref, *kept):
+                y_ref, *kept, axis):
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(0) < live_ref[0])
+    @pl.when(pl.program_id(axis) < live_ref[0])
     def _():
         y, g, u = _block_forward(x_ref[...], gate_ref[0], up_ref[0],
-                                 down_ref[0])
+                                 down_ref[0], y_ref.dtype)
         y_ref[...] = y
         if kept:
             kept[0][...] = g
@@ -330,15 +371,15 @@ def _sum_over_run(first, last, sums):
 
 def _bwd_kernel(expert_ref, live_ref, x_ref, dy_ref, g_ref, u_ref, w_ref,
                 gate_ref, up_ref, down_ref, dx_ref, dw_ref, dgate_ref,
-                dup_ref, ddown_ref, gate_acc, up_acc, down_acc):
+                dup_ref, ddown_ref, gate_acc, up_acc, down_acc, *, axis):
     from jax.experimental import pallas as pl
-    at, live = pl.program_id(0), live_ref[0]
+    at, live = pl.program_id(axis), live_ref[0]
 
     @pl.when(at < live)
     def _():
         dx, dw, dgate, dup, ddown = _block_backward(
             x_ref[...], dy_ref[...], g_ref[...], u_ref[...], w_ref[...],
-            gate_ref[0], up_ref[0], down_ref[0])
+            gate_ref[0], up_ref[0], down_ref[0], dx_ref.dtype)
         dx_ref[...] = dx
         dw_ref[...] = dw
         _sum_over_run(*_run_edges(expert_ref, at, live), (
@@ -346,10 +387,44 @@ def _bwd_kernel(expert_ref, live_ref, x_ref, dy_ref, g_ref, u_ref, w_ref,
             (down_acc, ddown, ddown_ref)))
 
 
-def _kernel_specs(plan, c, inter):
+VMEM_BYTES = 96 << 20   # what a grid step may ask of the v5e's 128 MiB.  The
+                        # forward pass holds a tile of an expert's three
+                        # matrices, double-buffered; the backward pass those,
+                        # their gradients (double-buffered too), the float32
+                        # sums of the gradients over an expert's blocks and
+                        # the float32 products the sums are made from; both
+                        # the row blocks beside them
+
+
+def _step_bytes(c, tile, item, backward):
+    """VMEM a grid step needs for a tile of `tile` of the intermediate
+    width, from the shapes: the residents `VMEM_BYTES`' comment lists."""
+    matrix, rows = tile * c, LANES * (c + tile)
+    if backward:
+        # three matrices in, three gradients out (two buffers each), three
+        # float32 sums, three float32 products; x, dy, g, u in, dx out, and
+        # the float32 dh, dg, du, h and dx of the algebra
+        return matrix * (12 * item + 24) + rows * (6 * item + 16)
+    return matrix * 6 * item + rows * (4 * item + 12)
+
+
+def _tile(c, inter, item, backward):
+    """The widest tile of the intermediate width (a divisor of it in whole
+    lanes) whose grid step fits `VMEM_BYTES`, or None."""
+    for tiles in range(1, inter // LANES + 1):
+        tile = inter // tiles
+        if inter % tiles == 0 and tile % LANES == 0 and \
+                _step_bytes(c, tile, item, backward) <= VMEM_BYTES:
+            return tile
+    return None
+
+
+def _kernel_specs(plan, c, tile, tiles):
     """Row blocks and the expert's weights of a row block, through the
     prefetched plan: a block past the live ones stays at the last live
-    one's, so nothing is fetched or written for it."""
+    one's, so nothing is fetched or written for it.  An index is a
+    function of (tile j, row block i, the two prefetched maps); the grid of
+    one tile has no j, as it had none before there were tiles."""
     from jax.experimental import pallas as pl
     block = plan.rank.shape[1]
     # int32 throughout: under jax_enable_x64 a Python 0 in an index map is
@@ -358,75 +433,110 @@ def _kernel_specs(plan, c, inter):
 
     def at(i, live):
         return jnp.minimum(i, live[0] - one)
+
+    def spec(shape, index):
+        if tiles == 1:
+            return pl.BlockSpec(shape, lambda i, e, live:
+                                index(zero, i, e, live))
+        return pl.BlockSpec(shape, index)
+
+    def rows(width):
+        return spec((block, width), lambda j, i, e, live: (at(i, live), zero))
+
+    def part(width):
+        """A row block of an output that is summed over the tiles."""
+        if tiles == 1:
+            return rows(width)
+        return spec((None, block, width),
+                    lambda j, i, e, live: (j, at(i, live), zero))
     return {
-        "rows": lambda width: pl.BlockSpec(
-            (block, width), lambda i, e, live: (at(i, live), zero)),
-        "gate": pl.BlockSpec(
-            (1, inter, c), lambda i, e, live: (e[at(i, live)], zero, zero)),
-        "down": pl.BlockSpec(
-            (1, c, inter), lambda i, e, live: (e[at(i, live)], zero, zero)),
+        "rows": rows, "part": part,
+        "kept": spec((block, tile),
+                     lambda j, i, e, live: (at(i, live), j)),
+        "gate": spec((1, tile, c),
+                     lambda j, i, e, live: (e[at(i, live)], j, zero)),
+        "down": spec((1, c, tile),
+                     lambda j, i, e, live: (e[at(i, live)], zero, j)),
     }
 
 
-VMEM_BYTES = 96 << 20   # of the v5e's 128 MiB: an expert's three matrices,
-                        # their gradients and float32 sums, double-buffered
-
-
-def _pallas(kernel, plan, name, interpret, out_shape, **kwargs):
+def _pallas(kernel, plan, tiles, name, interpret, out_shape, **kwargs):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    blocks = plan.rank.shape[0]
+    grid = (blocks,) if tiles == 1 else (tiles, blocks)
     return pl.pallas_call(
-        kernel, out_shape=out_shape,
+        functools.partial(kernel, axis=len(grid) - 1), out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(plan.rank.shape[0],), **kwargs),
+            num_scalar_prefetch=2, grid=grid, **kwargs),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret, name=name)
 
 
+def _part_shape(rows, width, dtype, tiles):
+    """An output summed over the tiles: itself of one, else a float32 part
+    a tile."""
+    if tiles == 1:
+        return jax.ShapeDtypeStruct((rows, width), dtype)
+    return jax.ShapeDtypeStruct((tiles, rows, width), F32)
+
+
+def _sum_parts(a, dtype, tiles):
+    return a if tiles == 1 else jnp.sum(a, axis=0).astype(dtype)
+
+
 # Jitted, so that a program that traces the operator again (the primal, the
 # recomputed forward, each fit's shape inference) finds the kernels traced.
-@functools.partial(jax.jit, static_argnames=("save", "interpret"))
-def _kernel_forward(xg, gate, up, down, plan, save, interpret=False):
+@functools.partial(jax.jit, static_argnames=("save", "tile", "interpret"))
+def _kernel_forward(xg, gate, up, down, plan, save, tile=None,
+                    interpret=False):
     rows, c = xg.shape
     inter = gate.shape[1]
-    spec = _kernel_specs(plan, c, inter)
-    shapes = [jax.ShapeDtypeStruct((rows, c), xg.dtype)]
-    out_specs = [spec["rows"](c)]
+    tile = tile or inter
+    tiles = inter // tile
+    spec = _kernel_specs(plan, c, tile, tiles)
+    shapes = [_part_shape(rows, c, xg.dtype, tiles)]
+    out_specs = [spec["part"](c)]
     if save:
         shapes += [jax.ShapeDtypeStruct((rows, inter), xg.dtype)] * 2
-        out_specs += [spec["rows"](inter)] * 2
+        out_specs += [spec["kept"]] * 2
     out = _pallas(
-        _fwd_kernel, plan, "routed_experts_fwd", interpret, shapes,
+        _fwd_kernel, plan, tiles, "routed_experts_fwd", interpret, shapes,
         in_specs=[spec["rows"](c), spec["gate"], spec["gate"], spec["down"]],
         out_specs=out_specs,
     )(plan.block_expert, plan.live, xg, gate, up, down)
-    return tuple(out) if save else (out[0], None, None)
+    y = _sum_parts(out[0], xg.dtype, tiles)
+    return (y,) + tuple(out[1:]) if save else (y, None, None)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _kernel_backward(xg, dy, g, u, gate, up, down, plan, interpret=False):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _kernel_backward(xg, dy, g, u, gate, up, down, plan, tile=None,
+                     interpret=False):
     from jax.experimental.pallas import tpu as pltpu
     rows, c = xg.shape
     inter = gate.shape[1]
-    spec = _kernel_specs(plan, c, inter)
-    shapes = [jax.ShapeDtypeStruct((rows, c), xg.dtype),
-              jax.ShapeDtypeStruct((rows, 1), F32)] + \
+    tile = tile or inter
+    tiles = inter // tile
+    spec = _kernel_specs(plan, c, tile, tiles)
+    shapes = [_part_shape(rows, c, xg.dtype, tiles),
+              _part_shape(rows, 1, F32, tiles)] + \
         [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (gate, up, down)]
     dx, dw, dgate, dup, ddown = _pallas(
-        _bwd_kernel, plan, "routed_experts_bwd", interpret, shapes,
-        in_specs=[spec["rows"](c), spec["rows"](c), spec["rows"](inter),
-                  spec["rows"](inter), spec["rows"](1), spec["gate"],
+        _bwd_kernel, plan, tiles, "routed_experts_bwd", interpret, shapes,
+        in_specs=[spec["rows"](c), spec["rows"](c), spec["kept"],
+                  spec["kept"], spec["rows"](1), spec["gate"],
                   spec["gate"], spec["down"]],
-        out_specs=[spec["rows"](c), spec["rows"](1), spec["gate"],
+        out_specs=[spec["part"](c), spec["part"](1), spec["gate"],
                    spec["gate"], spec["down"]],
-        scratch_shapes=[pltpu.VMEM((inter, c), F32),
-                        pltpu.VMEM((inter, c), F32),
-                        pltpu.VMEM((c, inter), F32)],
+        scratch_shapes=[pltpu.VMEM((tile, c), F32),
+                        pltpu.VMEM((tile, c), F32),
+                        pltpu.VMEM((c, tile), F32)],
     )(plan.block_expert, plan.live, xg, dy, g, u,
       plan.row_weight.reshape(rows, 1), gate, up, down)
-    return dx, dw.reshape(rows), dgate, dup, ddown
+    return _sum_parts(dx, xg.dtype, tiles), \
+        _sum_parts(dw, F32, tiles).reshape(rows), dgate, dup, ddown
 
 
 def _exact_dot(p, y, terms):
@@ -530,15 +640,16 @@ def _dense(x2, gate, up, down, top_w, local):
     return out.reshape(n, x2.shape[1]).astype(x2.dtype)
 
 
-def capacity(tokens, top_k, num_experts, count):
-    """(capacity in assignments, rows, block) of the grouped form: twice the
-    mean load, in blocks of 128 rows (of at most an expert's mean group, for
-    the small sizes of the tests); a load above it takes the dense form."""
+def capacity(tokens, top_k, num_experts, count, factor=2.0):
+    """(capacity in assignments, rows, block) of the grouped form: `factor`
+    times the mean load, in blocks of 128 rows (of at most an expert's mean
+    group, for the small sizes of the tests); a load above it takes the
+    dense form."""
     worst = tokens * min(top_k, count)
     mean = max(1, -(-tokens * top_k * count // num_experts))
     per_expert = 1 << (-(-mean // count) - 1).bit_length()
     block = min(128, max(8, per_expert))
-    cap = min(worst, -(-2 * mean // block) * block)
+    cap = min(worst, -(-int(factor * mean) // block) * block)
     return cap, cap + count * block, block
 
 
@@ -559,25 +670,32 @@ def _combine(driver, rows, plan, tokens, weighted, dtype):
         rows, mode="drop").astype(dtype)
 
 
-def _tiles(tokens, c, inter, block):
-    """Whether the kernels can tile these shapes."""
-    return tokens % LANES == 0 and c % LANES == 0 and inter % LANES == 0 \
-        and block == LANES
+def _tiles(tokens, c, inter, block, item):
+    """The tiles of the intermediate width the kernels take forward and
+    backward at these shapes, or None where they cannot tile them: a size
+    off the lanes, or a grid step that no tile brings under `VMEM_BYTES`."""
+    if tokens % LANES or c % LANES or inter % LANES or block != LANES:
+        return None
+    tiles = _tile(c, inter, item, False), _tile(c, inter, item, True)
+    return None if None in tiles else tiles
 
 
-def _driver(tokens, c, inter, block, interpret):
+def _driver(tokens, c, inter, block, interpret, item=2):
     """"kernel", "interpret" or "xla" for a call: the compiled kernel on
-    ``tpu`` where the shapes tile, XLA's products anywhere else.  Interpret
-    mode is never chosen for the caller."""
+    ``tpu`` where the shapes tile and a grid step fits the VMEM (`item`:
+    bytes an activation or weight entry takes), XLA's products anywhere
+    else.  Interpret mode is never chosen for the caller."""
+    tiles = _tiles(tokens, c, inter, block, item)
     if interpret:
-        if not _tiles(tokens, c, inter, block):
+        if tiles is None:
             raise MXNetError(
                 "routed_experts: the kernels tile tokens, hidden and "
                 "intermediate sizes of multiples of %d in blocks of %d "
-                "rows, not %d, %d and %d in blocks of %d"
-                % (LANES, LANES, tokens, c, inter, block))
+                "rows, a tile of an expert's matrices within %d bytes of "
+                "VMEM a grid step; not %d, %d and %d in blocks of %d"
+                % (LANES, LANES, VMEM_BYTES, tokens, c, inter, block))
         return "interpret"
-    if jax.default_backend() == "tpu" and _tiles(tokens, c, inter, block):
+    if jax.default_backend() == "tpu" and tiles is not None:
         return "kernel"
     return "xla"
 
@@ -589,14 +707,17 @@ def _count(driver):
 
 
 @functools.lru_cache(maxsize=None)
-def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
-    """The custom-VJP `(x2, router_weight, gate, up, down) -> ((N, C), the
-    assignments each expert held got, those left without a row)`.  Either
-    pass takes the grouped form while the load is within `cap` and the
-    dense form above it."""
+def _apply_fn(cap, rows, block, top_k, offset, router, driver, tiles=None):
+    """The custom-VJP `(x2, router_weight, gate, up, down[, bias]) -> ((N,
+    C), the assignments each expert held got, those left without a row)`.
+    Either pass takes the grouped form while the load is within `cap` and
+    the dense form above it.  `router` is a `Router`; `tiles` the kernels'
+    (forward, backward) tiles of the intermediate width, None for whole
+    matrices."""
     products = (_xla_forward, _xla_backward) if driver == "xla" else tuple(
-        functools.partial(f, interpret=driver == "interpret")
-        for f in (_kernel_forward, _kernel_backward))
+        functools.partial(f, tile=tile, interpret=driver == "interpret")
+        for f, tile in zip((_kernel_forward, _kernel_backward),
+                           tiles or (None, None)))
 
     def form(counts):
         return (jnp.sum(counts) > cap).astype(I32)
@@ -605,10 +726,10 @@ def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
         local = top_e - offset
         return jnp.where((local >= 0) & (local < count), local, count)
 
-    def forward(save, x2, router_weight, gate, up, down):
+    def forward(save, x2, router_weight, gate, up, down, bias=None):
         count, inter = gate.shape[0], gate.shape[1]
-        probs, top_p, top_w, top_e = _routing(x2, router_weight, top_k,
-                                              norm_topk)
+        scores, top_s, top_w, top_e = _routing(x2, router_weight, bias,
+                                               top_k, router)
         plan = _plan(top_w, top_e, offset, count, rows, block)
         weights = tuple(w.astype(x2.dtype) for w in (gate, up, down))
 
@@ -626,7 +747,7 @@ def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
         out = lax.switch(which, (grouped, dense), x2, *weights)
         dropped = jnp.where(which == 0, plan.dropped, 0)
         return (out[0], plan.counts, dropped), \
-            (probs, top_p, top_w, top_e, plan) + tuple(out[1:])
+            (scores, top_s, top_w, top_e, plan) + tuple(out[1:])
 
     @jax.custom_vjp
     def apply(*args):
@@ -637,8 +758,8 @@ def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
         return out, (args, kept)
 
     def bwd(res, cts):
-        (x2, router_weight, gate, up, down), \
-            (probs, top_p, top_w, top_e, plan, g, u) = res
+        (x2, router_weight, gate, up, down, *bias), \
+            (scores, top_s, top_w, top_e, plan, g, u) = res
         ct = cts[0]
         count = gate.shape[0]
         weights = tuple(w.astype(x2.dtype) for w in (gate, up, down))
@@ -662,36 +783,51 @@ def _apply_fn(cap, rows, block, top_k, offset, norm_topk, driver):
             return dx.astype(F32), dgate, dup, ddown, dtop_w
         dx, dgate, dup, ddown, dtop_w = lax.switch(
             form(plan.counts), (grouped, dense), x2, ct, g, u, *weights)
-        dx_router, drouter = _routing_bwd(x2, router_weight, probs, top_p,
-                                          top_e, norm_topk, dtop_w)
+        dx_router, drouter = _routing_bwd(x2, router_weight, scores, top_s,
+                                          top_e, router, dtop_w)
         return ((dx + dx_router).astype(x2.dtype), drouter) + tuple(
             d.astype(w.dtype) for d, w in zip((dgate, dup, ddown),
-                                              (gate, up, down)))
+                                              (gate, up, down))) + \
+            tuple(jnp.zeros_like(b) for b in bias)
 
     apply.defvjp(fwd, bwd)
     return apply
 
 
 def routed_experts(x, router_weight, gate, up, down, num_experts, top_k,
-                   offset, norm_topk=True, interpret=False):
+                   offset, norm_topk=True, interpret=False, bias=None,
+                   scoring="softmax", norm_eps=0.0, capacity_factor=2.0):
     """(partial output of x's shape and type, assignments per expert held
-    (count,) int32, assignments left without a row, scalar int32).
+    (count,) int32, assignments left without a row, scalar int32).  `bias`
+    (num_experts,): the selection bias, which takes no gradient.
+    `capacity_factor`: the rows of the grouped form, in mean loads.
     `interpret` is for tests: the kernel, interpreted, on any backend."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise MXNetError("routed_experts: scoring is 'softmax' or 'sigmoid', "
+                         "not %r" % (scoring,))
     count = gate.shape[0]
     x2 = x.reshape(-1, x.shape[-1])
-    cap, rows, block = capacity(x2.shape[0], top_k, num_experts, count)
-    driver = _driver(x2.shape[0], x2.shape[1], gate.shape[1], block,
-                     interpret)
+    if not capacity_factor >= 1.0:
+        raise MXNetError("routed_experts: capacity_factor is at least 1 "
+                         "(the mean load), not %r" % (capacity_factor,))
+    cap, rows, block = capacity(x2.shape[0], top_k, num_experts, count,
+                                float(capacity_factor))
+    shapes = (x2.shape[0], x2.shape[1], gate.shape[1], block)
+    driver = _driver(*shapes, interpret, x2.dtype.itemsize)
+    tiles = None if driver == "xla" else _tiles(*shapes, x2.dtype.itemsize)
     _count(driver)
     apply = _apply_fn(cap, rows, block, int(top_k), int(offset),
-                      bool(norm_topk), driver)
-    out, counts, dropped = apply(x2, router_weight, gate, up, down)
+                      Router(scoring, bool(norm_topk), float(norm_eps)),
+                      driver, tiles)
+    out, counts, dropped = apply(
+        x2, router_weight, gate, up, down, *(() if bias is None else (bias,)))
     return out.reshape(x.shape), counts, dropped
 
 
 def _experts_flops(params, in_avals, out_avals):
-    """The router over all experts, and the three products of an expert at
-    the expected number of assignments to the experts held here."""
+    """The router over all experts (a softmax's or a sigmoid's product is
+    the same), and the three products of an expert at the expected number
+    of assignments to the experts held here."""
     x, gate = in_avals[0], in_avals[2]
     tokens = 1
     for d in x.shape[:-1]:
@@ -704,13 +840,17 @@ def _experts_flops(params, in_avals, out_avals):
 
 def _load_counters(deltas):
     """The routing of an epoch, from what every `RoutedExperts` layer of a
-    graph has added to `load` and `dropped` (`OpDef.counters`)."""
+    graph has added to `load` and `dropped` (`OpDef.counters`; `params` is
+    the node's own: the span says which scoring routed)."""
     loads = np.concatenate([d["load"] for d in deltas])
     lost = [d["dropped"] for d in deltas]
+    scoring = sorted({str(d["params"].get("scoring", "softmax"))
+                      for d in deltas})
     args = {"layers": len(deltas), "tokens": int(max(d[1] for d in lost)),
             "assigned": int(loads.sum()), "max": float(loads.max()),
             "mean": float(loads.mean()),
-            "dropped": int(sum(d[0] for d in lost))}
+            "dropped": int(sum(d[0] for d in lost)),
+            "scoring": ",".join(scoring)}
     return {"span": "moe.load", "args": args,
             "counters": {"moe." + k: args[k]
                          for k in ("tokens", "assigned", "dropped")},
@@ -718,40 +858,65 @@ def _load_counters(deltas):
             if args["mean"] else {}}
 
 
-@register("RoutedExperts", nin=7, naux=2, mode_dependent=True,
+def _input_names(params):
+    return ["data", "router_weight", "gate_weight", "up_weight",
+            "down_weight"] + \
+        (["select_bias"] if params.get("select_bias") else []) + \
+        ["load", "dropped"]
+
+
+@register("RoutedExperts", nin=-1, mode_dependent=True,
+          naux=lambda p: 3 if p.get("select_bias") else 2,
           params={"num_experts": REQUIRED, "top_k": REQUIRED,
                   "experts_offset": 0, "experts_count": REQUIRED,
-                  "norm_topk": True},
-          input_names=["data", "router_weight", "gate_weight", "up_weight",
-                       "down_weight", "load", "dropped"],
+                  "norm_topk": True, "scoring": "softmax",
+                  "select_bias": False, "norm_eps": 0.0,
+                  "capacity_factor": 2.0},
+          input_names=_input_names,
           cost_meta={"flops": _experts_flops}, scan_remat=True,
           counters=_load_counters)
-def _routed_experts(params, x, router_weight, gate, up, down, load, dropped):
+def _routed_experts(params, x, router_weight, gate, up, down, *states):
     """This share's part of a routed-expert layer (see the module's text).
     data (..., C); router_weight (num_experts, C); gate_weight and
     up_weight (experts_count, I, C); down_weight (experts_count, C, I).
-    Auxiliary states, added to in every training step: `load`
-    (experts_count,), and `dropped` (2,): the assignments left without a
-    row, and the tokens routed."""
+    The router: `scoring` "softmax" or "sigmoid", `norm_topk` with
+    `norm_eps` added to the chosen scores' sum; with `select_bias` a
+    further input (num_experts,) is added to the scores for the CHOICE alone.  It is an auxiliary state: no gradient
+    reaches it and the step hands it back as it was.  Auxiliary states
+    added to in every training step: `load` (experts_count,), and `dropped`
+    (2,): the assignments left without a row, and the tokens routed."""
     num, k = int(params["num_experts"]), int(params["top_k"])
     offset, count = int(params["experts_offset"]), \
         int(params["experts_count"])
+    biased = bool(params.get("select_bias"))
+    if len(states) != 2 + biased:
+        raise MXNetError("RoutedExperts: %d inputs after down_weight, "
+                         "select_bias=%s takes %d"
+                         % (len(states), biased, 2 + biased))
+    bias = states[0] if biased else None
+    load, dropped = states[-2:]
     if router_weight.shape != (num, x.shape[-1]) or k > num or \
             offset < 0 or offset + count > num or \
             gate.shape[0] != count or up.shape != gate.shape or \
-            down.shape != (count, x.shape[-1], gate.shape[1]):
+            down.shape != (count, x.shape[-1], gate.shape[1]) or \
+            (biased and bias.shape != (num,)):
         raise MXNetError(
-            "RoutedExperts: router %s, gate %s, up %s, down %s do not fit "
+            "RoutedExperts: router %s, gate %s, up %s, down %s%s do not fit "
             "data %s with num_experts %d, top_k %d and experts held "
             "[%d, %d)" % (tuple(router_weight.shape), tuple(gate.shape),
                           tuple(up.shape), tuple(down.shape),
-                          tuple(x.shape), num, k, offset, offset + count))
+                          ", select_bias %s" % (tuple(bias.shape),)
+                          if biased else "", tuple(x.shape), num, k, offset,
+                          offset + count))
     out, counts, lost = routed_experts(
         x, router_weight, gate, up, down, num, k, offset,
-        bool(params["norm_topk"]))
+        bool(params["norm_topk"]), bias=bias,
+        scoring=str(params["scoring"]), norm_eps=float(params["norm_eps"]),
+        capacity_factor=float(params["capacity_factor"]))
     if not params.get("_train", False):
         return out
     tokens = x.size // x.shape[-1]
-    return out, load + counts.astype(load.dtype), \
-        dropped + jnp.stack([lost, jnp.asarray(tokens, lost.dtype)]) \
-        .astype(dropped.dtype)
+    return (out,) + ((bias,) if biased else ()) + (
+        load + counts.astype(load.dtype),
+        dropped + jnp.stack([lost, jnp.asarray(tokens, lost.dtype)])
+        .astype(dropped.dtype))

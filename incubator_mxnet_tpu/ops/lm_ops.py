@@ -1,8 +1,10 @@
 """Small operators of hybrid linear-attention language models.
 
 ``RMSNorm`` (plain and zero-centred, optionally gated), ``RotaryEmbedding``
-(partial, rotate-half pairing) and ``CausalConv1D`` (the depthwise
-convolution over time in front of a linear-attention mixer).  Registered
+(partial, rotate-half pairing), ``CausalConv1D`` (the depthwise
+convolution over time in front of a linear-attention mixer) and
+``GatedShortConv`` (the same convolution between two gates: the token mixer
+of the LFM2 family).  Registered
 like `BlockwiseAttention`, so that a saved ``*-symbol.json`` loads in a fresh
 process with no llm/ import.  Each computes in float32 whatever the
 activations' type and hands back the activations' type.
@@ -79,16 +81,19 @@ def _rotary_embedding(params, x):
     return rotary(x, rd, float(params["base"]))
 
 
+def _causal_conv(xf, weight):
+    """`causal_conv1d` on float32 data, float32 out."""
+    k = weight.shape[1]
+    w = weight.astype(jnp.float32)
+    padded = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
+    t = xf.shape[1]
+    return sum(padded[:, j:j + t] * w[:, j] for j in range(k))
+
+
 def causal_conv1d(x, weight):
     """x (B, T, C), weight (C, K): y_t = sum_j w[:, j] * x_{t-(K-1)+j}, with
     zeros before the start of the sequence."""
-    k = weight.shape[1]
-    xf = x.astype(jnp.float32)
-    w = weight.astype(jnp.float32)
-    padded = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
-    t = x.shape[1]
-    out = sum(padded[:, j:j + t] * w[:, j] for j in range(k))
-    return out.astype(x.dtype)
+    return _causal_conv(x.astype(jnp.float32), weight).astype(x.dtype)
 
 
 @register("CausalConv1D", nin=2, params={"kernel": REQUIRED},
@@ -106,3 +111,40 @@ def _causal_conv1d(params, x, weight):
             "got %s" % (x.shape[-1], int(params["kernel"]),
                         tuple(weight.shape)))
     return causal_conv1d(x, weight)
+
+
+def gated_short_conv(bcx, weight):
+    """bcx (B, T, 3C) laid out [B | C | x], weight (C, K):
+    y = C * causal_conv1d(B * x), the gates and the convolution in float32,
+    no activation."""
+    c = weight.shape[0]
+    b, gate, x = (bcx[..., i * c:(i + 1) * c].astype(jnp.float32)
+                  for i in range(3))
+    return (gate * _causal_conv(b * x, weight)).astype(bcx.dtype)
+
+
+@register("GatedShortConv", nin=2, params={"kernel": REQUIRED},
+          input_names=["data", "weight"],
+          cost_meta={"flops": lambda params, ins, outs:
+                     2.0 * (int(params["kernel"]) + 1) *
+                     math.prod(outs[0].shape)})
+def _gated_short_conv(params, bcx, weight):
+    """The LFM2 family's token mixer between its two projections: data is
+    the fused (batch, time, 3 x channels) projection [B | C | x], and the
+    output C * CausalConv1D(B * x) over (batch, time, channels): a causal
+    depthwise convolution of `kernel` taps, no bias, between two
+    elementwise gates.  No product: XLA fuses the shifted multiply-adds
+    and both gates into one pass over the input, and that is the one form
+    there is; a traced call counts it (`ops.short_conv.lowered.xla`), as
+    the operators with a kernel count theirs."""
+    k = int(params["kernel"])
+    if bcx.ndim != 3 or bcx.shape[-1] % 3 or \
+            weight.shape != (bcx.shape[-1] // 3, k):
+        raise MXNetError(
+            "GatedShortConv: data must be (batch, time, 3 x channels) and "
+            "weight (channels, kernel) = (%s, %d); got %s and %s"
+            % (bcx.shape[-1] // 3 if bcx.ndim == 3 else "?", k,
+               tuple(bcx.shape), tuple(weight.shape)))
+    from .. import obs
+    obs.counter("ops.short_conv.lowered.xla").inc()
+    return gated_short_conv(bcx, weight)

@@ -126,9 +126,10 @@ class OpDef:
         self.scan_remat = bool(scan_remat)
         # counters: the op's auxiliary states are counters it adds to in
         # every training step.  `counters(deltas)`, `deltas` one {aux slot
-        # name: what was added since the last look (numpy)} per node of
-        # this op in a graph, gives {"span": name, "args": {...},
-        # "counters": {name: increment}, "gauges": {name: value}}, which
+        # name: what was added since the last look (numpy), "params": the
+        # node's own} per node of this op in a graph, gives {"span": name,
+        # "args": {...}, "counters": {name: increment}, "gauges": {name:
+        # value}}, which
         # `BaseModule.fit` leaves in `mx.obs` where it synchronises the
         # parameters anyway, at the epoch's end
         self.counters = counters

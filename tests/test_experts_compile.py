@@ -1,8 +1,11 @@
 """The routed experts' three Pallas kernels, compiled HERE for the chip the
 benchmark runs on (a TPU v5e that is described, not attached), at the
-published widths and the timed size (8,192 tokens, hidden 2,048,
-intermediate 512, 16 of 512 experts held, 10 a token): what Mosaic would
-refuse on the chip (a block off the tiling, an index map it cannot lower,
+published widths and the timed sizes of both configurations that hold them
+(`qwen3_next_80b_a3b`: 8,192 tokens, hidden 2,048, intermediate 512, 16 of
+512 experts held, 10 a token, a softmax router, one tile; `lfm2_24b_a2b`:
+16,384 tokens, intermediate 1,536, 8 of 64 held, 4 a token, a sigmoid
+router with a selection bias, the backward pass in two tiles of 768): what
+Mosaic would refuse on the chip (a block off the tiling, an index map it cannot lower,
 more VMEM than the chip has for an expert's matrices, their gradients and
 the float32 sums) it refuses here, at no chip time.  Nothing runs, so
 nothing here says anything about results or times
@@ -17,7 +20,17 @@ from jax.sharding import SingleDeviceSharding
 
 from incubator_mxnet_tpu.ops import experts
 
-N, C, INTER, NUM, HELD, TOPK = 8192, 2048, 512, 512, 16, 10
+C = 2048
+# tokens, intermediate, experts, held, a token, (capacity, rows, block)
+# at the configuration's capacity factor (2 and 4 mean loads), the router,
+# whether it takes a selection bias, (forward, backward) tiles
+SIZES = {
+    "qwen3_next_80b_a3b": (8192, 512, 512, 16, 10, (5120, 7168, 128),
+                           experts.Router(), False, (512, 512)),
+    "lfm2_24b_a2b": (16384, 1536, 64, 8, 4, (32768, 33792, 128),
+                     experts.Router("sigmoid", True, 1e-6), True,
+                     (1536, 768)),
+}
 
 
 @pytest.fixture(scope="module")
@@ -44,29 +57,35 @@ def uncached():
     compilation_cache.reset_cache()
 
 
-def _through_the_kernel(x2, router_weight, gate, up, down):
+def _through_the_kernel(config):
     """What `routed_experts` runs on a TPU (here `default_backend()` is the
-    CPU, so the driver is named)."""
-    cap, rows, block = experts.capacity(N, TOPK, NUM, HELD)
-    assert (cap, rows, block) == (5120, 7168, 128)
-    return experts._apply_fn(cap, rows, block, TOPK, 0, True, "kernel")(
-        x2, router_weight, gate, up, down)[0]
+    CPU, so the driver is named), and the shapes of its arguments."""
+    n, inter, num, held, top_k, plan, router, biased, tiles = SIZES[config]
+    factor = {"qwen3_next_80b_a3b": 2.0, "lfm2_24b_a2b": 4.0}[config]
+    assert experts.capacity(n, top_k, num, held, factor) == plan
+    assert experts._tiles(n, C, inter, plan[2], 2) == tiles
+    apply = experts._apply_fn(*plan, top_k, 0, router, "kernel", tiles)
+    shapes = [((n, C), jnp.bfloat16), ((num, C), jnp.bfloat16),
+              ((held, inter, C), jnp.bfloat16),
+              ((held, inter, C), jnp.bfloat16),
+              ((held, C, inter), jnp.bfloat16)] + \
+        ([((num,), jnp.float32)] if biased else [])
+    return (lambda *args: apply(*args)[0]), shapes
 
 
 @pytest.mark.parametrize("calls", [2, 4])
-def test_kernels_compile_for_the_v5e(one_chip, uncached, calls):
+@pytest.mark.parametrize("config", sorted(SIZES))
+def test_kernels_compile_for_the_v5e(one_chip, uncached, config, calls):
     """Forward alone (the grouped product with nothing kept, and the
     combine), value and gradient (the product that keeps the
     pre-activations, its combine, the backward product, and the combine
     of the rows' gradients)."""
-    def shape(*dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-    args = (shape(N, C), shape(NUM, C), shape(HELD, INTER, C),
-            shape(HELD, INTER, C), shape(HELD, C, INTER))
-    fn = _through_the_kernel
+    fn, shapes = _through_the_kernel(config)
+    args = [jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+            for dims, dtype in shapes]
     if calls == 4:
+        forward = fn
         fn = jax.value_and_grad(lambda *a: jnp.sum(
-            _through_the_kernel(*a).astype(jnp.float32)),
-            argnums=(0, 1, 2, 3, 4))
+            forward(*a).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4))
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == calls
