@@ -1,14 +1,32 @@
 """Attention operators for the transformer LM workload.
 
 One registered op, ``BlockwiseAttention``: multi-head scaled-dot-product
-attention over packed ``(batch, time, channels)`` activations, lowered
-through `parallel/ring_attention.blockwise_attention` — the flash-style
-online-softmax recurrence that never materializes the (T, T) score
-matrix.  The projections around it (qkv, out_proj) stay ordinary
-`FullyConnected` nodes so the megatron sharding rules
+attention over packed ``(batch, time, channels)`` activations.  The
+projections around it (qkv, out_proj) stay ordinary `FullyConnected` nodes
+so the megatron sharding rules
 (`parallel/tensor_parallel.ShardingRules.megatron`) see them by name and
 the mxcost dot-class rules price them; this op prices only the
 score/value contractions it owns via `cost_meta`.
+
+What runs where:
+
+* without ``num_kv_heads``: `parallel/ring_attention.blockwise_attention`,
+  the online-softmax recurrence in `jax.numpy`, on every platform;
+* with ``num_kv_heads`` (`grouped_query_attention`): on a TPU, where the
+  shapes tile (`_tiles`: queries and keys in whole lane tiles, head sizes
+  of 64 or whole lane tiles, no fewer keys than queries, a grid step
+  within the kernels' VMEM), the flash kernels of `ops/flash_attention.py`
+  over GROUPED heads under one custom VJP (`_flash`): a grid step holds a
+  block of queries of the r query heads that share a key-value head as
+  rows, so K and V are read once for the r heads and no block of scores
+  passes through HBM, forward or backward.  Kept for the backward pass: q,
+  k, v, the output and a float32 log-sum-exp a row.  Anywhere else, and
+  for every other shape, XLA's block form (`_xla_blocks`): a checkpointed
+  block of queries at a time, what the tests compare the kernels with.
+  The choice is made from the platform and the shapes alone (`_driver`;
+  ``MXNET_FLASH_INTERPRET`` runs the kernels interpreted, for the CPU
+  tests), and which one a traced call took is counted:
+  `ops.attention.lowered.kernel` / `.xla`.
 
 Registering the op here (rather than hiding the attention math inside a
 gluon block) keeps saved LM symbol JSON self-describing: a checkpoint's
@@ -16,6 +34,8 @@ gluon block) keeps saved LM symbol JSON self-describing: a checkpoint's
 no llm/ import.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,12 +50,11 @@ def _attn_flops(params, in_avals, out_avals):
     return 4.0 * b * t * t * c
 
 
-def grouped_query_attention(q, k, v, causal=True, block_size=None):
-    """q (B, T, Hq, D), k (B, S, Hkv, D), v (B, S, Hkv, Dv), Hq a multiple
-    of Hkv (key-value head h serves query heads h*r .. h*r + r - 1): exact
-    softmax attention in float32, one block of queries at a time against
-    the keys it may see, so that only a block of scores exists at once; a
-    block's scores are computed again in the backward pass, not kept."""
+def _xla_blocks(q, k, v, causal=True, block_size=None):
+    """XLA's form of `grouped_query_attention`: one block of queries at a
+    time against the keys it may see, so that only a block of scores
+    exists at once; a block's scores are computed again in the backward
+    pass, not kept."""
     b, t, hq, d = q.shape
     s, hkv = k.shape[1], k.shape[2]
     r = hq // hkv
@@ -60,6 +79,147 @@ def grouped_query_attention(q, k, v, causal=True, block_size=None):
         out.append(block(q[:, first:last], k[:, :keys], v[:, :keys],
                          first + (s - t)))
     return jnp.concatenate(out, axis=1).reshape(b, t, hq, v.shape[-1])
+
+
+LANES = 128
+ROWS = 1024     # stacked query rows a grid step aims at
+
+
+def _tiles(r, t, s, d, dv, itemsize):
+    """(block_q, block_k) of the kernels at these shapes, or None where
+    they do not hold them: queries and keys in whole lane tiles, head sizes
+    of half a lane tile or whole ones, no fewer keys than queries, and the
+    backward kernel's grid step (the larger of the two: a head's K and V
+    and their float32 gradients whole, the stacked rows' blocks, five
+    float32 tiles of scores) within the VMEM the kernels ask for.  About
+    `ROWS` stacked rows a step; the widest tile of keys that divides
+    them."""
+    from . import flash_attention as fa
+    if t % LANES or s % LANES or d % 64 or dv % 64 or s < t:
+        return None
+    block_q = LANES
+    while block_q * 2 * r <= ROWS and t % (block_q * 2) == 0:
+        block_q *= 2
+    rows = r * block_q
+    pd, pdv = -(-d // LANES) * LANES, -(-dv // LANES) * LANES
+    for block_k in (1024, 512, 256, LANES):
+        step = 2 * s * (pd + pdv) * (itemsize + 4) + \
+            2 * rows * (2 * pd + pdv) * itemsize + 4 * rows * pd + \
+            5 * 4 * rows * block_k
+        if s % block_k == 0 and step <= fa.VMEM_LIMIT_BYTES:
+            return block_q, block_k
+    return None
+
+
+def _driver(r, t, s, d, dv, itemsize):
+    """"kernel", "interpret" or "xla" for a call, from the platform and the
+    shapes: the flash kernel on ``tpu`` (or interpreted, where
+    ``MXNET_FLASH_INTERPRET`` asks) where `_tiles` holds the shapes, XLA's
+    block form anywhere else."""
+    from . import flash_attention as fa
+    use, interpret = fa.pallas_mode()
+    if not use or _tiles(r, t, s, d, dv, itemsize) is None:
+        return "xla"
+    return "interpret" if interpret else "kernel"
+
+
+def _count(driver):
+    from .. import obs
+    obs.counter("ops.attention.lowered." +
+                ("xla" if driver == "xla" else "kernel")).inc()
+
+
+def _kernel_layout(q, k, v, *like_q):
+    """(q4, k3, v3[, ...]) as the kernel takes them: (B Hkv, r, T, D) with
+    the softmax scale folded into q where that is exact, (B Hkv, S, D),
+    (B Hkv, S, Dv), what is left of the scale, and further arrays of q's
+    layout (the output, its gradient) brought to q4's."""
+    from . import flash_attention as fa
+    b, t, hq, d = q.shape
+    s, hkv = k.shape[1], k.shape[2]
+
+    def rows(x):
+        return x.reshape(b, t, hkv, hq // hkv, -1).transpose(
+            0, 2, 3, 1, 4).reshape(b * hkv, hq // hkv, t, -1)
+
+    def keys(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * hkv, s, -1)
+    q, scale = fa._fold_scale(q, d)
+    return (rows(q), keys(k), keys(v), scale) + tuple(rows(x) for x in like_q)
+
+
+def _from_rows(x4, b):
+    """(B Hkv, r, T, E) -> (B, T, Hq, E)."""
+    bh, r, t, e = x4.shape
+    return x4.reshape(b, bh // b, r, t, e).transpose(0, 3, 1, 2, 4).reshape(
+        b, t, bh // b * r, e)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, causal, interpret):
+    """The flash kernels over grouped heads (`ops/flash_attention.py`): the
+    r query heads of a key-value head are rows of one grid step, forward
+    and backward.  Kept for the backward pass: q, k, v, the output and a
+    float32 log-sum-exp a row -- no score."""
+    return _flash_fwd(q, k, v, causal, interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, interpret):
+    from . import flash_attention as fa
+    b, t, hq, d = q.shape
+    s, r = k.shape[1], hq // k.shape[2]
+    block_q, block_k = _tiles(r, t, s, d, v.shape[3], q.dtype.itemsize)
+    q4, k3, v3, scale = _kernel_layout(q, k, v)
+    o4, m, l = fa._kernel_forward(
+        q4, k3, v3, s - t, 0, causal=causal, block_q=block_q,
+        block_k=block_k, scale=scale, normalize=True, interpret=interpret)
+    o = _from_rows(o4, b)
+    return o, (q, k, v, o, m + jnp.log(l))
+
+
+def _flash_bwd(causal, interpret, kept, g):
+    from . import flash_attention as fa
+    q, k, v, o, lse = kept
+    b, t, hq, d = q.shape
+    s, hkv = k.shape[1], k.shape[2]
+    r = hq // hkv
+    block_q, block_k = _tiles(r, t, s, d, v.shape[3], q.dtype.itemsize)
+    q4, k3, v3, scale, do4 = _kernel_layout(q, k, v, g)
+    # the rows' sum of dO * O, block by block as the kernel's statistics
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.reshape(b, t // block_q, block_q, hkv, r).transpose(
+        0, 3, 1, 4, 2).reshape(b * hkv, 1, t * r)
+    dq4, dk3, dv3 = fa._kernel_backward(
+        q4, k3, v3, do4, lse, delta, s - t, 0, causal=causal,
+        block_q=block_q, block_k=block_k, scale=scale,
+        dq_scale=float(d) ** -0.5, interpret=interpret)
+
+    def keys(x3, like):
+        return x3.reshape(b, hkv, s, -1).transpose(0, 2, 1, 3).astype(
+            like.dtype)
+    return _from_rows(dq4, b), keys(dk3 * jnp.float32(scale), k), \
+        keys(dv3, v)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def grouped_query_attention(q, k, v, causal=True, block_size=None):
+    """q (B, T, Hq, D), k (B, S, Hkv, D), v (B, S, Hkv, Dv), Hq a multiple
+    of Hkv (key-value head h serves query heads h*r .. h*r + r - 1), S >=
+    T (query i sees keys 0 .. i + S - T): exact softmax attention with
+    float32 scores, maximum, sum and accumulator, the probabilities rounded
+    to v's type for the second product.  `_driver` picks what runs, and
+    `ops.attention.lowered.kernel` / `.xla` count its choice a traced
+    call; `block_size` sizes XLA's blocks, the kernel's tiles come from the
+    shapes (`_tiles`)."""
+    r = q.shape[2] // k.shape[2]
+    driver = _driver(r, q.shape[1], k.shape[1], q.shape[3], v.shape[3],
+                     q.dtype.itemsize)
+    _count(driver)
+    if driver == "xla":
+        return _xla_blocks(q, k, v, causal, block_size)
+    return _flash(q, k, v, causal, driver == "interpret")
 
 
 @register("BlockwiseAttention", nin=3,
