@@ -370,14 +370,26 @@ SCAN_MIN_RUN = 2
 SCAN_HINT_RUN = 4
 
 
-def _clean_cuts(ops, pos, heads):
-    """Positions p where every op->op edge crossing the cut after ops[p]
-    originates AT ops[p] — i.e. the graph's linear spine points.  `_topo`
-    guarantees edges go earlier->later, so a clean cut means everything
-    after it sees only ops[p]'s outputs (plus variables).  Graph heads
-    act as virtual consumers past the end: a head produced mid-graph
-    dirties every later cut, so a scanned run can never hide a value a
-    caller reads."""
+def _clean_cuts(ops, pos, heads, min_run):
+    """(Positions p where every op->op edge crossing the cut after ops[p]
+    originates AT ops[p] — i.e. the graph's linear spine points —, the
+    edges left out of that count).  `_topo` guarantees edges go
+    earlier->later, so a clean cut means everything after it sees only
+    ops[p]'s outputs (plus variables).  Graph heads act as virtual
+    consumers past the end: a head produced mid-graph dirties every later
+    cut, so a scanned run can never hide a value a caller reads.
+
+    Left out: an edge that CANNOT lie inside a layer of any run.  A run is
+    at least `min_run` layers of equal length among the n ops, so a layer
+    is n // min_run ops at most, and an edge longer than that has an end
+    outside every layer it touches: it is a value made in front of a stack
+    and read behind it (the weights and targets of a loss that the graph
+    derives from its input), not a layer's residual.  `scan_plan` then
+    holds each run to what made leaving it out sound: the edge starts in
+    front of the run's first layer and ends behind its last
+    (`_bypassed`).  A shorter edge could be either and counts as a
+    layer's own, as every edge did before: a stack under such an edge
+    stays unfolded (tests/test_scan_layers.py states both outcomes)."""
     n = len(ops)
     dirty = [False] * n
     spans = []
@@ -389,10 +401,20 @@ def _clean_cuts(ops, pos, heads):
     for hnode, _ in heads:
         if not hnode.is_variable:
             spans.append((pos[id(hnode)], n))
-    for i, j in spans:
+    long = [(i, j) for i, j in spans if j - i > n // min_run and j < n]
+    for i, j in set(spans) - set(long):
         for p in range(i + 1, j):
             dirty[p] = True
-    return [p for p in range(n) if not dirty[p]]
+    return [p for p in range(n) if not dirty[p]], long
+
+
+def _bypassed(segments, pos, long):
+    """Whether every long edge left out of the cuts passes over the run
+    whole (or misses it): from in front of its first op to behind its
+    last."""
+    first, last = pos[id(segments[0][0])], pos[id(segments[-1][-1])]
+    return all((i < first and j > last) or j <= first or i >= last
+               for i, j in long)
 
 
 def _seg_signature(seg, seg_ids, prev_boundary, aux_ids):
@@ -502,7 +524,7 @@ def scan_plan(symbol, min_run=SCAN_MIN_RUN):
             if src.is_variable:
                 var_consumers.setdefault(id(src), []).append(n)
 
-    cuts = _clean_cuts(ops, pos, heads)
+    cuts, long = _clean_cuts(ops, pos, heads, max(min_run, 2))
     if len(cuts) < 2:
         return out
     # segments between consecutive clean cuts (first segment starts at 0)
@@ -583,6 +605,8 @@ def scan_plan(symbol, min_run=SCAN_MIN_RUN):
                 for s in range(len(metas[0][3]))]
         reason = _run_eligible(segments, params, auxs, head_nodes,
                                var_consumers, heads)
+        if reason is None and not _bypassed(segments, pos, long):
+            reason = "a value made in front of the run is read inside it"
         if reason is None:
             carry_src = None
             seg0_ids = {id(n) for n in segments[0]}

@@ -15,17 +15,24 @@ hybrid of the LFM2 mixture-of-experts family (`Lfm2MoeLM`,
 `lfm2_moe_symbol`): a dense layer in front of routed ones, mixers by a
 published list, a sigmoid router with a selection bias, a tied head; it
 reuses `qwen3_next.py`'s attention mixer and sparse layer; training path
-only.
+only.  `sdar.py` is the block-diffusion LM of the SDAR mixture-of-experts
+family (`SdarMoeLM`, `sdar_moe_symbol`): the graph corrupts the clean
+sequence (`BlockDiffusionNoise`), runs [noisy | clean] rows through
+attention layers under the block-diffusion mask with routed experts, and
+weighs the noisy copy's loss by m / t; the same mixer and sparse layer;
+training path only.
 """
 from .model import (LMConfig, TransformerBlock, TransformerLM, lm_symbol,
                     lm_block_op_count)
 from .qwen3_next import (Qwen3NextConfig, Qwen3NextLM, Qwen3NextBlock,
                          qwen3_next_symbol)
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeLM, Lfm2MoeBlock, lfm2_moe_symbol)
+from .sdar import SdarMoeConfig, SdarMoeLM, SdarMoeBlock, sdar_moe_symbol
 from .decode_core import (DecodePrograms, stack_lm_params, init_kv_cache)
 
 __all__ = ["LMConfig", "TransformerBlock", "TransformerLM", "lm_symbol",
            "lm_block_op_count", "Qwen3NextConfig", "Qwen3NextLM",
            "Qwen3NextBlock", "qwen3_next_symbol", "Lfm2MoeConfig", "Lfm2MoeLM",
-           "Lfm2MoeBlock", "lfm2_moe_symbol", "DecodePrograms",
+           "Lfm2MoeBlock", "lfm2_moe_symbol", "SdarMoeConfig", "SdarMoeLM",
+           "SdarMoeBlock", "sdar_moe_symbol", "DecodePrograms",
            "stack_lm_params", "init_kv_cache"]

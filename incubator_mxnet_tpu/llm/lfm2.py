@@ -33,7 +33,7 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..parallel.expert_parallel import ExpertShare
 from .qwen3_next import (RMSNorm, GatedAttentionMixer, SparseMoE, _dense,
-                         _loss_symbol)
+                         _from_keys, _loss_symbol)
 
 ROUTER_EPS = 1e-6   # the family adds it to the chosen scores' sum
 
@@ -92,14 +92,7 @@ class Lfm2MoeConfig:
         d.setdefault("rope_theta",
                      (d.get("rope_parameters") or {}).get("rope_theta",
                                                           cls.rope_theta))
-        held = d.get("experts_held")
-        if isinstance(held, dict):
-            d["num_experts"] = int(held.get("of", d.get("num_experts")))
-            d["experts_held"] = ExpertShare(
-                d["num_experts"], int(held.get("offset", 0)),
-                int(held["count"]))
-        return cls(**{k: v for k, v in d.items()
-                      if k in cls.__dataclass_fields__})
+        return _from_keys(cls, d)
 
     # what the mixers of `llm/qwen3_next.py` read under that family's names
     @property

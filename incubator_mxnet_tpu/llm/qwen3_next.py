@@ -64,18 +64,24 @@ class Qwen3NextConfig:
     def from_dict(cls, d):
         """From a dict of the family's keys; `experts_held` may be a dict
         {"offset", "count", "of"} (`of`: the experts routed over)."""
-        d = dict(d)
-        held = d.get("experts_held")
-        if isinstance(held, dict):
-            d["num_experts"] = int(held.get("of", d.get("num_experts")))
-            d["experts_held"] = ExpertShare(
-                d["num_experts"], int(held.get("offset", 0)),
-                int(held["count"]))
-        return cls(**{k: v for k, v in d.items()
-                      if k in cls.__dataclass_fields__})
+        return _from_keys(cls, d)
 
     def is_attention_layer(self, i):
         return (i + 1) % self.full_attention_interval == 0
+
+
+def _from_keys(cls, d):
+    """A configuration dataclass from a dict of its family's keys (others
+    are passed over); `experts_held` may be a dict {"offset", "count",
+    "of"}, `of` the experts routed over."""
+    d = dict(d)
+    held = d.get("experts_held")
+    if isinstance(held, dict):
+        d["num_experts"] = int(held.get("of", d.get("num_experts")))
+        d["experts_held"] = ExpertShare(
+            d["num_experts"], int(held.get("offset", 0)), int(held["count"]))
+    return cls(**{k: v for k, v in d.items()
+                  if k in cls.__dataclass_fields__})
 
 
 def _dense(units, in_units, dtype, prefix):
@@ -163,12 +169,18 @@ class GatedAttentionMixer(HybridBlock):
     (`zero_centered` or plain), a rotary embedding on
     `cfg.partial_rotary_factor` of a head and, with `gate`, a sigmoid gate
     on its output, projected beside q.  `llm/lfm2.py` takes it without the
-    gate, with plain norms and the rotary embedding on the whole head."""
+    gate, with plain norms and the rotary embedding on the whole head;
+    `llm/sdar.py` likewise, under another `mask` (`BlockwiseAttention`'s
+    mask parameters; causal unless told) and with the time axis holding
+    `copies` copies of the sequence's positions."""
 
-    def __init__(self, cfg, gate=True, zero_centered=True, **kwargs):
+    def __init__(self, cfg, gate=True, zero_centered=True, mask=None,
+                 copies=1, **kwargs):
         super().__init__(**kwargs)
         c, dt = cfg.hidden_size, cfg.param_dtype
         self._cfg, self._gate = cfg, gate
+        self._mask = dict(mask or {"causal": True})
+        self._copies = {} if copies == 1 else {"copies": int(copies)}
         h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         with self.name_scope():
@@ -187,7 +199,7 @@ class GatedAttentionMixer(HybridBlock):
         h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         rope = {"rotary_dim": int(d * cfg.partial_rotary_factor),
-                "base": cfg.rope_theta}
+                "base": cfg.rope_theta, **self._copies}
         q = qg = self.q_proj(x)
         if self._gate:
             q = F.slice_axis(qg, axis=-1, begin=0, end=h * d)
@@ -200,7 +212,7 @@ class GatedAttentionMixer(HybridBlock):
         attn = F.BlockwiseAttention(
             F.Reshape(q, shape=(0, 0, -1)), F.Reshape(k, shape=(0, 0, -1)),
             self.v_proj(x), name="attention", num_heads=h, num_kv_heads=kv,
-            causal=True)
+            **self._mask)
         if self._gate:
             attn = attn * F.Activation(gate, act_type="sigmoid")
         return self.out_proj(attn)
@@ -328,7 +340,13 @@ def _loss_symbol(model, vocab_size, prefix, variable_init):
     logits = model(sym.Variable("data"))                 # (B, T, V)
     pred = sym.Reshape(logits, shape=(-1, vocab_size))
     label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
-    out = sym.SoftmaxOutput(pred, label, name="softmax")
+    return _init_variables(sym.SoftmaxOutput(pred, label, name="softmax"),
+                           prefix, variable_init)
+
+
+def _init_variables(out, prefix, variable_init):
+    """`out`, its variables told how a fresh `Module.fit` initialises
+    them."""
     for node in out._topo():
         if node.is_variable and node.name.startswith(prefix):
             init = next((i for suffix, i in variable_init

@@ -15,9 +15,10 @@ head, in two grid forms, and one backward kernel:
   heads that share the key-value head, stacked as r·block_q rows of D, and
   the head's whole K and V in VMEM (fetched once a head: their block index
   does not move over its query blocks, so they are read once for the r
-  heads they serve and never repeated in HBM).  A loop walks tiles of
-  `block_k` keys: tiles above the diagonal never run, tiles under it skip
-  the mask, the ones on it pay the mask's VPU passes.  Scores, running
+  heads they serve and never repeated in HBM).  Loops walk tiles of keys
+  as `tile_runs` states them for the mask (below): tiles the mask empties
+  never run, tiles it leaves whole skip the mask, the others pay the
+  mask's VPU passes.  Scores, running
   maximum, running sum and accumulator are float32; the probabilities are
   rounded to V's type for the second product; the softmax scale is folded
   into q where that is exact (a power of two: 1/8 and 1/16 at heads of 64
@@ -37,6 +38,21 @@ head, in two grid forms, and one backward kernel:
   blocks -- the r query heads' contributions are summed by being rows of
   one product; ds·k goes to the block's dQ.  Five products and one exp a
   score, no block of scores in HBM.
+
+**The mask** is a parameter, `(kind, block_length)`: ("none", 0),
+("causal", 0) or ("block_diffusion", B), the last over a sequence of 2L
+rows and keys [noisy copy | clean copy], both copies at positions 0..L-1
+in blocks of B (b(i) = i // B): a noisy row sees the noisy keys of its own
+block and the clean keys of the blocks before it, a clean row the clean
+keys of its own block and of those before it (BD3-LM, arXiv:2503.09573).
+`tile_runs` is the ONE statement of which tiles of keys a block of queries
+runs unmasked, runs under the mask and never runs; the forward kernel, the
+backward kernel and the streaming forward walk it with traced bounds,
+`ops/attention._xla_blocks` and the tile counters with Python integers.
+For `block_diffusion` a block of noisy queries runs the few keys of its
+own diagonal as narrow tiles (128 keys where the blocks are shorter), and
+the in-tile mask compares a column of the rows' block numbers with a row of
+the keys': one pass over the tile.
 
 **Heads of 64** are half a lane tile, and nothing is done about it: K, V
 and q tiles are padded to 128 lanes in VMEM (HBM holds them dense), the
@@ -105,17 +121,107 @@ _NN = (((1,), (0,)), ((), ()))
 I32 = np.int32
 
 
+NONE, CAUSAL = ("none", 0), ("causal", 0)
+
+
+def _traced(x):
+    return not isinstance(x, (bool, int, np.integer))
+
+
+def _div(a, b):
+    return jax.lax.div(a, b) if _traced(a) else a // b
+
+
+def _least(a, b):
+    return jnp.minimum(a, b) if _traced(a) else min(a, b)
+
+
+def _clamp(x, hi):
+    return jax.lax.clamp(I32(0), x, hi) if _traced(x) else min(max(x, 0), hi)
+
+
+def _pick(cond, a, b):
+    return jnp.where(cond, a, b) if _traced(cond) else (a if cond else b)
+
+
+def tile_runs(mask, q_first, bq, block_k, kv_len, narrow=None):
+    """The walk of one block of `bq` queries over the keys, as runs
+    `(first, last, width, rule)`: tiles first .. last - 1 of `width` keys
+    (tile i holds keys i width .. (i + 1) width - 1) run, under the in-tile
+    mask `rule` ("causal", "diagonal", "clean") or, where it is None,
+    whole; no other tile runs.  `q_first`: for "causal" the first query's
+    position past the first key's; for "block_diffusion" its row among the
+    2L (a block of queries lies in one copy, and a tile in one).  A Python
+    integer gives Python integers (XLA's form, the counters), a traced one
+    traced bounds (the kernels' loops).
+
+    causal: the tiles strictly under the diagonal whole, the ones that
+    touch it masked, the rest never.  block_diffusion (L = kv_len / 2, B
+    the block length, blocks lo .. hi hold the queries; `lag` 1 for noisy
+    queries, which see the clean blocks BEFORE their own, 0 for clean ones,
+    which see their own too): noisy queries run the noisy keys of blocks lo
+    .. hi as tiles of `narrow` keys ("diagonal"; whole where one block
+    holds the queries and the tile), every query the clean keys before
+    (lo + 1 - lag) B whole and those up to (hi + 1 - lag) B masked
+    ("clean")."""
+    kind, B = mask
+    # int32 throughout: under jax_enable_x64 a Python 0 is 64 bits wide,
+    # which Mosaic cannot lower
+    c = I32 if _traced(q_first) else int
+    nk = c(kv_len // block_k)
+    if kind == "none":
+        return [(c(0), nk, block_k, None)]
+    if kind == "causal":
+        n_whole = _clamp(_div(q_first, c(block_k)), nk)
+        return [(c(0), n_whole, block_k, None),
+                (n_whole, _clamp(_div(q_first + c(bq - 1 + block_k),
+                                      c(block_k)), nk), block_k, "causal")]
+    L, w, B = c(kv_len // 2), narrow or block_k, c(B)
+    noisy = q_first < L
+    lag = _pick(noisy, c(1), c(0))
+    p0 = q_first - _pick(noisy, c(0), L)
+    lo, hi = _div(p0, B), _div(p0 + c(bq - 1), B)
+    # the noisy keys of the queries' own blocks: none for clean queries
+    a0 = _div(lo * B, c(w))
+    a1 = _pick(noisy, _div(_least((hi + c(1)) * B, L) + c(w - 1), c(w)), a0)
+    runs = [(a0, a1, w, "diagonal")]
+    if B >= w:
+        # tiles inside the one block that holds every query: whole
+        one = lo == hi
+        w0 = _pick(one, _div(lo * B + c(w - 1), c(w)), a1)
+        w1 = _pick(one, _div(_least((lo + c(1)) * B, L), c(w)), a1)
+        w0 = _pick(noisy, w0, a0)
+        w1 = _pick(noisy, _pick(w1 > w0, w1, w0), a0)
+        runs = [(a0, w0, w, "diagonal"), (w0, w1, w, None),
+                (w1, a1, w, "diagonal")]
+    nl = c(kv_len // 2 // block_k)
+    whole = _div(_least((lo + c(1) - lag) * B, L), c(block_k))
+    some = _div(_least((hi + c(1) - lag) * B, L) + c(block_k - 1),
+                c(block_k))
+    return runs + [(nl, nl + whole, block_k, None),
+                   (nl + whole, nl + some, block_k, "clean")]
+
+
+def seen(mask, q_pos, k_pos, period=None):
+    """(queries, keys) booleans from the rows' and keys' positions: the
+    mask written out (XLA's form; the kernels' in-tile rules are its
+    cases).  `period`: the L of `block_diffusion`."""
+    kind, B = mask
+    q, k = q_pos[:, None], k_pos[None, :]
+    if kind == "causal":
+        return q >= k
+    q_clean, k_clean = q >= period, k >= period
+    qb = (q - jnp.where(q_clean, period, 0)) // B
+    kb = (k - jnp.where(k_clean, period, 0)) // B
+    return jnp.where(k_clean, jnp.where(q_clean, kb <= qb, kb < qb),
+                     jnp.logical_and(jnp.logical_not(q_clean), kb == qb))
+
+
 def _rows_of(q_ref):
     """The block's queries as rows: (1, r, block_q, D) -> (r block_q, D),
     query head j of the group in rows j block_q .. (j + 1) block_q."""
     _, r, bq, d = q_ref.shape
     return q_ref[0].reshape(r * bq, d), r, bq
-
-
-def _row_positions(q_start, r, bq, block_k):
-    """(r block_q, block_k) sequence positions of the stacked rows."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (r * bq, block_k), 0)
-    return q_start + (jax.lax.rem(row, I32(bq)) if r > 1 else row)
 
 
 def _init(acc_scr, m_scr, l_scr):
@@ -124,17 +230,57 @@ def _init(acc_scr, m_scr, l_scr):
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
 
-def _tile_step(q, ks, vs, acc_scr, m_scr, l_scr, scale, q_pos, k_start):
-    """One (rows, block_k) tile of the online-softmax recurrence: scores,
+class _Rows:
+    """What the in-tile mask needs of a grid step's stacked rows, made once
+    a step: for "causal" their positions as a whole tile of `block_k` keys
+    (what PR 35's kernels compared), for "block_diffusion" the number of
+    each row's block inside its copy, as a column (or, `across`, for the
+    backward kernel's transposed tiles, as a row), and `lag`."""
+
+    def __init__(self, mask, q_start, r, bq, block_k, kv_len, across=False):
+        self.mask, self.across = mask, across
+        kind, B = mask
+        if kind == "causal":
+            shape = (block_k, r * bq) if across else (r * bq, block_k)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, int(across))
+            self.q_pos = q_start + (jax.lax.rem(row, I32(bq)) if r > 1
+                                    else row)
+        elif kind == "block_diffusion":
+            L = I32(kv_len // 2)
+            noisy = q_start < L
+            self.lag = jnp.where(noisy, I32(1), I32(0))
+            shape = (1, r * bq) if across else (r * bq, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, int(across))
+            within = jax.lax.rem(row, I32(bq)) if r > 1 else row
+            self.block = jax.lax.div(
+                q_start - jnp.where(noisy, I32(0), L) + within, I32(B))
+            self.period = L
+
+    def seen(self, rule, shape, k_start):
+        """The tile's booleans under `rule`, keys from `k_start` on."""
+        axis = 0 if self.across else 1
+        if rule == "causal":
+            return self.q_pos >= k_start + jax.lax.broadcasted_iota(
+                jnp.int32, shape, axis)
+        width = shape[axis]
+        key = jax.lax.broadcasted_iota(
+            jnp.int32, (width, 1) if self.across else (1, width), axis)
+        if rule == "diagonal":
+            return jax.lax.div(k_start + key, I32(self.mask[1])) == self.block
+        return jax.lax.div(k_start - self.period + key,
+                           I32(self.mask[1])) + self.lag <= self.block
+
+
+def _tile_step(q, ks, vs, acc_scr, m_scr, l_scr, scale, seen):
+    """One (rows, width) tile of the online-softmax recurrence: scores,
     running maximum and sum and the accumulator float32, the probabilities
-    rounded to the value's type for the second product.  `q_pos` None: no
-    key of the tile is masked for any row."""
+    rounded to the value's type for the second product.  `seen(shape)`: the
+    tile's mask; None: no key of the tile is masked for any row."""
     s = jax.lax.dot_general(q, ks, _NT, preferred_element_type=jnp.float32)
     if scale != 1.0:
         s = s * np.float32(scale)
-    if q_pos is not None:
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG)
+    if seen is not None:
+        s = jnp.where(seen(s.shape), s, _NEG)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -155,66 +301,56 @@ def _finish(o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr, normalize):
     l_ref[0, 0] = l_scr[:, 0]
 
 
-def _walk_tiles(tile, q_first, bq, block_k, kv_len, causal):
-    """`tile(i, masked)` over the tiles of `block_k` keys that a block of
-    `bq` queries sees, the first of them `q_first` positions after the
-    first key.  Causal, the walk splits at the diagonal: tiles strictly
-    above it are fully masked and never execute (the structural causal win
-    the unfused path cannot have -- it always materializes all T x T
-    scores); tiles strictly below need no mask at all; only
-    diagonal-touching tiles pay the mask's VPU passes.  Offsets are traced
-    ring positions, so both bounds are dynamic."""
-    # int32 throughout: under jax_enable_x64 a Python 0 is 64 bits wide,
-    # which Mosaic cannot lower
-    zero, nk = I32(0), I32(kv_len // block_k)
-
-    def loop(first, last, masked):
-        jax.lax.fori_loop(first, last, lambda i, _: tile(i, masked), None)
-
-    if not causal:
-        return loop(zero, nk, False)
-    n_unmasked = jax.lax.clamp(zero, jax.lax.div(q_first, I32(block_k)), nk)
-    loop(zero, n_unmasked, False)
-    loop(n_unmasked, jax.lax.clamp(
-        zero, jax.lax.div(q_first + I32(bq - 1 + block_k), I32(block_k)),
-        nk), True)
+def _walk_tiles(tile, runs):
+    """`tile(i, width, rule)` over the runs of tiles that `tile_runs`
+    states for a block of queries: a loop a run, its bounds traced (offsets
+    are ring positions), so a tile the mask empties never executes (the
+    structural win the unfused path cannot have -- it always materializes
+    all T x T scores), a whole tile pays no mask, and only the others pay
+    the mask's VPU passes."""
+    for first, last, width, rule in runs:
+        jax.lax.fori_loop(
+            first, last,
+            lambda i, _, width=width, rule=rule: tile(i, width, rule), None)
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
                 o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr,
-                *, block_k, causal, kv_len, scale, normalize):
+                *, block_k, mask, narrow, kv_len, scale, normalize):
     """Whole-KV kernel: the head's K and V stay in VMEM over its query
-    blocks (read from HBM once for the r query heads they serve), a loop
-    walks their tiles."""
+    blocks (read from HBM once for the r query heads they serve), loops
+    walk their tiles."""
     from jax.experimental import pallas as pl
 
     q, r, bq = _rows_of(q_ref)
     _init(acc_scr, m_scr, l_scr)
     q_start = qoff_ref[0] + pl.program_id(1) * I32(bq)
-    q_pos = _row_positions(q_start, r, bq, block_k) if causal else None
+    rows = _Rows(mask, q_start, r, bq, block_k, kv_len)
 
-    def tile(i, masked):
-        first = pl.multiple_of(i * I32(block_k), block_k)
-        at = pl.ds(first, block_k)
+    def tile(i, width, rule):
+        first = pl.multiple_of(i * I32(width), width)
+        at = pl.ds(first, width)
         _tile_step(q, k_ref[0, at, :], v_ref[0, at, :], acc_scr, m_scr,
-                   l_scr, scale, q_pos if masked else None,
-                   koff_ref[0] + first)
+                   l_scr, scale, rule and (lambda shape: rows.seen(
+                       rule, shape, koff_ref[0] + first)))
 
-    _walk_tiles(tile, q_start - koff_ref[0], bq, block_k, kv_len, causal)
+    _walk_tiles(tile, tile_runs(mask, q_start - koff_ref[0], bq, block_k,
+                                kv_len, narrow))
     _finish(o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr, normalize)
 
 
 def _fwd_kernel_stream(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
                        o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr,
-                       *, block_k, causal, scale, normalize):
+                       *, block_k, mask, kv_len, scale, normalize):
     """KV-streaming variant: one (BH, q-block, KV-tile) grid step per
     invocation, accumulator carried in VMEM scratch across the innermost
     grid axis.  Holds only ONE (block_k, D) K/V tile in VMEM at a time, so
     kv_len is bounded by HBM, not VMEM -- the long-context envelope
-    (T=32k+ causal) the whole-KV kernel cannot reach.  Causal grid steps
-    entirely above the diagonal skip their compute via pl.when, and their
-    tile is not fetched (`_kernel_forward`'s index map stays at the last
-    tile the block of queries sees); those under it skip the mask."""
+    (T=32k+ causal) the whole-KV kernel cannot reach.  A grid step whose
+    tile lies in no run of `tile_runs` skips its compute via pl.when, and
+    a tile past the last one the block of queries sees is not fetched
+    (`_kernel_forward`'s index map stays there); a whole tile skips the
+    mask."""
     from jax.experimental import pallas as pl
 
     j = pl.program_id(2)
@@ -227,19 +363,18 @@ def _fwd_kernel_stream(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
     q_start = qoff_ref[0] + pl.program_id(1) * I32(bq)
     k_start = koff_ref[0] + j * I32(block_k)
 
-    def tile(masked):
-        q_pos = _row_positions(q_start, r, bq, block_k) if masked else None
+    def tile(rule):
+        rows = rule and _Rows(mask, q_start, r, bq, block_k, kv_len)
         _tile_step(q, k_ref[0], v_ref[0], acc_scr, m_scr, l_scr, scale,
-                   q_pos, k_start)
+                   rule and (lambda shape: rows.seen(rule, shape, k_start)))
 
-    if causal:
-        below = q_start >= k_start + I32(block_k - 1)
-        pl.when(below)(lambda: tile(False))
-        pl.when(jnp.logical_and(jnp.logical_not(below),
-                                q_start + I32(bq - 1) >= k_start))(
-            lambda: tile(True))
+    if mask[0] == "none":
+        tile(None)
     else:
-        tile(False)
+        for first, last, _, rule in tile_runs(
+                mask, q_start - koff_ref[0], bq, block_k, kv_len):
+            pl.when(jnp.logical_and(j >= first, j < last))(
+                lambda rule=rule: tile(rule))
 
     @pl.when(j == pl.num_programs(2) - I32(1))
     def _():
@@ -267,26 +402,29 @@ def _streams(kv_len, d, itemsize):
 # recomputed forward, each fit's shape inference) finds the kernel's body
 # traced.
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "scale", "normalize", "stream",
+    "mask", "narrow", "block_q", "block_k", "scale", "normalize", "stream",
     "interpret"))
-def _kernel_forward(q4, k3, v3, q_off, k_off, *, causal, block_q, block_k,
-                    scale=1.0, normalize=False, stream=False,
-                    interpret=False):
+def _kernel_forward(q4, k3, v3, q_off, k_off, *, block_q, block_k,
+                    mask=NONE, narrow=None, scale=1.0, normalize=False,
+                    stream=False, interpret=False):
     """q4 (BH, r, Tq, D) against k3 (BH, S, D) and v3 (BH, S, Dv): o (BH,
     r, Tq, Dv) in q's type (the accumulator, or with `normalize` the
     output), and per row the float32 maximum and sum of exponentials, each
     (BH, 1, Tq r) BLOCK by block: (query block, head of the group, row).
-    `block_q` divides Tq and `block_k` S."""
+    `block_q` divides Tq and `block_k` S.  `mask`: the kernels' mask
+    parameter; `narrow`: the width of the tiles on
+    `block_diffusion`'s noisy diagonal (the whole-KV form only)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, r, Tq, D = q4.shape
     kv_len, Dv = k3.shape[1], v3.shape[2]
     rows = r * block_q
+    causal = mask == CAUSAL
     # int32 throughout: under jax_enable_x64 a Python 0 in an index map is
     # 64 bits wide, which Mosaic cannot lower
     zero = I32(0)
-    common = dict(block_k=block_k, causal=causal, scale=scale,
+    common = dict(block_k=block_k, mask=mask, kv_len=kv_len, scale=scale,
                   normalize=normalize)
     if stream:
         grid = (BH, Tq // block_q, kv_len // block_k)
@@ -305,7 +443,7 @@ def _kernel_forward(q4, k3, v3, q_off, k_off, *, causal, block_q, block_k,
         # the whole (kv_len, D) K and V of a head in VMEM: fast, and the
         # loop's dynamic bounds skip above-diagonal tiles entirely
         grid = (BH, Tq // block_q)
-        kernel = functools.partial(_fwd_kernel, kv_len=kv_len, **common)
+        kernel = functools.partial(_fwd_kernel, narrow=narrow, **common)
         kv_rows = kv_len
 
         def tile(b, i, qoff, koff):
@@ -343,7 +481,7 @@ def _kernel_forward(q4, k3, v3, q_off, k_off, *, causal, block_q, block_k,
 
 def _bwd_kernel(qoff_ref, koff_ref, q_ref, do_ref, lse_ref, delta_ref,
                 k_ref, v_ref, dq_ref, dk_ref, dv_ref, dq_scr,
-                *, block_k, causal, kv_len, scale, dq_scale):
+                *, block_k, mask, narrow, kv_len, scale, dq_scale):
     """The backward pass of `_fwd_kernel`, one query block a grid step: the
     scores of a tile are computed once more, TRANSPOSED (keys down, the
     stacked rows across), so that the row's log-sum-exp and `delta` are
@@ -366,22 +504,19 @@ def _bwd_kernel(qoff_ref, koff_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
     q_start = qoff_ref[0] + i * I32(bq)
-    if causal:
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_k, r * bq), 1)
-        q_pos = q_start + (jax.lax.rem(col, I32(bq)) if r > 1 else col)
+    rows = _Rows(mask, q_start, r, bq, block_k, kv_len, across=True)
 
-    def tile(j, masked):
-        first = pl.multiple_of(j * I32(block_k), block_k)
-        at = pl.ds(first, block_k)
+    def tile(j, width, rule):
+        first = pl.multiple_of(j * I32(width), width)
+        at = pl.ds(first, width)
         ks, vs = k_ref[0, at, :], v_ref[0, at, :]
         s = jax.lax.dot_general(ks, q, _NT,
                                 preferred_element_type=jnp.float32)
         if scale != 1.0:
             s = s * np.float32(scale)
-        if masked:
-            k_pos = koff_ref[0] + first + \
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+        if rule:
+            s = jnp.where(rows.seen(rule, s.shape, koff_ref[0] + first), s,
+                          _NEG)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(vs, do, _NT,
                                  preferred_element_type=jnp.float32)
@@ -394,16 +529,18 @@ def _bwd_kernel(qoff_ref, koff_ref, q_ref, do_ref, lse_ref, delta_ref,
             ds, ks, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _walk_tiles(tile, q_start - koff_ref[0], bq, block_k, kv_len, causal)
+    _walk_tiles(tile, tile_runs(mask, q_start - koff_ref[0], bq, block_k,
+                                kv_len, narrow))
     dq_ref[0] = (dq_scr[...] * np.float32(dq_scale)).reshape(
         dq_ref.shape[1:]).astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "scale", "dq_scale", "interpret"))
-def _kernel_backward(q4, k3, v3, do4, lse, delta, q_off, k_off, *, causal,
-                     block_q, block_k, scale=1.0, dq_scale=1.0,
-                     interpret=False):
+    "mask", "narrow", "block_q", "block_k", "scale", "dq_scale",
+    "interpret"))
+def _kernel_backward(q4, k3, v3, do4, lse, delta, q_off, k_off, *, block_q,
+                     block_k, mask=NONE, narrow=None, scale=1.0,
+                     dq_scale=1.0, interpret=False):
     """dQ (BH, r, Tq, D) in q's type, float32 dK (BH, S, D) and dV (BH, S,
     Dv) of `_kernel_forward(normalize=True)` at the same blocks: `lse` the
     rows' m + log(l) and `delta` their sum of dO * O, (BH, 1, Tq r) block by
@@ -425,8 +562,9 @@ def _kernel_backward(q4, k3, v3, do4, lse, delta, q_off, k_off, *, causal,
     def whole(b, i, qoff, koff):
         return b, zero, zero
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, block_k=block_k, causal=causal,
-                          kv_len=kv_len, scale=scale, dq_scale=dq_scale),
+        functools.partial(_bwd_kernel, block_k=block_k, mask=mask,
+                          narrow=narrow, kv_len=kv_len, scale=scale,
+                          dq_scale=dq_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(BH, Tq // block_q),
             in_specs=[pl.BlockSpec((1, r, block_q, D), rows_of),
@@ -474,8 +612,8 @@ def _partial_tpu(q3, k3, v3, q_off, k_off, causal, block_q, block_k,
         block_k //= 2
     q3, scale = _fold_scale(q3, D)
     o, m, l = _kernel_forward(
-        q3[:, None], k3, v3, q_off, k_off, causal=causal, block_q=block_q,
-        block_k=block_k, scale=scale,
+        q3[:, None], k3, v3, q_off, k_off, mask=CAUSAL if causal else NONE,
+        block_q=block_q, block_k=block_k, scale=scale,
         stream=_streams(kv_len, D, q3.dtype.itemsize), interpret=interpret)
     return o[:, 0], m, l
 

@@ -15,6 +15,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register, REQUIRED
 from ..base import MXNetError
@@ -49,13 +50,18 @@ def _rms_norm(params, x, gamma, *rest):
                     rest[0] if params.get("gated") else None)
 
 
-def rotary(x, rotary_dim, base):
+def rotary(x, rotary_dim, base, copies=1):
     """x (B, T, H, D): positions 0..T-1 rotate the first `rotary_dim`
-    entries of every head, entry i paired with entry i + rotary_dim / 2."""
+    entries of every head, entry i paired with entry i + rotary_dim / 2.
+    `copies`: the time axis holds that many copies of one sequence, each at
+    positions 0..T/copies - 1 (the positions' period is T / copies)."""
     t, half = x.shape[1], rotary_dim // 2
     inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32)
                                * 2.0 / rotary_dim))
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    position = jnp.arange(t, dtype=jnp.float32)
+    if copies != 1:
+        position = jnp.tile(position[:t // copies], copies)
+    angle = position[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :,
                                                                 None, :]
     xf = x.astype(jnp.float32)
@@ -67,18 +73,23 @@ def rotary(x, rotary_dim, base):
 
 
 @register("RotaryEmbedding",
-          params={"rotary_dim": REQUIRED, "base": 10000.0})
+          params={"rotary_dim": REQUIRED, "base": 10000.0, "copies": 1})
 def _rotary_embedding(params, x):
     """Rotary position embedding on (batch, time, heads, head size) data:
     the first `rotary_dim` entries of each head turn with the position
-    (rotate-half pairing, theta = `base`), the others pass through."""
-    rd = int(params["rotary_dim"])
-    if x.ndim != 4 or rd % 2 or rd > x.shape[-1]:
+    (rotate-half pairing, theta = `base`), the others pass through.
+    `copies` (1 unless told) gives the positions' period as a share of the
+    time axis: time holds that many copies of one sequence, each at
+    positions 0..time/copies - 1, as block-diffusion training's [noisy |
+    clean] rows do; given so, the symbol binds at any length."""
+    rd, copies = int(params["rotary_dim"]), int(params.get("copies") or 1)
+    if x.ndim != 4 or rd % 2 or rd > x.shape[-1] or copies < 1 or \
+            x.shape[1] % copies:
         raise MXNetError(
-            "RotaryEmbedding: data must be (batch, time, heads, head size) "
-            "and rotary_dim even and at most the head size; got %s and %d"
-            % (tuple(x.shape), rd))
-    return rotary(x, rd, float(params["base"]))
+            "RotaryEmbedding: data must be (batch, time, heads, head size), "
+            "rotary_dim even and at most the head size and time a multiple "
+            "of copies; got %s, %d and %d" % (tuple(x.shape), rd, copies))
+    return rotary(x, rd, float(params["base"]), copies)
 
 
 def _causal_conv(xf, weight):
@@ -148,3 +159,94 @@ def _gated_short_conv(params, bcx, weight):
     from .. import obs
     obs.counter("ops.short_conv.lowered.xla").inc()
     return gated_short_conv(bcx, weight)
+
+
+def block_diffusion_noise(ids, block_length, mask_token, low, high,
+                          seed=None, key=None):
+    """(noisy ids, mask, weight) of clean ids (batch, L): every block of
+    `block_length` tokens draws a noise level t uniform on the thousandths
+    of [low, high], each of its tokens is replaced by `mask_token` with
+    probability t (mask 1 there), and the weight is mask / t (BD3-LM's
+    linear schedule, arXiv:2503.09573).  With `seed` a row's draws are
+    `jax.random`'s under fold_in(fold_in(PRNGKey(seed), checksum of the
+    row's ids), 0 for the levels, 1 for the tokens), checksum = sum_i id_i
+    (2 i + 1) mod 2^32: a function of the tokens and the seed alone, the
+    same on every platform, which a reference can make again -- the levels
+    and their reciprocals are tables made on the host, so the device does
+    integer work, look-ups and one comparison, nothing whose rounding a
+    compiler may choose.  Without `seed` the rows split `key`."""
+    batch, length = ids.shape
+    blocks = -(-length // block_length)
+    first, last = round(1000 * low), round(1000 * high)
+    levels = np.arange(first, last + 1, dtype=np.float32) / np.float32(1000)
+    if seed is not None:
+        odd = 2 * jnp.arange(length, dtype=jnp.uint32) + jnp.uint32(1)
+        check = jnp.sum(ids.astype(jnp.int32).astype(jnp.uint32) * odd,
+                        axis=1, dtype=jnp.uint32)
+        base = jax.random.PRNGKey(int(seed))
+        keys = jax.vmap(lambda c: jax.random.fold_in(base, c))(check)
+    else:
+        keys = jax.random.split(key, batch)
+
+    def row(k):
+        level = jnp.repeat(jax.random.randint(
+            jax.random.fold_in(k, 0), (blocks,), 0, len(levels)),
+            block_length)[:length]
+        return jax.random.uniform(jax.random.fold_in(k, 1), (length,),
+                                  jnp.float32) < jnp.asarray(levels)[level], \
+            level
+    masked, level = jax.vmap(row)(keys)
+    mask = masked.astype(jnp.float32)
+    return jnp.where(masked, jnp.asarray(mask_token, ids.dtype), ids), \
+        mask, mask * jnp.asarray(np.float32(1) / levels)[level]
+
+
+def _noise_counters(deltas):
+    """An epoch's noise, from what every `BlockDiffusionNoise` node of a
+    graph has added to `stats` (`OpDef.counters`)."""
+    rows, masked, weight = (float(sum(d["stats"][i] for d in deltas))
+                            for i in range(3))
+    args = {"rows": int(rows), "masked": int(masked), "weight_sum": weight,
+            "masked_share": masked / rows if rows else 0.0}
+    return {"span": "diffusion.noise", "args": args,
+            "counters": {"diffusion.rows": args["rows"],
+                         "diffusion.masked": args["masked"],
+                         "diffusion.weight_sum": weight},
+            "gauges": {}}
+
+
+@register("BlockDiffusionNoise", nin=2, nout=3, naux=1, needs_rng=True,
+          mode_dependent=True,
+          params={"block_length": REQUIRED, "mask_token": REQUIRED,
+                  "low": 0.001, "high": 1.0, "seed": None},
+          input_names=["data", "stats"], counters=_noise_counters)
+def _block_diffusion_noise(params, ids, stats, key):
+    """The corruption of block-diffusion training, in the graph: clean ids
+    (batch, L) in; the noisy ids (a masked token is `mask_token`), the mask
+    m and the weight m / t out, t a block's noise level, uniform on the
+    thousandths of [`low`, `high`] (`block_diffusion_noise`).  With `seed` the draw is a function
+    of the tokens and the seed alone; without it the graph's random
+    resource is drawn from, as `Dropout` does: fresh noise every step.  The
+    auxiliary state `stats` (3,) is added to in every training step: rows
+    seen, rows masked, the weights' sum."""
+    block, low, high = int(params["block_length"]), float(params["low"]), \
+        float(params["high"])
+    if ids.ndim != 2 or block < 1 or stats.shape != (3,) or \
+            not 0.0005 <= low <= high <= 1.0:
+        raise MXNetError(
+            "BlockDiffusionNoise: data must be (batch, length) ids, "
+            "block_length >= 1, 0.001 <= low <= high <= 1 and stats (3,); got "
+            "%s, %d, [%g, %g] and %s" % (tuple(ids.shape), block, low, high,
+                                         tuple(stats.shape)))
+    seed = params.get("seed")
+    noisy, mask, weight = block_diffusion_noise(
+        ids, block, float(params["mask_token"]), low, high,
+        None if seed is None else int(seed), key)
+    from .. import obs
+    obs.counter("ops.diffusion_noise.lowered." +
+                ("seeded" if seed is not None else "random")).inc()
+    if not params.get("_train", False):
+        return noisy, mask, weight
+    return noisy, mask, weight, stats + jnp.stack([
+        jnp.float32(ids.size), jnp.sum(mask), jnp.sum(weight)]).astype(
+            stats.dtype)

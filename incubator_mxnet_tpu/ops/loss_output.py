@@ -17,16 +17,27 @@ from ..base import MXNetError
 _SOFTMAX_OUT_PARAMS = {
     "grad_scale": 1.0, "ignore_label": -1.0, "multi_output": False,
     "use_ignore": False, "preserve_shape": False, "normalization": "null",
-    "out_grad": False, "smooth_alpha": 0.0,
+    "out_grad": False, "smooth_alpha": 0.0, "use_weight": False,
 }
 
 
-@register("SoftmaxOutput", nin=2, params=dict(_SOFTMAX_OUT_PARAMS),
-          aliases=("Softmax",), input_names=["data", "label"])
-def _softmax_output(params, data, label):
+@register("SoftmaxOutput", nin=-1, params=dict(_SOFTMAX_OUT_PARAMS),
+          aliases=("Softmax",),
+          input_names=lambda p: ["data", "label"] +
+          (["weight"] if p.get("use_weight") else []))
+def _softmax_output(params, data, label, *weight):
     """Forward = softmax; backward = (softmax - onehot(label)) * grad_scale,
     with ignore-label masking and normalization (reference
-    `softmax_output-inl.h` SoftmaxOutputBackward)."""
+    `softmax_output-inl.h` SoftmaxOutputBackward).  With `use_weight` a
+    third input of the label's shape weighs each row's gradient, (softmax -
+    onehot) * weight under the same normalizations (an objective that
+    weighs its rows, such as masked diffusion's m / t); the forward pass is
+    the probabilities as before, and no gradient reaches the weight."""
+    weighted = bool(params.get("use_weight"))
+    if len(weight) != weighted:
+        raise MXNetError(
+            "SoftmaxOutput: %d inputs after the label, use_weight=%s takes "
+            "%d" % (len(weight), weighted, weighted))
     multi = bool(params["multi_output"])
     preserve = bool(params["preserve_shape"])
     axis = 1 if multi else -1
@@ -43,6 +54,7 @@ def _softmax_output(params, data, label):
         # data is treated as (batch, prod(rest)) (softmax_output-inl.h)
         data = data.reshape(orig_shape[0], -1)
         label = label.reshape(orig_shape[0])
+        weight = tuple(w.reshape(orig_shape[0]) for w in weight)
         flattened = True
 
     # softmax and its (softmax - onehot) gradient run in fp32 even for
@@ -51,22 +63,26 @@ def _softmax_output(params, data, label):
     in_dtype = data.dtype
 
     @jax.custom_vjp
-    def f(d, l):
+    def f(d, l, *w):
         return jax.nn.softmax(d.astype(jnp.float32), axis=axis) \
             .astype(in_dtype)
 
-    def fwd(d, l):
+    def fwd(d, l, *w):
         out = jax.nn.softmax(d.astype(jnp.float32), axis=axis)
-        return out.astype(in_dtype), (out, l)
+        return out.astype(in_dtype), (out, l) + w
 
     def bwd(res, g):
-        out, l = res
+        out, l = res[:2]
         k = out.shape[axis]
         li = l.astype("int32")
         onehot = jax.nn.one_hot(li, k, dtype=out.dtype, axis=axis)
         if smooth > 0:
             onehot = onehot * (1 - smooth) + smooth / (k - 1) * (1 - onehot)
         grad = out - onehot
+        for w in res[2:]:
+            grad = grad * jnp.expand_dims(
+                w.reshape(l.shape), axis if axis != -1 else l.ndim) \
+                .astype(out.dtype)
         if use_ignore:
             mask = (l != ignore)
             mshape = list(l.shape)
@@ -84,10 +100,11 @@ def _softmax_output(params, data, label):
         grad = grad * scale
         if params["out_grad"]:
             grad = grad * g.astype(out.dtype)
-        return grad.astype(in_dtype), jnp.zeros_like(l)
+        return (grad.astype(in_dtype), jnp.zeros_like(l)) + \
+            tuple(jnp.zeros_like(w) for w in res[2:])
 
     f.defvjp(fwd, bwd)
-    out = f(data, label)
+    out = f(data, label, *weight)
     if flattened:
         out = out.reshape(orig_shape)
     return out

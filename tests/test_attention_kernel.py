@@ -92,7 +92,7 @@ def test_kernels_interpreted_without_a_mask(interpreted):
     """`causal=False`: every tile runs and none is masked."""
     args = _inputs("more_keys")
     grads, out = _value_and_grads(functools.partial(
-        attention.grouped_query_attention, causal=False), args)
+        attention.grouped_query_attention, mask=flash_attention.NONE), args)
     want_grads, want = _value_and_grads(functools.partial(
         _whole_matrix, causal=False), args)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
@@ -211,7 +211,7 @@ def test_kernels_compile_for_the_v5e(one_chip, uncached, config, calls):
             for dims in CELLS[config]]
 
     def fn(q, k, v):
-        return attention._flash(q, k, v, True, False)
+        return attention._flash(q, k, v, flash_attention.CAUSAL, False)
     if calls == 2:
         forward = fn
         fn = jax.value_and_grad(lambda *a: jnp.sum(
@@ -235,7 +235,179 @@ def test_plain_flash_attention_compiles_for_the_v5e(one_chip, uncached,
 
     def fn(q4, k3, v3):
         return flash_attention._kernel_forward(
-            q4, k3, v3, 0, 0, causal=True, block_q=256, block_k=256,
+            q4, k3, v3, 0, 0, mask=flash_attention.CAUSAL, block_q=256,
+            block_k=256,
             stream=stream)
     text = jax.jit(fn).lower(q3, k3, k3).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+# -- the block-diffusion mask --------------------------------------------------
+
+def _bd_dense(length, block):
+    """The (2L, 2L) booleans of the block-diffusion mask, from its four
+    lines: rows and keys [noisy copy | clean copy], b(i) = i // block."""
+    b = np.arange(length) // block
+    same, before = b[:, None] == b[None, :], b[None, :] < b[:, None]
+    return np.block([[same, before],
+                     [np.zeros_like(same), same | before]])
+
+
+def _bd_whole_matrix(q, k, v, block):
+    r = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, r, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[3] ** -0.5
+    scores = jnp.where(_bd_dense(q.shape[1] // 2, block), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhe->bqhe", jax.nn.softmax(scores, -1), v)
+
+
+def _bd_inputs(length, r, d=64, batch=1):
+    keys = jax.random.split(jax.random.PRNGKey(length + r), 3)
+    return (jax.random.normal(keys[0], (batch, 2 * length, r, d)),
+            jax.random.normal(keys[1], (batch, 2 * length, 1, d)),
+            jax.random.normal(keys[2], (batch, 2 * length, 1, d)))
+
+
+# the cell's block, one that divides a lane tile, one that does not divide a
+# tile, and one that holds whole tiles (its diagonal runs unmasked tiles)
+@pytest.mark.parametrize("block", [4, 32, 48, 256])
+@pytest.mark.parametrize("r", [1, 8])
+def test_block_diffusion_three_ways(interpreted, block, r):
+    """Forward and the three gradients under `mask=("block_diffusion",
+    B)`: the kernels, interpreted, against XLA's block form against the
+    dense boolean mask written from the four lines."""
+    args = _bd_inputs(256, r)
+    mask = ("block_diffusion", block)
+    before = _counts()
+    grads, out = _value_and_grads(functools.partial(
+        attention.grouped_query_attention, mask=mask), args)
+    assert _counts() == (before[0] + 2, before[1])
+    xla_grads, xla_out = _value_and_grads(functools.partial(
+        attention._xla_blocks, mask=mask, block_size=64), args)
+    want_grads, want = _value_and_grads(functools.partial(
+        _bd_whole_matrix, block=block), args)
+    for got, name in ((out, "kernel"), (xla_out, "xla")):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5,
+                                   err_msg=name)
+    for got in (grads, xla_grads):
+        for g, w, name in zip(got, want_grads, "qkv"):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("block,length,r", [(4, 256, 8), (32, 256, 1),
+                                            (48, 384, 1), (256, 512, 2),
+                                            (4, 4096, 8)])
+def test_tile_runs_cover_the_mask_and_no_more(block, length, r):
+    """`tile_runs` against the dense mask, tile by tile: a tile with a seen
+    entry runs, a whole run's tiles are whole, and a tile that runs masked
+    is neither empty... nor need it be (the statement may mask a whole
+    tile, never skip a live one); the counters' sums are the runs'."""
+    d = 128 if length == 4096 else 64
+    block_q, block_k = attention._tiles(r, 2 * length, 2 * length, d, d, 2,
+                                        length)
+    mask = ("block_diffusion", block)
+    narrow = attention._narrow(mask, block_q, block_k, length)
+    dense = _bd_dense(length, block)
+    whole = masked = 0
+    for first in range(0, 2 * length, block_q):
+        rows = dense[first:first + block_q]
+        live = np.zeros(2 * length, bool)
+        for lo, hi, width, rule in flash_attention.tile_runs(
+                mask, first, block_q, block_k, 2 * length, narrow):
+            for i in range(lo, hi):
+                tile = rows[:, i * width:(i + 1) * width]
+                assert not live[i * width:(i + 1) * width].any()
+                live[i * width:(i + 1) * width] = True
+                assert tile.any(), (first, i, width, rule)
+                if rule is None:
+                    assert tile.all(), (first, i, width)
+                whole += (rule is None) * width // 128
+                masked += (rule is not None) * width // 128
+        assert not rows[:, ~live].any(), first
+    counts = attention.tile_counts(mask, 2 * length, 2 * length, block_q,
+                                   block_k, narrow)
+    assert counts == (whole, masked, 2 * length // block_q *
+                      (2 * length // 128) - whole - masked)
+    if length == 4096:
+        # the cell's shapes: tiles of (128 rows of 8 heads, 1,024 keys),
+        # the noisy diagonal as one tile of 128 keys a block of queries
+        assert (block_q, block_k, narrow) == (128, 1024, 128)
+        assert counts[2] / sum(counts) > 0.6
+        entries = dense.sum() / dense.size
+        assert abs(entries - 0.25) < 0.001
+
+
+def test_tile_counters_count_a_lowered_call(interpreted):
+    names = ("run", "masked", "skipped")
+
+    def read():
+        return tuple(mx.obs.counter("ops.attention.tiles." + n).value
+                     for n in names)
+    args = _bd_inputs(256, 1)
+    mask = ("block_diffusion", 4)
+    fn = functools.partial(attention.grouped_query_attention, mask=mask)
+    want = attention.tile_counts(mask, 512, 512, 256, 256, 128)
+    assert want == (0, 6, 2)        # of 2 blocks of queries x 4 lane tiles
+    before = read()
+    jax.make_jaxpr(fn)(*args)       # the forward kernel's call
+    assert read() == tuple(b + n for b, n in zip(before, want))
+    # the forward rule's call and the backward kernel's count theirs
+    before = read()
+    jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2)))(
+        *args)
+    assert read() == tuple(b + 2 * n for b, n in zip(before, want))
+    # the causal walk counts too: 2 blocks of 128 queries against 3 tiles
+    before = read()
+    jax.make_jaxpr(attention.grouped_query_attention)(*_inputs("more_keys"))
+    assert read()[2] - before[2] == 0 and read()[0] - before[0] == 2 and \
+        read()[1] - before[1] == 1
+
+
+def test_block_diffusion_operator_and_its_refusals(interpreted):
+    from incubator_mxnet_tpu.ops import registry
+    op = registry.get("BlockwiseAttention")
+    q, k, v = _bd_inputs(128, 8)
+    params = op.canonicalize_params({
+        "num_heads": 8, "num_kv_heads": 1, "mask": "block_diffusion",
+        "block_length": 4})
+    out = op.fn(dict(params), q.reshape(1, 256, -1), k.reshape(1, 256, -1),
+                v.reshape(1, 256, -1))
+    np.testing.assert_allclose(
+        out, _bd_whole_matrix(q, k, v, 4).reshape(1, 256, -1), rtol=2e-5,
+        atol=2e-5)
+    # the mask's entries, not the square's
+    flops = op.cost_meta["flops"](params, [jax.ShapeDtypeStruct(
+        (1, 256, 512), jnp.float32)], None)
+    assert flops == 4.0 * 128 * (128 + 4) * 512
+    with pytest.raises(mx.MXNetError, match="block_length"):
+        op.fn(dict(params, block_length=None), q.reshape(1, 256, -1),
+              k.reshape(1, 256, -1), v.reshape(1, 256, -1))
+    with pytest.raises(mx.MXNetError, match="mask"):
+        op.fn(dict(params, mask="window"), q.reshape(1, 256, -1),
+              k.reshape(1, 256, -1), v.reshape(1, 256, -1))
+    with pytest.raises(mx.MXNetError, match="2L"):
+        attention.grouped_query_attention(q, k[:, :128], v[:, :128],
+                                          mask=("block_diffusion", 4))
+
+
+@pytest.mark.parametrize("calls", [1, 2])
+def test_block_diffusion_kernels_compile_for_the_v5e(one_chip, uncached,
+                                                     calls):
+    """`sdar_30b_a3b_chat`'s shape: 2 x (2 x 4,096) rows, 32 / 4 heads of
+    128, blocks of 4."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fn(q, k, v):
+        return attention._flash(q, k, v, ("block_diffusion", 4), False)
+    if calls == 2:
+        forward = fn
+        fn = jax.value_and_grad(lambda *a: jnp.sum(
+            forward(*a).astype(jnp.float32)), argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(q, k, k).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

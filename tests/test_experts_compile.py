@@ -4,7 +4,9 @@ published widths and the timed sizes of both configurations that hold them
 (`qwen3_next_80b_a3b`: 8,192 tokens, hidden 2,048, intermediate 512, 16 of
 512 experts held, 10 a token, a softmax router, one tile; `lfm2_24b_a2b`:
 16,384 tokens, intermediate 1,536, 8 of 64 held, 4 a token, a sigmoid
-router with a selection bias, the backward pass in two tiles of 768): what
+router with a selection bias, the backward pass in two tiles of 768;
+`sdar_30b_a3b_chat`: 16,384 rows, intermediate 768, 16 of 128 held, 8 a
+row, a softmax router, one tile): what
 Mosaic would refuse on the chip (a block off the tiling, an index map it cannot lower,
 more VMEM than the chip has for an expert's matrices, their gradients and
 the float32 sums) it refuses here, at no chip time.  Nothing runs, so
@@ -30,6 +32,8 @@ SIZES = {
     "lfm2_24b_a2b": (16384, 1536, 64, 8, 4, (32768, 33792, 128),
                      experts.Router("sigmoid", True, 1e-6), True,
                      (1536, 768)),
+    "sdar_30b_a3b_chat": (16384, 768, 128, 16, 8, (32768, 34816, 128),
+                          experts.Router(), False, (768, 768)),
 }
 
 
@@ -61,7 +65,7 @@ def _through_the_kernel(config):
     """What `routed_experts` runs on a TPU (here `default_backend()` is the
     CPU, so the driver is named), and the shapes of its arguments."""
     n, inter, num, held, top_k, plan, router, biased, tiles = SIZES[config]
-    factor = {"qwen3_next_80b_a3b": 2.0, "lfm2_24b_a2b": 4.0}[config]
+    factor = {"lfm2_24b_a2b": 4.0}.get(config, 2.0)
     assert experts.capacity(n, top_k, num, held, factor) == plan
     assert experts._tiles(n, C, inter, plan[2], 2) == tiles
     apply = experts._apply_fn(*plan, top_k, 0, router, "kernel", tiles)

@@ -194,6 +194,70 @@ def test_scan_plan_period_grouping_covers_multi_op_layers():
         "each scanned layer must cover its fc AND its activation"
 
 
+def _stack_under_a_weight(n_layers, head=0, tail=0, read_in_layer=None):
+    """A stack under a value that one op in front of it makes and an op
+    behind it reads, as a loss weighs its rows by what the graph drew from
+    its input (`llm/sdar.py`); `head` / `tail`: further ops between that
+    op and the stack, the stack and the reader; or the value read inside
+    layer `read_in_layer` too."""
+    halves = sym.split(sym.Variable("data"), num_outputs=2, axis=1,
+                       name="halves")
+    net, weight = halves[0], halves[1]
+    for i in range(head):
+        net = sym.Activation(net, act_type="tanh", name="head%d" % i)
+    for i in range(n_layers):
+        net = sym.FullyConnected(net, num_hidden=32, name="blk%d_fc" % i)
+        if i == read_in_layer:
+            net = sym.broadcast_mul(net, weight, name="blk%d_w" % i)
+        net = sym.Activation(net, act_type="relu", name="blk%d_relu" % i)
+    for i in range(tail):
+        net = sym.Activation(net, act_type="tanh", name="tail%d" % i)
+    net = sym.broadcast_mul(net, weight, name="weighted")
+    return sym.SoftmaxOutput(net, name="softmax")
+
+
+def _layer_runs(plan):
+    return [r["length"] for r in plan["runs"]
+            if r["segments"][0][0].name.startswith("blk")]
+
+
+@pytest.mark.parametrize("n_layers", [2, 4])
+def test_scan_plan_folds_a_stack_under_an_edge_that_passes_over_it(n_layers):
+    """At two layers too: of the 7 ops the weight's edge spans 6, more
+    than a layer of any run could hold (7 // 2)."""
+    plan = scan_plan(_stack_under_a_weight(n_layers))
+    assert _layer_runs(plan) == [n_layers]
+    covered = {n.name for r in plan["runs"] for seg in r["segments"]
+               for n in seg}
+    assert not {"halves", "weighted"} & covered
+
+
+def test_scan_plan_keeps_an_edge_that_could_be_a_layers_own():
+    """The weight made 3 ops into a graph of 17 and read 7 ops later: an
+    edge no longer than 17 // 2 may be a layer's residual, so it counts,
+    and the two layers under it stay inlined, as they did before any edge
+    was left out: unfolded, not folded around the value."""
+    net = sym.Variable("data")
+    for i in range(3):
+        net = sym.Activation(net, act_type="tanh", name="head%d" % i)
+    halves = sym.split(net, num_outputs=2, axis=1, name="halves")
+    net, weight = halves[0], halves[1]
+    for i in range(3):
+        net = sym.FullyConnected(net, num_hidden=32, name="blk%d_fc" % i)
+        net = sym.Activation(net, act_type="relu", name="blk%d_relu" % i)
+    net = sym.broadcast_mul(net, weight, name="weighted")
+    for i in range(5):
+        net = sym.Activation(net, act_type="tanh", name="tail%d" % i)
+    symbol = sym.SoftmaxOutput(net, name="softmax")
+    assert len([n for n in symbol._topo() if not n.is_variable]) == 17
+    assert _layer_runs(scan_plan(symbol)) == []
+
+
+def test_scan_plan_rejects_a_run_that_reads_the_passing_value():
+    plan = scan_plan(_stack_under_a_weight(4, read_in_layer=1))
+    assert 4 not in _layer_runs(plan)
+
+
 def test_scan_plan_rejects_shared_weights():
     plan = scan_plan(_shared_weight_fc())
     assert not plan["runs"], "shared-weight stack must not be scanned"

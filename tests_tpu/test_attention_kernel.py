@@ -96,10 +96,86 @@ def test_kernels_against_the_float32_reference(config):
         assert g.dtype == given.dtype and g.shape == given.shape
     want = _value_and_grad(_reference, ct)(*args)
     kernels = _gaps(got, want)
-    blocks = _gaps(_value_and_grad(functools.partial(
-        attention._xla_blocks, causal=True), ct)(*args), want)
+    blocks = _gaps(_value_and_grad(attention._xla_blocks, ct)(*args), want)
     print(config, "gaps to the float32 reference: kernels", kernels,
           "XLA's block form", blocks)
+    for name, gap in kernels.items():
+        assert gap <= max(2 * blocks[name], ROUNDINGS), (name, kernels,
+                                                         blocks)
+
+
+# -- the block-diffusion mask (PR 36) ------------------------------------------
+
+# `sdar_30b_a3b_chat`: 2 x (2 x 4,096) rows [noisy | clean], 32 query on 4
+# key-value heads of 128, blocks of 4
+BD = (2, 8192, 32, 4, 128, 4)
+
+
+def _bd_reference(q, k, v, block=BD[5]):
+    """Float32, every key-value head repeated, a block of 256 queries at a
+    time (`lax.map`, its scores made again in the backward pass: 2 x 8,192
+    rows of 32 heads fit) under the dense boolean mask written from its
+    four lines."""
+    b, t, hq, d = q.shape
+    length = t // 2
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    k, v = (jnp.repeat(x, hq // k.shape[2], axis=2) for x in (k, v))
+    keys = jnp.arange(t)
+
+    @jax.checkpoint
+    def rows(xs):
+        qb, first = xs
+        i = first + jnp.arange(qb.shape[1])
+        bq, bk = (i % length)[:, None] // block, (keys % length)[None] // block
+        noisy_q, noisy_k = i[:, None] < length, keys[None] < length
+        seen = jnp.where(noisy_q, jnp.where(noisy_k, bk == bq, bk < bq),
+                         jnp.where(noisy_k, False, bk <= bq))
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
+                       precision="highest") * d ** -0.5
+        pr = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", pr, v, precision="highest")
+    out = jax.lax.map(rows, (q.reshape(b, t // 256, 256, hq, d).swapaxes(0, 1),
+                             jnp.arange(0, t, 256)))
+    return out.swapaxes(0, 1).reshape(b, t, hq, d)
+
+
+def test_block_diffusion_kernels_against_the_float32_reference():
+    """The kernels under `mask=("block_diffusion", 4)` at the cell's shape:
+    taken on the TPU (`ops.attention.lowered.kernel`), and the output and
+    the three gradients within the bounds above of the float32 attention
+    under the dense mask; XLA's block form beside them."""
+    import time
+    b, t, hq, hkv, d, block = BD
+    ks = jax.random.split(jax.random.PRNGKey(36), 4)
+    q, ct = (jax.random.normal(ks[i], (b, t, hq, d)).astype(jnp.bfloat16)
+             for i in (0, 3))
+    k, v = (jax.random.normal(ks[i], (b, t, hkv, d)).astype(jnp.bfloat16)
+            for i in (1, 2))
+    args, mask = (q, k, v), ("block_diffusion", block)
+    kernel, xla = (obs.counter("ops.attention.lowered." + n)
+                   for n in ("kernel", "xla"))
+    before = kernel.value, xla.value
+    run = _value_and_grad(functools.partial(
+        attention.grouped_query_attention, mask=mask), ct)
+    assert run.lower(*args).as_text().count("tpu_custom_call") == 2
+    assert kernel.value > before[0] and xla.value == before[1]
+    got = run(*args)
+    want = _value_and_grad(_bd_reference, ct)(*args)
+    kernels = _gaps(got, want)
+    blocks = _gaps(_value_and_grad(functools.partial(
+        attention._xla_blocks, mask=mask), ct)(*args), want)
+    forward = jax.jit(functools.partial(attention.grouped_query_attention,
+                                        mask=mask))
+    times = {}
+    for name, fn in (("forward", forward), ("value_and_grad", run)):
+        jax.block_until_ready(fn(*args))
+        tic = time.perf_counter()
+        for _ in range(10):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        times[name] = (time.perf_counter() - tic) / 10 * 1e3
+    print("block_diffusion gaps to the float32 reference: kernels", kernels,
+          "XLA's block form", blocks, "ms a call", times)
     for name, gap in kernels.items():
         assert gap <= max(2 * blocks[name], ROUNDINGS), (name, kernels,
                                                          blocks)
