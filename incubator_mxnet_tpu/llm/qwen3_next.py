@@ -19,7 +19,9 @@ folds a run of them (the three delta-rule layers of a period, or whole
 periods of a deeper stack) into one scanned body; a layer kind that stands
 alone between two others stays inlined.  Both new operator kinds ask a
 scanned body to recompute its activations in the backward pass
-(`OpDef.scan_remat`).
+(`OpDef.scan_remat`), all but what an operator's forward rule names as
+kept (`ops.registry.scan_kept`): the outputs of the attention's and the
+delta rule's forward kernels, so that neither runs a second time.
 """
 from __future__ import annotations
 

@@ -20,7 +20,9 @@ What runs where:
   block of queries of the r query heads that share a key-value head as
   rows, so K and V are read once for the r heads and no block of scores
   passes through HBM, forward or backward.  Kept for the backward pass: q,
-  k, v, the output and a float32 log-sum-exp a row.  Anywhere else, and
+  k, v, the output and a float32 log-sum-exp a row, the last two named
+  `registry.scan_kept` (a re-materialised scanned layer stacks them and
+  does not run the forward kernel again).  Anywhere else, and
   for every other shape, XLA's block form (`_xla_blocks`): a checkpointed
   block of queries at a time, what the tests compare the kernels with.
   The choice is made from the platform and the shapes alone (`_driver`;
@@ -50,7 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from .flash_attention import CAUSAL, NONE
-from .registry import register, REQUIRED
+from .registry import register, REQUIRED, scan_kept
 
 
 def _mask_param(params):
@@ -281,7 +283,11 @@ def _flash(q, k, v, mask, interpret):
     r query heads of a key-value head are rows of one grid step, forward
     and backward.  `mask`: the kernels' mask parameter.
     Kept for the backward pass: q, k, v, the output and a float32
-    log-sum-exp a row -- no score."""
+    log-sum-exp a row -- no score.  Of those the output and the
+    log-sum-exp are what the forward kernel made, and the forward rule
+    names them `registry.scan_kept`: a re-materialised scanned layer
+    stacks them (2 x rows x heads x (head size x itemsize + 4) bytes a
+    layer) and computes q, k and v again, not the kernel."""
     return _flash_fwd(q, k, v, mask, interpret)[0]
 
 
@@ -292,8 +298,10 @@ def _flash_fwd(q, k, v, mask, interpret):
     o4, m, l = fa._kernel_forward(
         q4, k3, v3, k.shape[1] - t, 0, scale=scale, normalize=True,
         interpret=interpret, **_walk(q, k, v, mask))
-    o = _from_rows(o4, b)
-    return o, (q, k, v, o, m + jnp.log(l))
+    # the primal output and the kept one are the same named value, so
+    # nothing reads the kernel's raw outputs but these two
+    o = scan_kept(_from_rows(o4, b))
+    return o, (q, k, v, o, scan_kept(m + jnp.log(l)))
 
 
 def _flash_bwd(mask, interpret, kept, g):

@@ -26,8 +26,10 @@ drivers sweep them over a (batch, value head):
 
 Both run under one custom VJP.  The backward pass keeps the inputs and, per
 chunk, the state that entered it and the block's inverse (written by the
-forward sweep when a gradient is asked for), and makes everything else
-again in its one reverse sweep.  Products take their operands as XLA's
+forward sweep when a gradient is asked for, and named `registry.scan_kept`
+with the output: a re-materialised scanned layer stacks the three), and
+makes everything else again in its one reverse sweep.  Products take their
+operands as XLA's
 `Precision.DEFAULT` takes float32 on the backend at hand (on the TPU:
 rounded to bfloat16, summed in float32), the inverse's chain as `HIGHEST`
 (in the kernel: bf16x3); state, decays and every sum are float32.  Which
@@ -45,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .registry import register, REQUIRED
+from .registry import register, REQUIRED, scan_kept
 from ..base import MXNetError
 
 F32 = jnp.float32
@@ -588,8 +590,11 @@ def _chunk_cumsum(g, c):
 
 
 def _sweep_fwd(q, k, v, g, beta, c, driver):
-    o, states, tinvs = _forward(driver, q, k, v, _chunk_cumsum(g, c), beta,
-                                c, True)
+    # what the sweep made is named (`registry.scan_kept`): a
+    # re-materialised scanned layer stacks these three and computes the
+    # sweep's inputs again, not the sweep
+    o, states, tinvs = (scan_kept(x) for x in _forward(
+        driver, q, k, v, _chunk_cumsum(g, c), beta, c, True))
     return o, (q, k, v, g, beta, states, tinvs)
 
 
@@ -663,9 +668,11 @@ def _gated_delta_rule(params, q, k, v, g, beta):
     state in VMEM; anywhere else a `lax.scan` over the chunks runs the same
     chunk algebra (ops/delta_rule.py).  The backward pass is written, not
     derived: it keeps the inputs and, per chunk, the entering state and the
-    chunk's inverse.  Registered `scan_remat`: in a scanned layer body the
-    forward sweep runs again in the backward pass, which is what the body
-    needs anyway while `RoutedExperts` stands in it."""
+    chunk's inverse.  Registered `scan_remat`: a scanned layer body
+    computes its activations again in the backward pass, but not the
+    forward sweep, whose outputs (o, the states, the inverses: 470 MB a
+    layer at `qwen3_next_80b_a3b`'s shapes) the forward rule names
+    `registry.scan_kept`."""
     hk, hv = int(params["num_heads"]), int(params["num_v_heads"])
     b, t = q.shape[0], q.shape[1]
     if q.shape[-1] % hk or v.shape[-1] % hv or hv % hk or \

@@ -884,7 +884,14 @@ def _routed_experts(params, x, router_weight, gate, up, down, *states):
     further input (num_experts,) is added to the scores for the CHOICE alone.  It is an auxiliary state: no gradient
     reaches it and the step hands it back as it was.  Auxiliary states
     added to in every training step: `load` (experts_count,), and `dropped`
-    (2,): the assignments left without a row, and the tokens routed."""
+    (2,): the assignments left without a row, and the tokens routed.
+
+    Registered `scan_remat`, and it names nothing `registry.scan_kept` yet:
+    what its written backward pass keeps (the rows' two pre-activations,
+    the plan, the scores) is ~290 MB a layer at `lfm2_24b_a2b`'s shapes,
+    0.87 GB over that cell's three scanned layers, which its 14.63 GB of
+    15.75 do not have until the block's stacked outputs that nobody reads
+    are gone (ROADMAP S3); in a scanned layer its forward runs again."""
     num, k = int(params["num_experts"]), int(params["top_k"])
     offset, count = int(params["experts_offset"]), \
         int(params["experts_count"])

@@ -32,7 +32,7 @@ from typing import Callable, Optional
 from ..base import MXNetError, py_literal
 
 __all__ = ["OpDef", "register", "get", "list_ops", "REQUIRED", "eager_call",
-           "vjp_call", "eval_shape"]
+           "vjp_call", "eval_shape", "SCAN_KEPT", "scan_kept"]
 
 
 class _Required:
@@ -43,6 +43,22 @@ class _Required:
 REQUIRED = _Required()
 
 _REGISTRY: dict[str, "OpDef"] = {}
+
+# The one name under which an operator's custom-VJP forward rule declares
+# what a re-materialised scanned layer keeps for it (`OpDef.scan_remat`).
+SCAN_KEPT = "scan_kept"
+
+
+def scan_kept(x):
+    """`x`, named as a value that a re-materialised scanned layer body
+    keeps rather than computes again (`symbol.graph_eval_fn`: its
+    `jax.checkpoint` saves the values of this name and nothing else).  For
+    a custom-VJP forward rule to pass its kernel's OUTPUTS through, where
+    the written backward pass reads them: the kernel then runs once a
+    layer, not twice.  An identity anywhere else (an inlined layer, a
+    forward-only program: the name lowers to nothing)."""
+    from jax.ad_checkpoint import checkpoint_name
+    return checkpoint_name(x, SCAN_KEPT)
 
 
 class OpDef:
@@ -122,7 +138,12 @@ class OpDef:
         # routed experts, whose written backward pass keeps the router's
         # float32 probabilities, the plan and the rows' pre-activations),
         # so a scanned layer holding it recomputes its activations in the
-        # backward pass instead of stacking them (`symbol.graph_eval_fn`)
+        # backward pass instead of stacking them (`symbol.graph_eval_fn`),
+        # all but the values that an operator of the layer names
+        # `scan_kept`: those are stacked, and what made them (a Pallas
+        # forward kernel) does not run again.  An operator names a value
+        # where the benchmark's cell of its kind has paid for the bytes on
+        # the chip (PERF.md section 6, PR 37)
         self.scan_remat = bool(scan_remat)
         # counters: the op's auxiliary states are counters it adds to in
         # every training step.  `counters(deltas)`, `deltas` one {aux slot

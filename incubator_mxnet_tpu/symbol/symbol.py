@@ -631,6 +631,45 @@ def load_json(json_str):
 # Graph-level inference helpers shared with the executor
 # ---------------------------------------------------------------------------
 
+def _rematerialised(body):
+    """A scanned layer `body` whose backward pass computes its activations
+    again, all but the values an operator's forward rule has named
+    `ops.registry.scan_kept`.  A body that names nothing is under a bare
+    `jax.checkpoint`."""
+    import jax
+    from ..ops.registry import SCAN_KEPT
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(SCAN_KEPT))
+
+
+def _count_kept(body, length, *avals):
+    """Counters of what `_rematerialised(body)` keeps over a run of `length`
+    layers, from the avals, a traced run: `scan.remat.kept` named values a
+    layer and `scan.remat.kept_bytes`, their bytes times the layers.  Read
+    as `jax.ad_checkpoint.print_saved_residuals` reads them: the residuals
+    of the linearized body that a `name` equation of our name made."""
+    import jax
+    from .. import obs
+    from ..ops.registry import SCAN_KEPT
+    closed, (_, pullback) = jax.make_jaxpr(
+        lambda *xs: jax.linearize(body, *xs), return_shape=True)(*avals)
+    eqns = closed.jaxpr.eqns
+    # a residual that the known part reads too comes out of a
+    # `reduce_precision` to its own precision, which `jax.checkpoint` puts
+    # behind whatever made it
+    made = {id(eqn.outvars[0]): eqn.invars[0] for eqn in eqns
+            if eqn.primitive.name == "reduce_precision"}
+    residuals = {id(made.get(id(v), v)) for v in closed.jaxpr.outvars[
+        -len(jax.tree_util.tree_leaves(pullback)):]}
+    kept = [eqn.outvars[0].aval for eqn in eqns
+            if eqn.primitive.name == "name"
+            and eqn.params["name"] == SCAN_KEPT
+            and residuals & {id(v) for v in eqn.invars + eqn.outvars}]
+    obs.counter("scan.remat.kept").inc(len(kept))
+    obs.counter("scan.remat.kept_bytes").inc(
+        length * sum(a.size * a.dtype.itemsize for a in kept))
+
+
 def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
     """Build a pure function (args_dict_values, aux_values, key) -> (outputs,
     new_aux) executing the graph.  This function is what the executor jits:
@@ -646,7 +685,12 @@ def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
     whose carry changes shape) silently falls back to the inlined path —
     the plan is structural, shapes are only known here.  A scanned body
     that holds an operator registered with `scan_remat` recomputes its
-    activations in the backward pass instead of stacking them."""
+    activations in the backward pass instead of stacking them, but for
+    the values its operators name `ops.registry.scan_kept` (a kernel's
+    outputs that a written backward pass reads): those are stacked, and
+    the kernel runs once a layer.  `mx.obs` counters `scan.remat.kept`
+    and `scan.remat.kept_bytes` say, a traced training run, how many
+    values a layer keeps and their bytes over the run's layers."""
     import jax
     import jax.numpy as jnp
 
@@ -805,8 +849,11 @@ def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):
                 # an operator of this layer keeps far more for its backward
                 # pass than it takes in (`OpDef.scan_remat`): stacked over
                 # the layers that would not fit, so the backward pass
-                # computes the layer's activations again from its carry
-                body = jax.checkpoint(body)
+                # computes the layer's activations again from its carry,
+                # but for what the layer's operators name as kept
+                body = _rematerialised(body)
+                if is_train:
+                    _count_kept(body, length, c_aval, xs0)
             carry_out, ys = jax.lax.scan(body, c0, xs)
             env[id(run["boundary"])] = (carry_out,)
             for slot, layer_nodes in enumerate(run["aux"]):
