@@ -15,6 +15,9 @@ Typical use: ``import incubator_mxnet_tpu as mx``.
 """
 from __future__ import annotations
 
+import time as _time
+_import_t0 = _time.time_ns() // 1000    # the `mx.import` phase's start
+
 __version__ = "0.1.0"
 
 from .attribute import AttrScope
@@ -73,3 +76,7 @@ rnd = random
 config.apply_startup_knobs()
 from .compile import place_compilation_cache as _place  # noqa: E402
 _place()
+from .obs import trace as _obs_trace  # noqa: E402
+_obs_trace.watch_jax()
+_obs_trace.record_phase("mx.import", _import_t0,
+                        _time.time_ns() // 1000 - _import_t0, cat="compile")

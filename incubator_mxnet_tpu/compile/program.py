@@ -32,7 +32,6 @@ from __future__ import annotations
 import hashlib
 import logging
 import re
-import time as _time
 
 from ..analysis import locks as _alocks
 from ..obs import trace as _obs_trace
@@ -183,8 +182,8 @@ class CachedProgram:
             if exe is not None:
                 return exe
             if cache.enabled():
-                with _obs_trace.span("compile.load", cat="compile",
-                                     label=self.label):
+                with _obs_trace.phase("compile.load", cat="compile",
+                                      label=self.label):
                     exe = cache.load(key, devices)
                 if exe is not None:
                     self.disk_hits += 1
@@ -197,20 +196,17 @@ class CachedProgram:
         # phase-split timing: lower (trace -> StableHLO) vs the XLA
         # compile proper — the cold-start debt mxtop's CACHE line and
         # benchmark/'s program_build_s report per program
-        t0 = _time.perf_counter()
-        with _obs_trace.span("compile.lower", cat="compile",
-                             label=self.label):
+        with _obs_trace.phase("compile.lower", cat="compile",
+                              label=self.label) as lower:
             lowered = self._jit.lower(*args)
-        t1 = _time.perf_counter()
         # XLA's compile, or the load from JAX's persistent cache
-        with _obs_trace.span("compile.compile", cat="compile",
-                             label=self.label):
+        with _obs_trace.phase("compile.compile", cat="compile",
+                              label=self.label) as comp:
             exe = lowered.compile()
-        t2 = _time.perf_counter()
-        self.lower_s_total += t1 - t0
-        self.compile_s_total += t2 - t1
-        cache.note_compile(self.label, sig_repr, lower_s=t1 - t0,
-                           compile_s=t2 - t1)
+        self.lower_s_total += lower.s
+        self.compile_s_total += comp.s
+        cache.note_compile(self.label, sig_repr, lower_s=lower.s,
+                           compile_s=comp.s)
         if key is not None:
             cache.live_put(key, exe, self.label)
             if cache.enabled():

@@ -44,6 +44,7 @@ import numpy as _np
 
 from .base import MXNetError
 from .ndarray.ndarray import NDArray
+from .obs import trace as _obs_trace
 
 __all__ = ["FusedOptimizer", "FusedTrainStep", "FusedInference"]
 
@@ -369,18 +370,15 @@ class _TracedCore:
 
     def __init__(self, core, example_args):
         import jax
-        import time as _time
         flat, in_tree = jax.tree_util.tree_flatten(tuple(example_args))
 
         def flat_core(*leaves):
             return core(*jax.tree_util.tree_unflatten(in_tree, leaves))
 
-        from .obs import trace as _obs_trace
-        t0 = _time.perf_counter()
-        with _obs_trace.span("fused.trace", cat="compile"):
+        with _obs_trace.phase("fused.trace", cat="compile") as ph:
             closed, out_shape = jax.make_jaxpr(
                 flat_core, return_shape=True)(*flat)
-        self.trace_s = _time.perf_counter() - t0
+        self.trace_s = ph.s
         self._closed = closed
         self._in_tree = in_tree
         self._out_tree = jax.tree_util.tree_structure(out_shape)
@@ -1770,11 +1768,17 @@ class FusedTrainStep:
             # (checkpoint restore, set_params at epoch boundaries) —
             # donating host-staged buffers into an AOT executable
             # corrupts them; re-own through one XLA copy first
-            if _tree_nbytes((ws, ss, auxs)) <= REOWN_IN_PLACE_BYTES:
-                ws, ss, auxs = reown_for_donation((ws, ss, auxs))
-            else:
-                del ws, ss, auxs
-                ws, ss, auxs = self._reown_in_place(states)
+            nbytes = _tree_nbytes((ws, ss, auxs))
+            whole = nbytes <= REOWN_IN_PLACE_BYTES
+            with _obs_trace.phase(
+                    "fused.reown", cat="compile", bytes=nbytes,
+                    leaves=len(jax.tree_util.tree_leaves((ws, ss, auxs))),
+                    mode="whole" if whole else "in_place"):
+                if whole:
+                    ws, ss, auxs = reown_for_donation((ws, ss, auxs))
+                else:
+                    del ws, ss, auxs
+                    ws, ss, auxs = self._reown_in_place(states)
 
         mcarry = []
         for fn, m in metric_fns:

@@ -534,6 +534,9 @@ class ImageRecordIterImpl(DataIter):
         self.corrupt_records = n_corrupt
         self._corrupt_lock = _alocks.make_lock("image.corrupt")
         self._quarantine = None
+        # corrupt records the prefetching builders found before a log was
+        # attached (`reset()` below starts them): written by set_quarantine
+        self._unlogged = []
         if n_corrupt:
             import logging
             logging.getLogger(__name__).warning(
@@ -643,8 +646,13 @@ class ImageRecordIterImpl(DataIter):
 
     def set_quarantine(self, log):
         """Attach a quarantine log: corrupt records the batch builders
-        skip append one entry each (source path + record id)."""
-        self._quarantine = log
+        skip append one entry each (source path + record id), those they
+        found before the log was attached included."""
+        with self._corrupt_lock:
+            self._quarantine = log
+            found, self._unlogged = self._unlogged, []
+        for entry in found:
+            log.append(**entry)
 
     def apply_quarantine(self, entries):
         """Drop previously quarantined record ids for this .rec file
@@ -681,20 +689,22 @@ class ImageRecordIterImpl(DataIter):
                 min(lo + self.batch_size, len(self._order)))
 
     def _corrupt_record(self, rec_id, exc):
+        entry = dict(reason="corrupt_record", source=self._path_imgrec,
+                     record=int(rec_id), detail=str(exc)[:200])
         with self._corrupt_lock:
             self.corrupt_records += 1
             n = self.corrupt_records
+            log = self._quarantine
+            if log is None:
+                self._unlogged.append(entry)
         import logging
         logging.getLogger(__name__).warning(
             "ImageRecordIter: record %d of %s is corrupt (%s) — "
             "substituting zeros and quarantining (corrupt_records=%d)",
             rec_id, self._path_imgrec, str(exc)[:120], n)
-        if self._quarantine is not None:
+        if log is not None:
             try:
-                self._quarantine.append(reason="corrupt_record",
-                                        source=self._path_imgrec,
-                                        record=int(rec_id),
-                                        detail=str(exc)[:200])
+                log.append(**entry)
             except Exception:
                 pass
         try:

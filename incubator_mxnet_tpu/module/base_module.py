@@ -441,18 +441,18 @@ class BaseModule:
                 resume_nbatch = ckpt_resume.nbatch
                 gstep = ckpt_resume.step
 
-        with _obs_trace.span("fit.bind", cat="train"):
+        with _obs_trace.phase("fit.bind", cat="train"):
             self.bind(data_shapes=train_data.provide_data,
                       label_shapes=train_data.provide_label,
                       for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        with _obs_trace.span("fit.init_params", cat="train"):
+        with _obs_trace.phase("fit.init_params", cat="train"):
             self.init_params(initializer=initializer, arg_params=arg_params,
                              aux_params=aux_params,
                              allow_missing=allow_missing,
                              force_init=force_init)
-        with _obs_trace.span("fit.init_optimizer", cat="train"):
+        with _obs_trace.phase("fit.init_optimizer", cat="train"):
             self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
                                 optimizer_params=optimizer_params, mesh=mesh)
         sup = self._start_supervisor()
@@ -809,8 +809,8 @@ class BaseModule:
             # host-sync hazards (analysis.hostsync would misattribute)
             from .. import analysis as _analysis
             with _analysis.hostsync.paused():
-                with _obs_trace.span("fit.epoch_end", cat="train",
-                                     epoch=epoch, nbatch=nbatch) as sp:
+                with _obs_trace.phase("fit.epoch_end", cat="train",
+                                      epoch=epoch, nbatch=nbatch) as sp:
                     # the metric read is where the loop first needs the
                     # device's results: its time is the wait for the
                     # last block (not work), told apart as `wait_us`
@@ -825,9 +825,19 @@ class BaseModule:
                     self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
                                      (toc - tic))
 
-                    arg_params_, aux_params_ = self.get_params()
-                    self.set_params(arg_params_, aux_params_)
-                    self._note_op_counters(aux_params_)
+                    # every parameter to the host and back: `bytes` each
+                    # way where tracing is on (PERF.md section 3)
+                    with _obs_trace.phase("fit.get_params",
+                                          cat="train") as ph:
+                        arg_params_, aux_params_ = self.get_params()
+                        moved = _params_nbytes(arg_params_, aux_params_) \
+                            if _obs_trace.enabled() else None
+                        ph.note(bytes=moved)
+                    with _obs_trace.phase("fit.set_params", cat="train",
+                                          bytes=moved):
+                        self.set_params(arg_params_, aux_params_)
+                    with _obs_trace.phase("fit.op_counters", cat="train"):
+                        self._note_op_counters(aux_params_)
 
                     if epoch_end_callback is not None:
                         for callback in _as_list(epoch_end_callback):
@@ -1068,3 +1078,9 @@ def _as_list(obj):
     if isinstance(obj, (list, tuple)):
         return obj
     return [obj]
+
+
+def _params_nbytes(*dicts):
+    """Bytes of the arrays in the given name -> NDArray dicts."""
+    return sum(a.size * a.dtype.itemsize
+               for d in dicts for a in (d or {}).values())

@@ -30,6 +30,17 @@ Enabled by pointing ``MXNET_OBS_TRACE`` at the shared span file (the
 env propagates to spawned workers/daemons) or `enable(path)`.  Off,
 every hook is a single global read returning a shared no-op span.
 
+**Phases.**  A `phase` is a span that is also tallied: with tracing on
+it is exactly a `span` (one record, one annotation), and on or off it
+adds its wall seconds and a count to a process-wide tally that
+`phases()` returns and the ``phase`` metrics namespace renders.  Set-up
+(the package import, a `fit`'s bind and init, the trace, lower and
+compile of a program) and a `fit`'s epoch end are phases, so a job
+with tracing off still knows where its start went.  JAX's own compile
+events (`watch_jax`) are put down to the innermost phase open on the
+calling thread, or to ``""`` where none is.  `reset()` leaves the
+tally; `reset_phases()` clears it.
+
 **One clock.**  A live span also holds a `jax.profiler.TraceAnnotation`
 of its name for as long as it is open, so a profile taken by anyone —
 a benchmark's traced run, an operator's `jax.profiler.trace` — shows
@@ -56,7 +67,8 @@ from . import jsonl_sink as _jsonl
 
 __all__ = ["enabled", "enable", "disable", "flush", "stats",
            "span", "start_span", "record_span", "current_frame",
-           "activate", "rpc_span", "server_span", "NULL_SPAN"]
+           "activate", "rpc_span", "server_span", "NULL_SPAN",
+           "phase", "record_phase", "phases", "reset_phases", "watch_jax"]
 
 _ctx = contextvars.ContextVar("mx_obs_trace", default=None)
 
@@ -482,3 +494,151 @@ def server_span(msg, name, cat="server", **args):
     finally:
         _ctx.reset(token)
         sp.end()
+
+
+# -- phases: spans that are also tallied (module docstring) -----------------
+
+_tally = {}               # name -> [n, seconds, {jax event: [n, seconds]}]
+_tally_lock = threading.Lock()
+_open = threading.local()  # .stack: names of the phases open on a thread
+
+# JAX's compile events (jax._src.dispatch / compiler / compilation_cache)
+# under the names the tally gives them.  A persistent-cache hit is a
+# `compile` event too: its time holds the `cache_load`.
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+
+
+def _entry(name):
+    entry = _tally.get(name)
+    if entry is None:
+        entry = _tally[name] = [0, 0.0, {}]
+    return entry
+
+
+def _tally_add(name, seconds):
+    with _tally_lock:
+        entry = _entry(name)
+        entry[0] += 1
+        entry[1] += seconds
+
+
+class Phase:
+    """One open phase: the span it is (or `NULL_SPAN`), and after the body
+    its wall seconds `s`."""
+
+    __slots__ = ("name", "s", "_cat", "_args", "_sp", "_token", "_t0")
+
+    def __init__(self, name, cat, args):
+        self.name = name
+        self.s = None
+        self._cat = cat
+        self._args = args
+
+    def __enter__(self):
+        sp = self._sp = start_span(self.name, cat=self._cat, **self._args)
+        self._token = None if sp is NULL_SPAN else _ctx.set(sp.frame())
+        try:
+            _open.stack.append(self.name)
+        except AttributeError:
+            _open.stack = [self.name]
+        self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **args):
+        self._sp.note(**args)
+        return self
+
+    def __exit__(self, *exc):
+        self.s = time.perf_counter() - self._t0
+        _open.stack.pop()
+        _tally_add(self.name, self.s)
+        if self._token is not None:
+            _ctx.reset(self._token)
+        self._sp.end()
+
+
+def phase(name, cat="span", **args):
+    """``with phase(name) as ph:`` -- a `span` that is also tallied
+    (`phases()`); ``ph.note(...)`` adds to the span's args, ``ph.s`` is
+    the body's wall seconds once it has run."""
+    return Phase(name, cat, args)
+
+
+def record_phase(name, ts_us, dur_us, cat="span", **args):
+    """Tally an already-timed phase (and record its span where tracing is
+    on): the package's own import, which no phase can be open around."""
+    _tally_add(name, dur_us / 1e6)
+    record_span(name, ts_us, dur_us, cat=cat, **args)
+
+
+def phases():
+    """``{name: {"n", "s", "jax": {event: {"n", "s"}}}}`` since the process
+    began (or `reset_phases()`); ``""`` holds JAX's events that no phase
+    was open around."""
+    with _tally_lock:
+        return {name: {"n": n, "s": s,
+                       "jax": {k: {"n": e[0], "s": e[1]}
+                               for k, e in events.items()}}
+                for name, (n, s, events) in _tally.items()}
+
+
+def reset_phases():
+    """Clear the tally (tests; `reset()` leaves it)."""
+    with _tally_lock:
+        _tally.clear()
+
+
+def _on_jax_start(event, value, **kw):
+    # JAX marks the start of a timed event with a scalar: an event inside
+    # another of its kind (a jit traced inside a jit's trace) is part of
+    # the outer one's time, and is not counted again
+    if event in _JAX_EVENTS:
+        depth = getattr(_open, "depth", None)
+        if depth is None:
+            depth = _open.depth = {}
+        depth[event] = depth.get(event, 0) + 1
+
+
+def _on_jax_event(event, duration=0.0, **kw):
+    key = _JAX_EVENTS.get(event)
+    if key is None:
+        return
+    depth = getattr(_open, "depth", None)
+    if depth and depth.get(event):
+        depth[event] -= 1
+        if depth[event]:
+            return
+    stack = getattr(_open, "stack", None)
+    with _tally_lock:
+        ev = _entry(stack[-1] if stack else "")[2].setdefault(key, [0, 0.0])
+        ev[0] += 1
+        ev[1] += duration
+
+
+def _phase_stats():
+    """The ``phase`` metrics namespace: the tally, ``""`` as ``outside``."""
+    return {name or "outside": entry for name, entry in phases().items()}
+
+
+_watching = []
+
+
+def watch_jax():
+    """Put JAX's compile events down to phases, and the tally in the
+    scrape (once a process: the package's import calls this)."""
+    if _watching:
+        return
+    _watching.append(True)
+    import jax.monitoring
+    jax.monitoring.register_scalar_listener(_on_jax_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    jax.monitoring.register_event_listener(_on_jax_event)
+    from . import metrics as _metrics
+    _metrics.register_producer("phase", _phase_stats)

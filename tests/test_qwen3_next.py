@@ -511,7 +511,8 @@ def test_moe_load_span_and_counters(fitted):
     cell, program, reference, spans = fitted
     (load,) = [s for s in spans if s["name"] == "moe.load"]
     (epoch_end,) = [s for s in spans if s["name"] == "fit.epoch_end"]
-    assert load["pa"] == epoch_end["sp"]
+    (counters,) = [s for s in spans if s["name"] == "fit.op_counters"]
+    assert load["pa"] == counters["sp"] and counters["pa"] == epoch_end["sp"]
     args = load["args"]
     tokens = 8 * cell.traffic["batch_per_chip"] * cell.cfg["seq_len"]
     assert args["tokens"] == tokens and args["dropped"] == 0

@@ -171,6 +171,17 @@ def render(snap):
                         _fmt(cache.get("stores"), 0),
                         _fmt(cache.get("lower_s_total"), 2),
                         _fmt(cache.get("compile_s_total"), 2)))
+    # -- where start-up went: the phase tally, largest first ----------------
+    phase = _namespace(fleet, "phase")
+    seconds = {k[:-2]: v for k, v in phase.items()
+               if k.endswith(".s") and ".jax." not in k}
+    if seconds:
+        compiles = sum(v for k, v in phase.items()
+                       if k.endswith(".jax.compile.n"))
+        lines.append("  PHASES  jax_compiles=%s  %s" % (
+            _fmt(compiles, 0), "  ".join(
+                "%s=%ss" % (k, _fmt(v, 2)) for k, v in sorted(
+                    seconds.items(), key=lambda kv: -kv[1])[:6])))
     worker = _namespace(fleet, "worker")
     if worker:
         lines.append("  WORKER  executed=%s dedup_hits=%s outstanding=%s"
