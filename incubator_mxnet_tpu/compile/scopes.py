@@ -31,6 +31,10 @@ _APPLIES = re.compile(r"\bto_apply=%?([\w.\-]+)")
 _NODE = re.compile(r"([A-Za-z_]\w*):([^/();\"]+)")
 
 
+# operator kinds whose node runs a subgraph as a loop (ops/control_flow.py)
+LOOPS = ("_foreach", "_while_loop")
+
+
 @functools.lru_cache(maxsize=None)
 def _classified(op_name):
     path = op_name.split(";", 1)[0]
@@ -38,22 +42,26 @@ def _classified(op_name):
                  OTHER)
     if phase == "fwd" and "transpose(" in path:
         phase = "bwd"
-    node = _NODE.search(path)
-    return (phase, node.group(1) if node else None,
-            node.group(2) if node else None)
+    nodes = _NODE.findall(path)
+    op, node = nodes[-1] if nodes else (None, None)
+    loop = next((name for kind, name in nodes if kind in LOOPS), None)
+    return phase, op, node, loop
 
 
 def classify(op_name):
-    """`{"phase", "op", "node"}` of one `op_name` (XLA joins the names of
-    merged instructions with ``;``: the first speaks).  `phase` is the
-    outermost of `PHASES` on the scope path, else ``other``; an op under
-    ``fwd`` that is a transpose belongs to the backward pass.  `op` and
-    `node` are the graph node's ``Kind:name`` scope, or None."""
-    return dict(zip(("phase", "op", "node"), _classified(op_name)))
+    """`{"phase", "op", "node", "loop"}` of one `op_name` (XLA joins the
+    names of merged instructions with ``;``: the first speaks).  `phase` is
+    the outermost of `PHASES` on the scope path, else ``other``; an op
+    under ``fwd`` that is a transpose belongs to the backward pass.  `op`
+    and `node` are the innermost graph node's ``Kind:name`` scope, or None:
+    inside a loop's body, the body's node, not the loop's.  `loop` is the
+    name of the outermost loop node (`LOOPS`) on the path, or None."""
+    return dict(zip(("phase", "op", "node", "loop"),
+                    _classified(op_name)))
 
 
 def parse(hlo_text):
-    """`{instruction: {"phase", "op", "node", "mixed"}}` of every
+    """`{instruction: {"phase", "op", "node", "loop", "mixed"}}` of every
     instruction a device runs as an event of its own: those of the entry
     computation and of the bodies it calls, not the insides of fusions
     and reducers.  A fusion speaks through its own metadata, which XLA

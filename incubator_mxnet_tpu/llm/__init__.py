@@ -20,7 +20,12 @@ family (`SdarMoeLM`, `sdar_moe_symbol`): the graph corrupts the clean
 sequence (`BlockDiffusionNoise`), runs [noisy | clean] rows through
 attention layers under the block-diffusion mask with routed experts, and
 weighs the noisy copy's loss by m / t; the same mixer and sparse layer;
-training path only.
+training path only.  `ouro.py` is the looped LM of the Ouro family
+(`OuroLM`, `ouro_symbol`): one stack of layers run `total_ut_steps` times in
+one re-materialised loop (`contrib.foreach`), a head and an exit gate at
+every step under the entropy-regularised exit objective
+(`LoopExitOutput`); the same attention mixer without q/k norms, `lfm2.py`'s
+dense SwiGLU; training path only.
 """
 from .model import (LMConfig, TransformerBlock, TransformerLM, lm_symbol,
                     lm_block_op_count)
@@ -28,11 +33,13 @@ from .qwen3_next import (Qwen3NextConfig, Qwen3NextLM, Qwen3NextBlock,
                          qwen3_next_symbol)
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeLM, Lfm2MoeBlock, lfm2_moe_symbol)
 from .sdar import SdarMoeConfig, SdarMoeLM, SdarMoeBlock, sdar_moe_symbol
+from .ouro import OuroConfig, OuroLM, OuroBlock, ouro_symbol
 from .decode_core import (DecodePrograms, stack_lm_params, init_kv_cache)
 
 __all__ = ["LMConfig", "TransformerBlock", "TransformerLM", "lm_symbol",
            "lm_block_op_count", "Qwen3NextConfig", "Qwen3NextLM",
            "Qwen3NextBlock", "qwen3_next_symbol", "Lfm2MoeConfig", "Lfm2MoeLM",
            "Lfm2MoeBlock", "lfm2_moe_symbol", "SdarMoeConfig", "SdarMoeLM",
-           "SdarMoeBlock", "sdar_moe_symbol", "DecodePrograms",
+           "SdarMoeBlock", "sdar_moe_symbol", "OuroConfig", "OuroLM",
+           "OuroBlock", "ouro_symbol", "DecodePrograms",
            "stack_lm_params", "init_kv_cache"]

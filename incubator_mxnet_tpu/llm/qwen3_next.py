@@ -168,19 +168,20 @@ class GatedDeltaNetMixer(HybridBlock):
 
 class GatedAttentionMixer(HybridBlock):
     """Grouped-query softmax attention with per-head q/k RMS norms
-    (`zero_centered` or plain), a rotary embedding on
-    `cfg.partial_rotary_factor` of a head and, with `gate`, a sigmoid gate
-    on its output, projected beside q.  `llm/lfm2.py` takes it without the
-    gate, with plain norms and the rotary embedding on the whole head;
-    `llm/sdar.py` likewise, under another `mask` (`BlockwiseAttention`'s
+    (`zero_centered` or plain; none with `qk_norm=False`), a rotary
+    embedding on `cfg.partial_rotary_factor` of a head and, with `gate`, a
+    sigmoid gate on its output, projected beside q.  `llm/lfm2.py` takes it
+    without the gate, with plain norms and the rotary embedding on the whole
+    head; `llm/sdar.py` likewise, under another `mask` (`BlockwiseAttention`'s
     mask parameters; causal unless told) and with the time axis holding
-    `copies` copies of the sequence's positions."""
+    `copies` copies of the sequence's positions; `llm/ouro.py` without the
+    gate and without q/k norms, one query head a key-value head."""
 
     def __init__(self, cfg, gate=True, zero_centered=True, mask=None,
-                 copies=1, **kwargs):
+                 copies=1, qk_norm=True, **kwargs):
         super().__init__(**kwargs)
         c, dt = cfg.hidden_size, cfg.param_dtype
-        self._cfg, self._gate = cfg, gate
+        self._cfg, self._gate, self._qk_norm = cfg, gate, qk_norm
         self._mask = dict(mask or {"causal": True})
         self._copies = {} if copies == 1 else {"copies": int(copies)}
         h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
@@ -190,10 +191,11 @@ class GatedAttentionMixer(HybridBlock):
                                  "q_proj_")
             self.k_proj = _dense(kv * d, c, dt, "k_proj_")
             self.v_proj = _dense(kv * d, c, dt, "v_proj_")
-            self.q_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
-                                  dtype=dt, prefix="q_norm_")
-            self.k_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
-                                  dtype=dt, prefix="k_norm_")
+            if qk_norm:
+                self.q_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
+                                      dtype=dt, prefix="q_norm_")
+                self.k_norm = RMSNorm(d, cfg.rms_norm_eps, zero_centered,
+                                      dtype=dt, prefix="k_norm_")
             self.out_proj = _dense(c, h * d, dt, "out_proj_")
 
     def hybrid_forward(self, F, x):
@@ -206,11 +208,12 @@ class GatedAttentionMixer(HybridBlock):
         if self._gate:
             q = F.slice_axis(qg, axis=-1, begin=0, end=h * d)
             gate = F.slice_axis(qg, axis=-1, begin=h * d, end=2 * h * d)
+        q_norm, k_norm = (self.q_norm, self.k_norm) if self._qk_norm else \
+            (lambda a: a, lambda a: a)
         q = F.RotaryEmbedding(
-            self.q_norm(F.Reshape(q, shape=(0, 0, h, d))), **rope)
+            q_norm(F.Reshape(q, shape=(0, 0, h, d))), **rope)
         k = F.RotaryEmbedding(
-            self.k_norm(F.Reshape(self.k_proj(x), shape=(0, 0, kv, d))),
-            **rope)
+            k_norm(F.Reshape(self.k_proj(x), shape=(0, 0, kv, d))), **rope)
         attn = F.BlockwiseAttention(
             F.Reshape(q, shape=(0, 0, -1)), F.Reshape(k, shape=(0, 0, -1)),
             self.v_proj(x), name="attention", num_heads=h, num_kv_heads=kv,

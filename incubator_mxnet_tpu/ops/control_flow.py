@@ -121,7 +121,18 @@ _FOREACH_PARAMS = {
           needs_rng=True, mode_dependent=True)
 def _foreach(params, *arrays):
     """reference control_flow.cc:1255 (ForeachState + ForeachComputeExCPU)
-    lowered to one `lax.scan`."""
+    lowered to one `lax.scan`.
+
+    In training the body is re-materialised under the scanned layers' rule
+    (`symbol._rematerialised`) where it holds an operator registered with
+    `scan_remat` or names values `ops.registry.scan_kept` (a flash kernel's
+    outputs): a step then keeps its inputs and those values, and its
+    backward pass computes the rest again, so the stacked activations do
+    not grow with the steps times the body's layers.  Any other body lowers
+    as it always has.  A traced training program counts `scan.remat.kept` /
+    `.kept_bytes` as a scanned run does, and `loop.steps`."""
+    from .. import obs
+    from ..symbol.symbol import _rematerialised, _count_kept
     train = bool(params.get("_train", False))
     gfn, arg_names = _sub_eval(params["subgraph"], train)
     slots = _binder(arg_names, params["arg_map"])
@@ -134,15 +145,32 @@ def _foreach(params, *arrays):
     states = tuple(arrays[nd_:nd_ + ns])
     closure = tuple(arrays[nd_ + ns:])
 
-    def pick(xs, st):
-        return tuple(xs[i] if k == "d" else st[i] if k == "s" else closure[i]
-                     for k, i in slots)
+    def step(cl, st, xs, sk):
+        ins = tuple(xs[i] if k == "d" else st[i] if k == "s" else cl[i]
+                    for k, i in slots)
+        outs, _ = gfn(ins, (), sk)
+        return tuple(outs[n_out:]), tuple(outs[:n_out])
+
+    if train:
+        remat = _rematerialised(step)
+        length = int(data[0].shape[0]) if data else 0
+        avals = [tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in group)
+                 for group in (closure, states,
+                               tuple(d[0] for d in data))]
+        # the kept set follows the shapes alone, not the key's value
+        fixed = jax.random.PRNGKey(0)
+        kept = _count_kept(lambda cl, st, xs: remat(cl, st, xs, fixed),
+                           length, *avals)
+        if kept or any(n.op is not None and n.op.scan_remat
+                       for n in _subgraph(params["subgraph"])._topo()):
+            step = remat
+        obs.counter("loop.steps").inc(length)
 
     def body(carry, xs):
         st, k = carry
         k, sk = jax.random.split(k)
-        outs, _ = gfn(pick(xs, st), (), sk)
-        return (tuple(outs[n_out:]), k), tuple(outs[:n_out])
+        new_states, ys = step(closure, st, xs, sk)
+        return (new_states, k), ys
 
     (fin_states, _), ys = jax.lax.scan(body, (states, key), data)
     return tuple(ys) + tuple(fin_states)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register
 from ..base import MXNetError
@@ -213,3 +214,150 @@ def _svm_output(params, data, label):
           params={"sparseness_target": 0.1, "penalty": 0.001, "momentum": 0.9})
 def _identity_kl(params, data):
     return data + 0
+
+
+def exit_distribution(gate):
+    """(log p, p) of the exit distribution over T recurrent steps, from the
+    exit gate's logits `gate` (T, rows): lambda_t = sigmoid(gate_t), p_t =
+    lambda_t prod_{j<t} (1 - lambda_j) for t < T and p_T = prod_{j<T}
+    (1 - lambda_j) (LoopLM, arXiv:2510.25741); the last step's gate is not
+    read.  In log space, so that no product of small numbers underflows."""
+    survive = jnp.cumsum(jax.nn.log_sigmoid(-gate[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(gate[:1]), survive])
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(gate[:-1]) + before[:-1], before[-1:]])
+    return log_p, jnp.exp(log_p)
+
+
+def exit_objective(gate, losses, beta):
+    """Per row: sum_t p_t L_t - beta H(p), H(p) = -sum_t p_t log p_t, the
+    entropy-regularised exit objective of LoopLM's stage I; `gate` and
+    `losses` (T, rows)."""
+    log_p, p = exit_distribution(gate)
+    return jnp.sum(p * losses, axis=0) + beta * jnp.sum(p * log_p, axis=0)
+
+
+def _exit_counters(deltas):
+    """An epoch's exit distribution, from what every `LoopExitOutput` node
+    of a graph has added to `exit_stats` (`OpDef.counters`): rows, then per
+    step the sum of p_t, the sum of the expected step count and of H(p)."""
+    total = sum(np.asarray(d["exit_stats"], np.float64) for d in deltas)
+    rows = float(total[0])
+    mean = total[1:] / rows if rows else 0.0 * total[1:]
+    args = {"rows": int(rows), "steps": len(total) - 3,
+            "p": [float(v) for v in mean[:-2]],
+            "expected_steps": float(mean[-2]), "entropy": float(mean[-1])}
+    return {"span": "loop.exit", "args": args,
+            "counters": {"loop.exit.rows": args["rows"]},
+            "gauges": {"loop.exit.expected_steps": args["expected_steps"],
+                       "loop.exit.entropy": args["entropy"]}}
+
+
+def _exit_logits(h, weight):
+    return jnp.einsum("nc,vc->nv", h, weight,
+                      preferred_element_type=jnp.float32)
+
+
+@register("LoopExitOutput", nin=6, naux=1, mode_dependent=True,
+          params={"beta": 0.1},
+          input_names=["data", "head_weight", "gate_weight", "gate_bias",
+                       "label", "exit_stats"],
+          counters=_exit_counters)
+def _loop_exit_output(params, states, weight, gate_w, gate_b, label, stats):
+    """The head, the exit gate and the entropy-regularised exit objective of
+    a looped LM (LoopLM, arXiv:2510.25741, stage I), as one loss output.
+
+    `data` (T, rows, C): the normed state after each of T recurrent steps;
+    `head_weight` (V, C), `gate_weight` (1, C), `gate_bias` (1,), `label`
+    (rows,).  Step t's logits z_t = W h_t, its loss L_t = -log
+    softmax(z_t)[label], its exit gate lambda_t = sigmoid(w_g h_t + b_g);
+    the exit distribution p (`exit_distribution`).  Forward: the LAST
+    step's probabilities, in the input's type (what a model run to its last
+    step serves).  Backward, as `SoftmaxOutput`'s, ignores the incoming
+    gradient: the gradient of sum over rows of sum_t p_t L_t - beta H(p)
+    with respect to every input but the label.  The steps are walked one
+    at a time: the forward pass keeps each step's loss, the backward pass
+    computes each step's logits again, so no two steps' logits, and no
+    (T, rows, V) value, exist at once.  The auxiliary state `exit_stats`
+    (T + 3,) is added to in every training step: rows, the sum of p_t per
+    step, of the expected step count sum_t t p_t and of H(p)."""
+    steps, rows, c = states.shape
+    if weight.ndim != 2 or weight.shape[1] != c or \
+            gate_w.shape != (1, c) or gate_b.shape != (1,) or \
+            label.size != rows or stats.shape != (steps + 3,):
+        raise MXNetError(
+            "LoopExitOutput: data (T, rows, C), head_weight (V, C), "
+            "gate_weight (1, C), gate_bias (1,), label of rows entries and "
+            "exit_stats (T + 3,); got %s, %s, %s, %s, %s and %s" % tuple(
+                tuple(a.shape) for a in (states, weight, gate_w, gate_b,
+                                         label, stats)))
+    beta = float(params["beta"])
+    in_dtype = states.dtype
+    label = label.reshape(rows).astype(jnp.int32)
+
+    def gates(h, gw, gb):
+        return jnp.einsum("trc,c->tr", h, gw[0],
+                          preferred_element_type=jnp.float32) + \
+            gb[0].astype(jnp.float32)
+
+    def step_loss(h, w, y):
+        z = _exit_logits(h, w)
+        return jax.nn.logsumexp(z, axis=-1) - \
+            jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0], z
+
+    def forward(h, w, y):
+        """(the last step's probabilities, every step's loss (T, rows))."""
+        early = jax.lax.map(lambda ht: step_loss(ht, w, y)[0], h[:-1])
+        last, z = step_loss(h[-1], w, y)
+        probs = jax.nn.softmax(z, axis=-1).astype(in_dtype)
+        return probs, jnp.concatenate([early, last[None]])
+
+    @jax.custom_vjp
+    def f(h, w, gw, gb, y):
+        return jax.nn.softmax(_exit_logits(h[-1], w), axis=-1) \
+            .astype(in_dtype)
+
+    def fwd(h, w, gw, gb, y):
+        probs, losses = forward(h, w, y)
+        return probs, (h, w, gw, gb, y, losses)
+
+    def bwd(res, g):
+        h, w, gw, gb, y, losses = res
+        a = gates(h, gw, gb)
+        d_gate, d_loss = jax.grad(
+            lambda a_, l_: jnp.sum(exit_objective(a_, l_, beta)),
+            argnums=(0, 1))(a, losses)
+
+        def one(dw, xs):
+            ht, dl = xs
+            z = _exit_logits(ht, w)
+            dz = (jax.nn.softmax(z, axis=-1) -
+                  jax.nn.one_hot(y, z.shape[-1], dtype=z.dtype)) * \
+                dl[:, None]
+            dz = dz.astype(in_dtype)
+            return dw + jnp.einsum("nv,nc->vc", dz, ht,
+                                   preferred_element_type=jnp.float32), \
+                jnp.einsum("nv,vc->nc", dz, w,
+                           preferred_element_type=jnp.float32)
+        dw, dh = jax.lax.scan(one, jnp.zeros(w.shape, jnp.float32),
+                              (h, d_loss))
+        dh = dh + d_gate[..., None] * gw[0].astype(jnp.float32)
+        dgw = jnp.einsum("tr,trc->c", d_gate, h,
+                         preferred_element_type=jnp.float32)[None]
+        return (dh.astype(in_dtype), dw.astype(w.dtype),
+                dgw.astype(gw.dtype),
+                jnp.sum(d_gate).reshape(1).astype(gb.dtype),
+                np.zeros(y.shape, jax.dtypes.float0))
+
+    f.defvjp(fwd, bwd)
+    out = f(states, weight, gate_w, gate_b, label)
+    if not params.get("_train", False):
+        return out
+    # the exit distribution of this step, counted (no gradient flows here)
+    log_p, p = exit_distribution(jax.lax.stop_gradient(
+        gates(states, gate_w, gate_b)))
+    t = jnp.arange(1, steps + 1, dtype=jnp.float32)[:, None]
+    return out, stats + jnp.concatenate([
+        jnp.full((1,), rows, jnp.float32), jnp.sum(p, axis=1),
+        jnp.sum(t * p).reshape(1),
+        -jnp.sum(p * log_p).reshape(1)]).astype(stats.dtype)

@@ -647,7 +647,8 @@ def _count_kept(body, length, *avals):
     layers, from the avals, a traced run: `scan.remat.kept` named values a
     layer and `scan.remat.kept_bytes`, their bytes times the layers.  Read
     as `jax.ad_checkpoint.print_saved_residuals` reads them: the residuals
-    of the linearized body that a `name` equation of our name made."""
+    of the linearized body that a `name` equation of our name made.
+    Returns the named values a layer keeps."""
     import jax
     from .. import obs
     from ..ops.registry import SCAN_KEPT
@@ -668,6 +669,7 @@ def _count_kept(body, length, *avals):
     obs.counter("scan.remat.kept").inc(len(kept))
     obs.counter("scan.remat.kept_bytes").inc(
         length * sum(a.size * a.dtype.itemsize for a in kept))
+    return len(kept)
 
 
 def graph_eval_fn(symbol, is_train, n_rng_hint=None, scan=None):

@@ -28,12 +28,15 @@ SMALL = {
     "more_keys": (1, 128, 384, 4, 1, 64, 64),
     "wider_value": (1, 128, 256, 4, 2, 64, 128),
 }
-# the two cells' shapes: (q, k, v), and the temporaries XLA may keep beside
-# the kernels (the transposes to and from the kernels' layout)
+# the causal cells' shapes: (q, k, v), and the temporaries XLA may keep
+# beside the kernels (the transposes to and from the kernels' layout);
+# `ouro_2_6b` runs one query head a key-value head
 CELLS = {
     "lfm2_24b_a2b": ((2, 8192, 32, 64), (2, 8192, 8, 64), (2, 8192, 8, 64)),
     "qwen3_next_80b_a3b": ((2, 4096, 16, 256), (2, 4096, 2, 256),
                            (2, 4096, 2, 256)),
+    "ouro_2_6b": ((2, 4096, 16, 128), (2, 4096, 16, 128),
+                  (2, 4096, 16, 128)),
 }
 
 

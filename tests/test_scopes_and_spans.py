@@ -103,17 +103,26 @@ def test_classify_rules():
     c = scopes.classify
     assert c("jit(stepk)/while/body/closed_call/fwd/jvp(Convolution:conv1)"
              "/conv_general_dilated") == \
-        {"phase": "fwd", "op": "Convolution", "node": "conv1"}
+        {"phase": "fwd", "op": "Convolution", "node": "conv1", "loop": None}
     assert c("jit(stepk)/while/body/closed_call/bwd/transpose(jvp("
              "Convolution:conv1))/conv_general_dilated")["phase"] == "bwd"
     # a transpose that kept the forward's scope is the backward pass
     assert c("fwd/transpose(jvp(BatchNorm:bn1))/mul") == \
-        {"phase": "bwd", "op": "BatchNorm", "node": "bn1"}
+        {"phase": "bwd", "op": "BatchNorm", "node": "bn1", "loop": None}
     # XLA joins merged names with ';': the first speaks
     assert c("a/optimizer/mul;a/guardian/select_n")["phase"] == "optimizer"
     assert c("jit(stepk)/while/body/add") == \
-        {"phase": "other", "op": None, "node": None}
-    assert c("") == {"phase": "other", "op": None, "node": None}
+        {"phase": "other", "op": None, "node": None, "loop": None}
+    assert c("") == {"phase": "other", "op": None, "node": None,
+                     "loop": None}
+    # inside a loop's body the body's node speaks, and the loop is named
+    assert c("jit(stepk)/while/body/closed_call/bwd/transpose(jvp(_foreach:"
+             "lm_loop1))/while/body/transpose(jvp(FullyConnected:lm_fc))/"
+             "dot_general") == {"phase": "bwd", "op": "FullyConnected",
+                                "node": "lm_fc", "loop": "lm_loop1"}
+    assert c("fwd/jvp(_foreach:outer)/while/body/jvp(_while_loop:inner)/"
+             "while/body/add") == {"phase": "fwd", "op": "_while_loop",
+                                   "node": "inner", "loop": "outer"}
 
 
 def test_parse_fusions_and_mixed():
@@ -157,14 +166,14 @@ ENTRY %main.3 (x: f32[4]) -> f32[4] {
     assert set(got) == {"x", "fusion.1", "fusion.2", "fusion.3", "reduce.2",
                         "copy.1"}
     assert got["fusion.1"] == {"phase": "guardian", "op": None, "node": None,
-                               "mixed": True,
+                               "loop": None, "mixed": True,
                                "inside": ["guardian", "optimizer"]}
     # an instruction under no phase (the scan's indexing) mixes nothing ...
     assert got["fusion.2"] == {"phase": "fwd", "op": "Activation",
-                               "node": "act0", "mixed": False}
+                               "node": "act0", "loop": None, "mixed": False}
     # ... but a phase inside a fusion that XLA named for none does
     assert got["fusion.3"] == {"phase": "other", "op": None, "node": None,
-                               "mixed": True, "inside": ["fwd"]}
+                               "loop": None, "mixed": True, "inside": ["fwd"]}
     assert got["reduce.2"]["phase"] == "metric" and \
         not got["reduce.2"]["mixed"]
     assert got["copy.1"]["phase"] == "other"
