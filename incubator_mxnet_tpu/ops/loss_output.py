@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import loop_exit
 from .registry import register
 from ..base import MXNetError
 
@@ -275,12 +276,16 @@ def _loop_exit_output(params, states, weight, gate_w, gate_b, label, stats):
     step's probabilities, in the input's type (what a model run to its last
     step serves).  Backward, as `SoftmaxOutput`'s, ignores the incoming
     gradient: the gradient of sum over rows of sum_t p_t L_t - beta H(p)
-    with respect to every input but the label.  The steps are walked one
-    at a time: the forward pass keeps each step's loss, the backward pass
-    computes each step's logits again, so no two steps' logits, and no
-    (T, rows, V) value, exist at once.  The auxiliary state `exit_stats`
-    (T + 3,) is added to in every training step: rows, the sum of p_t per
-    step, of the expected step count sum_t t p_t and of H(p)."""
+    with respect to every input but the label.  The forward pass keeps each
+    step's loss, the backward pass computes each step's logits again.  In
+    training on a TPU, where `loop_exit.driver` holds the shapes, Pallas
+    kernels walk the vocabulary in tiles and no float32 logits reach HBM
+    (ops/loop_exit.py); elsewhere XLA walks the steps one at a time, so no
+    two steps' logits, and no (T, rows, V) value, exist at once.  Which a
+    traced call took is counted: `ops.loop_exit.lowered.kernel` / `.xla`.
+    The auxiliary state `exit_stats` (T + 3,) is added to in every training
+    step: rows, the sum of p_t per step, of the expected step count sum_t t
+    p_t and of H(p)."""
     steps, rows, c = states.shape
     if weight.ndim != 2 or weight.shape[1] != c or \
             gate_w.shape != (1, c) or gate_b.shape != (1,) or \
@@ -294,6 +299,13 @@ def _loop_exit_output(params, states, weight, gate_w, gate_b, label, stats):
     beta = float(params["beta"])
     in_dtype = states.dtype
     label = label.reshape(rows).astype(jnp.int32)
+    # out of training only `f`'s primal runs: the last step's softmax, in
+    # XLA's form
+    driver, tiles = loop_exit.driver(
+        rows, c, weight.shape[0], in_dtype.itemsize) \
+        if params.get("_train", False) else ("xla", None)
+    loop_exit.count(driver)
+    interpret = driver == "interpret"
 
     def gates(h, gw, gb):
         return jnp.einsum("trc,c->tr", h, gw[0],
@@ -318,15 +330,35 @@ def _loop_exit_output(params, states, weight, gate_w, gate_b, label, stats):
             .astype(in_dtype)
 
     def fwd(h, w, gw, gb, y):
-        probs, losses = forward(h, w, y)
-        return probs, (h, w, gw, gb, y, losses)
+        if driver == "xla":
+            probs, losses = forward(h, w, y)
+            return probs, (h, w, gw, gb, y, losses, None)
+        probs, losses, lse = loop_exit.forward(
+            h, w, y, rows=tiles.rows, vocab=tiles.vocab, interpret=interpret)
+        return probs, (h, w, gw, gb, y, losses, lse)
 
     def bwd(res, g):
-        h, w, gw, gb, y, losses = res
+        h, w, gw, gb, y, losses, lse = res
         a = gates(h, gw, gb)
         d_gate, d_loss = jax.grad(
             lambda a_, l_: jnp.sum(exit_objective(a_, l_, beta)),
             argnums=(0, 1))(a, losses)
+        dgw = jnp.einsum("tr,trc->c", d_gate, h,
+                         preferred_element_type=jnp.float32)[None]
+        if driver == "xla":
+            dh, dw = xla_grads(h, w, gw, y, d_loss, d_gate)
+        else:
+            dh, dw = loop_exit.backward(
+                h, w, y, lse, d_loss, d_gate, gw, rows=tiles.grad_rows,
+                vocab=tiles.vocab, superblock=tiles.superblock,
+                interpret=interpret)
+        return (dh, dw.astype(w.dtype), dgw.astype(gw.dtype),
+                jnp.sum(d_gate).reshape(1).astype(gb.dtype),
+                np.zeros(y.shape, jax.dtypes.float0))
+
+    def xla_grads(h, w, gw, y, d_loss, d_gate):
+        """(dh in the input's type, dw float32): a scan over the steps, each
+        step's logits made again in float32."""
 
         def one(dw, xs):
             ht, dl = xs
@@ -342,12 +374,7 @@ def _loop_exit_output(params, states, weight, gate_w, gate_b, label, stats):
         dw, dh = jax.lax.scan(one, jnp.zeros(w.shape, jnp.float32),
                               (h, d_loss))
         dh = dh + d_gate[..., None] * gw[0].astype(jnp.float32)
-        dgw = jnp.einsum("tr,trc->c", d_gate, h,
-                         preferred_element_type=jnp.float32)[None]
-        return (dh.astype(in_dtype), dw.astype(w.dtype),
-                dgw.astype(gw.dtype),
-                jnp.sum(d_gate).reshape(1).astype(gb.dtype),
-                np.zeros(y.shape, jax.dtypes.float0))
+        return dh.astype(in_dtype), dw
 
     f.defvjp(fwd, bwd)
     out = f(states, weight, gate_w, gate_b, label)

@@ -395,12 +395,19 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def test_objective_at_the_cells_size_compiles_for_the_v5e(one_chip):
+def test_objective_at_the_cells_size_compiles_for_the_v5e(one_chip,
+                                                          monkeypatch):
     """Value, exit counts and gradient of the heads and objective at
     `ouro_loop_train_hostfed`'s size (4 steps of 8,192 rows, 49,152 ids,
-    bfloat16): the temporaries hold one step's float32 logits (1.61 GB) and
-    what is made of them, not four steps' (6.44 GB)."""
+    bfloat16), on the path a TPU takes (`default_backend()` steered: here it
+    is the CPU): the Pallas kernels of ops/loop_exit.py, two forward and
+    one a step and a superblock of rows backward, and temporaries under one
+    step's float32 logits (1.61 GB), which XLA's form holds with what is
+    made of them (~3 GB)."""
+    from incubator_mxnet_tpu.ops import loop_exit
     steps, rows, c, v = 4, 8192, 2048, 49152
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("MXNET_FLASH_INTERPRET", raising=False)
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -409,8 +416,14 @@ def test_objective_at_the_cells_size_compiles_for_the_v5e(one_chip):
     def step(h, w, gw, gb, y, stats):
         (out, new), vjp = jax.vjp(lambda *a: op(*a, y, stats), h, w, gw, gb)
         return out, new, vjp((jnp.zeros_like(out), jnp.zeros_like(new)))
+    kernel = mx.obs.counter("ops.loop_exit.lowered.kernel")
+    before = kernel.value
     compiled = jax.jit(step).lower(
         shape(steps, rows, c), shape(v, c), shape(1, c), shape(1),
         shape(rows, dtype=jnp.float32),
         shape(steps + 3, dtype=jnp.float32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    assert kernel.value == before + 1
+    superblock = loop_exit._tiles(rows, c, v, 2).superblock
+    assert compiled.as_text().count("tpu_custom_call") == \
+        2 + steps * rows // superblock
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * v * 4
