@@ -25,7 +25,14 @@ training path only.  `ouro.py` is the looped LM of the Ouro family
 one re-materialised loop (`contrib.foreach`), a head and an exit gate at
 every step under the entropy-regularised exit objective
 (`LoopExitOutput`); the same attention mixer without q/k norms, `lfm2.py`'s
-dense SwiGLU; training path only.
+dense SwiGLU; training path only.  `kanana2.py` is the multi-head latent
+attention LM of the Kanana-2 family, DeepSeek-V3's architecture
+(`Kanana2LM`, `kanana2_symbol`): keys and values through a normed latent,
+one rotary key shared by every head, 192-wide scores over 128-wide values,
+a dense layer in front of routed ones behind a sigmoid router with a
+selection bias and a scale, ungated shared experts, an untied head; the
+routed layer of `qwen3_next.py`, `lfm2.py`'s dense SwiGLU; training path
+only.
 """
 from .model import (LMConfig, TransformerBlock, TransformerLM, lm_symbol,
                     lm_block_op_count)
@@ -34,6 +41,8 @@ from .qwen3_next import (Qwen3NextConfig, Qwen3NextLM, Qwen3NextBlock,
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeLM, Lfm2MoeBlock, lfm2_moe_symbol)
 from .sdar import SdarMoeConfig, SdarMoeLM, SdarMoeBlock, sdar_moe_symbol
 from .ouro import OuroConfig, OuroLM, OuroBlock, ouro_symbol
+from .kanana2 import (Kanana2Config, Kanana2LM, Kanana2Block,
+                      MultiHeadLatentAttention, kanana2_symbol)
 from .decode_core import (DecodePrograms, stack_lm_params, init_kv_cache)
 
 __all__ = ["LMConfig", "TransformerBlock", "TransformerLM", "lm_symbol",
@@ -41,5 +50,7 @@ __all__ = ["LMConfig", "TransformerBlock", "TransformerLM", "lm_symbol",
            "Qwen3NextBlock", "qwen3_next_symbol", "Lfm2MoeConfig", "Lfm2MoeLM",
            "Lfm2MoeBlock", "lfm2_moe_symbol", "SdarMoeConfig", "SdarMoeLM",
            "SdarMoeBlock", "sdar_moe_symbol", "OuroConfig", "OuroLM",
-           "OuroBlock", "ouro_symbol", "DecodePrograms",
+           "OuroBlock", "ouro_symbol", "Kanana2Config", "Kanana2LM",
+           "Kanana2Block", "MultiHeadLatentAttention", "kanana2_symbol",
+           "DecodePrograms",
            "stack_lm_params", "init_kv_cache"]

@@ -15,8 +15,8 @@ a dense SwiGLU in the first `num_dense_layers` layers and `SparseMoE` of
 family's router: sigmoid scores, a selection bias that moves the choice
 alone (an auxiliary state: held still here, since the balancing rule that
 moves it between steps is the training recipe's), weights over the chosen
-scores' sum plus 1e-6 (the published scale of the weights is 1: another
-value is refused).
+scores' sum plus 1e-6, times `routed_scaling_factor` (`RoutedExperts`'
+`scale`; the family publishes 1).
 
 It is not one repeated block: `analysis/graph_passes.scan_plan` folds the
 run of conv + routed layers between two attention layers into one scanned,
@@ -78,11 +78,6 @@ class Lfm2MoeConfig:
                 "Lfm2MoeConfig: layer_types must name 'conv' or "
                 "'full_attention' for each of the %d layers; got %s"
                 % (self.num_hidden_layers, list(self.layer_types)))
-        if self.routed_scaling_factor != 1:
-            raise MXNetError(
-                "Lfm2MoeConfig: routed_scaling_factor is %r; the family "
-                "publishes 1 and `RoutedExperts` has no scale on its "
-                "weights" % (self.routed_scaling_factor,))
 
     @classmethod
     def from_dict(cls, d):
@@ -107,6 +102,7 @@ class Lfm2MoeConfig:
         """`RoutedExperts`' router parameters."""
         return {"scoring": "sigmoid", "norm_eps": ROUTER_EPS,
                 "select_bias": bool(self.use_expert_bias),
+                "scale": float(self.routed_scaling_factor),
                 "capacity_factor": float(self.capacity_factor)}
 
 

@@ -225,17 +225,21 @@ class GatedAttentionMixer(HybridBlock):
 
 class SparseMoE(HybridBlock):
     """The experts this chip holds of a routed layer, plus (with `shared`)
-    the shared expert every chip computes, behind its scalar sigmoid gate.
-    `router`: `RoutedExperts`' router parameters beyond the softmax
-    router's (`scoring`, `select_bias`, `norm_eps`, `capacity_factor`);
-    with `select_bias` the layer holds the selection bias as an auxiliary
+    the shared expert every chip computes, behind its scalar sigmoid gate
+    (`shared_gate`; without it the shared expert's output is added as it
+    is, as DeepSeek-V3's shared experts are).  `router`: `RoutedExperts`'
+    router parameters beyond the softmax router's (`scoring`,
+    `select_bias`, `norm_eps`, `scale`, `capacity_factor`); with
+    `select_bias` the layer holds the selection bias as an auxiliary
     state, which no gradient and no optimizer reaches."""
 
-    def __init__(self, cfg, shared=True, router=None, **kwargs):
+    def __init__(self, cfg, shared=True, router=None, shared_gate=True,
+                 **kwargs):
         super().__init__(**kwargs)
         c, dt = cfg.hidden_size, cfg.param_dtype
         inter, held = cfg.moe_intermediate_size, cfg.experts_held
         self._cfg, self._shared = cfg, shared
+        self._shared_gate = shared and shared_gate
         self._router = dict(router or {})
         with self.name_scope():
             def get(name, shape, **kw):
@@ -261,7 +265,9 @@ class SparseMoE(HybridBlock):
                 self.shared_gate = _dense(width, c, dt, "shared_gate_")
                 self.shared_up = _dense(width, c, dt, "shared_up_")
                 self.shared_down = _dense(c, width, dt, "shared_down_")
-                self.shared_sigmoid = _dense(1, c, dt, "shared_sigmoid_")
+                if self._shared_gate:
+                    self.shared_sigmoid = _dense(1, c, dt,
+                                                 "shared_sigmoid_")
 
     def hybrid_forward(self, F, x, router_weight, experts_gate_weight,
                        experts_up_weight, experts_down_weight, load,
@@ -279,6 +285,8 @@ class SparseMoE(HybridBlock):
         shared = self.shared_down(
             F.Activation(self.shared_gate(x), act_type="silu") *
             self.shared_up(x))
+        if not self._shared_gate:
+            return routed + shared
         return routed + F.broadcast_mul(
             F.Activation(self.shared_sigmoid(x), act_type="sigmoid"), shared)
 

@@ -28,7 +28,11 @@ What runs where:
   The choice is made from the platform and the shapes alone (`_driver`;
   ``MXNET_FLASH_INTERPRET`` runs the kernels interpreted, for the CPU
   tests), and which one a traced call took is counted:
-  `ops.attention.lowered.kernel` / `.xla`.
+  `ops.attention.lowered.kernel` / `.xla`.  A head size that is not whole
+  lane tiles is padded to them in VMEM (HBM holds it dense):
+  `ops.attention.padded_lanes` adds, a lowered kernel call, the lanes
+  added to a head of q and k and to a head of v (64 for DeepSeek-V3's
+  192-wide scores over 128-wide values, 128 for heads of 64).
 
 The mask is a parameter: ``causal`` (what it was), or
 ``mask="block_diffusion"`` with ``block_length`` over a ``time`` of 2L rows,
@@ -232,10 +236,13 @@ def _count_tiles(heads, *args):
         obs.counter("ops.attention.tiles." + name).inc(heads * n)
 
 
-def _count(driver):
+def _count(driver, d, dv):
     from .. import obs
     obs.counter("ops.attention.lowered." +
                 ("xla" if driver == "xla" else "kernel")).inc()
+    if driver != "xla":
+        obs.counter("ops.attention.padded_lanes").inc(-d % LANES +
+                                                       -dv % LANES)
 
 
 def _kernel_layout(q, k, v, *like_q):
@@ -345,7 +352,7 @@ def grouped_query_attention(q, k, v, mask=CAUSAL, block_size=None):
     r = q.shape[2] // k.shape[2]
     driver = _driver(r, q.shape[1], k.shape[1], q.shape[3], v.shape[3],
                      q.dtype.itemsize, _period(mask, q.shape[1], k.shape[1]))
-    _count(driver)
+    _count(driver, q.shape[3], v.shape[3])
     if driver == "xla":
         return _xla_blocks(q, k, v, mask, block_size)
     return _flash(q, k, v, mask, driver == "interpret")

@@ -6,7 +6,8 @@ It routes over ALL `num_experts`, in float32.  The router (`Router`) scores
 with a softmax or a sigmoid, CHOOSES the `top_k` largest of score + bias
 where a selection bias is given (an input that takes no gradient), WEIGHTS
 by the score without the bias, renormalises over the chosen ones with
-`norm_topk` (their sum plus `eps`).  The layer computes
+`norm_topk` (their sum plus `eps`) and multiplies by `scale`.  The layer
+computes
 
     sum over the chosen experts e that are held here of  w_e * E_e(x),
     E(x) = W_down (SiLU(W_gate x) * W_up x)
@@ -77,6 +78,7 @@ class Router(NamedTuple):
     scoring: str = "softmax"    # or "sigmoid"
     norm_topk: bool = True      # weights over the sum of the chosen scores
     eps: float = 0.0            # added to that sum
+    scale: float = 1.0          # multiplies the weights (1: no product)
 
 
 def _chosen(per_expert, top_e):
@@ -111,6 +113,8 @@ def _routing(x2, router_weight, bias, top_k, router):
     weights = top_s
     if router.norm_topk:
         weights = top_s / _chosen_total(top_s, router)
+    if router.scale != 1:
+        weights = weights * F32(router.scale)
     return scores, top_s, weights, experts
 
 
@@ -118,6 +122,8 @@ def _routing_bwd(x2, router_weight, scores, top_s, top_e, router, dw):
     """The transpose of `_routing` at weights = dw: (dx2, drouter_weight);
     the bias moves no weight, so it gets nothing.  `top_k` is transposed
     with compares against `top_e`, not a scatter."""
+    if router.scale != 1:
+        dw = dw * F32(router.scale)
     if router.norm_topk:
         total = _chosen_total(top_s, router)
         dw = (dw - jnp.sum(dw * top_s, axis=-1, keepdims=True) / total) / \
@@ -796,10 +802,12 @@ def _apply_fn(cap, rows, block, top_k, offset, router, driver, tiles=None):
 
 def routed_experts(x, router_weight, gate, up, down, num_experts, top_k,
                    offset, norm_topk=True, interpret=False, bias=None,
-                   scoring="softmax", norm_eps=0.0, capacity_factor=2.0):
+                   scoring="softmax", norm_eps=0.0, capacity_factor=2.0,
+                   scale=1.0):
     """(partial output of x's shape and type, assignments per expert held
     (count,) int32, assignments left without a row, scalar int32).  `bias`
-    (num_experts,): the selection bias, which takes no gradient.
+    (num_experts,): the selection bias, which takes no gradient.  `scale`
+    multiplies the routing weights.
     `capacity_factor`: the rows of the grouped form, in mean loads.
     `interpret` is for tests: the kernel, interpreted, on any backend."""
     if scoring not in ("softmax", "sigmoid"):
@@ -817,7 +825,8 @@ def routed_experts(x, router_weight, gate, up, down, num_experts, top_k,
     tiles = None if driver == "xla" else _tiles(*shapes, x2.dtype.itemsize)
     _count(driver)
     apply = _apply_fn(cap, rows, block, int(top_k), int(offset),
-                      Router(scoring, bool(norm_topk), float(norm_eps)),
+                      Router(scoring, bool(norm_topk), float(norm_eps),
+                             float(scale)),
                       driver, tiles)
     out, counts, dropped = apply(
         x2, router_weight, gate, up, down, *(() if bias is None else (bias,)))
@@ -871,7 +880,7 @@ def _input_names(params):
                   "experts_offset": 0, "experts_count": REQUIRED,
                   "norm_topk": True, "scoring": "softmax",
                   "select_bias": False, "norm_eps": 0.0,
-                  "capacity_factor": 2.0},
+                  "capacity_factor": 2.0, "scale": 1.0},
           input_names=_input_names,
           cost_meta={"flops": _experts_flops}, scan_remat=True,
           counters=_load_counters)
@@ -880,7 +889,8 @@ def _routed_experts(params, x, router_weight, gate, up, down, *states):
     data (..., C); router_weight (num_experts, C); gate_weight and
     up_weight (experts_count, I, C); down_weight (experts_count, C, I).
     The router: `scoring` "softmax" or "sigmoid", `norm_topk` with
-    `norm_eps` added to the chosen scores' sum; with `select_bias` a
+    `norm_eps` added to the chosen scores' sum, the weights times `scale`
+    (DeepSeek-V3's `routed_scaling_factor`); with `select_bias` a
     further input (num_experts,) is added to the scores for the CHOICE alone.  It is an auxiliary state: no gradient
     reaches it and the step hands it back as it was.  Auxiliary states
     added to in every training step: `load` (experts_count,), and `dropped`
@@ -919,7 +929,8 @@ def _routed_experts(params, x, router_weight, gate, up, down, *states):
         x, router_weight, gate, up, down, num, k, offset,
         bool(params["norm_topk"]), bias=bias,
         scoring=str(params["scoring"]), norm_eps=float(params["norm_eps"]),
-        capacity_factor=float(params["capacity_factor"]))
+        capacity_factor=float(params["capacity_factor"]),
+        scale=float(params["scale"]))
     if not params.get("_train", False):
         return out
     tokens = x.size // x.shape[-1]

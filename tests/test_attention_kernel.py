@@ -30,13 +30,16 @@ SMALL = {
 }
 # the causal cells' shapes: (q, k, v), and the temporaries XLA may keep
 # beside the kernels (the transposes to and from the kernels' layout);
-# `ouro_2_6b` runs one query head a key-value head
+# `ouro_2_6b` runs one query head a key-value head, `kanana2_30b_a3b` too,
+# with scores 192 deep over values 128 wide
 CELLS = {
     "lfm2_24b_a2b": ((2, 8192, 32, 64), (2, 8192, 8, 64), (2, 8192, 8, 64)),
     "qwen3_next_80b_a3b": ((2, 4096, 16, 256), (2, 4096, 2, 256),
                            (2, 4096, 2, 256)),
     "ouro_2_6b": ((2, 4096, 16, 128), (2, 4096, 16, 128),
                   (2, 4096, 16, 128)),
+    "kanana2_30b_a3b": ((2, 8192, 32, 192), (2, 8192, 32, 192),
+                        (2, 8192, 32, 128)),
 }
 
 
@@ -155,6 +158,9 @@ def test_tiles_follow_the_shapes():
     assert attention._tiles(4, 8192, 8192, 64, 64, 2) == (256, 1024)
     assert attention._tiles(8, 4096, 4096, 256, 256, 2) == (128, 1024)
     assert attention._tiles(1, 384, 640, 128, 128, 4) == (128, 128)
+    # scores 192 deep (padded to 256 lanes in VMEM) over values of 128:
+    # 59.5 MiB a backward grid step of the 64 the kernels ask for
+    assert attention._tiles(1, 8192, 8192, 192, 128, 2) == (1024, 1024)
     assert attention._tiles(4, 8192, 131072, 128, 128, 2) is None
 
 
@@ -209,7 +215,11 @@ def test_kernels_compile_for_the_v5e(one_chip, uncached, config, calls):
     `grouped_query_attention` runs on a TPU (here `default_backend()` is
     the CPU, so the custom VJP is named): no block of scores in HBM, so no
     `reduce-window` over one and under 0.5 GB of temporaries (XLA's form:
-    2.15 and 1.83 GB at `lfm2_24b_a2b`'s shape)."""
+    2.15 and 1.83 GB at `lfm2_24b_a2b`'s shape).  `kanana2_30b_a3b`'s
+    operands are three times as large: their copies in the kernels' layout
+    alone are 0.68 GB forward and 1.61 GB with the gradients, and XLA's
+    form would add at least one block of float32 scores (512 queries x
+    8,192 keys x 32 heads x 2 sequences, 1.07 GB)."""
     args = [jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
             for dims in CELLS[config]]
 
@@ -223,7 +233,9 @@ def test_kernels_compile_for_the_v5e(one_chip, uncached, config, calls):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == calls
     assert "reduce-window" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    bound = {("kanana2_30b_a3b", 1): 1.0e9,
+             ("kanana2_30b_a3b", 2): 2.0e9}.get((config, calls), 0.5e9)
+    assert compiled.memory_analysis().temp_size_in_bytes < bound
 
 
 @pytest.mark.parametrize("stream", [False, True])

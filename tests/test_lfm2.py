@@ -535,10 +535,10 @@ def test_config_from_the_published_keys():
     assert cfg.experts_held == ExpertShare(64, 0, 8)
     assert cfg.num_experts == 64 and cfg.num_dense_layers == 1
     assert cfg.router() == {"scoring": "sigmoid", "norm_eps": 1e-6,
-                            "select_bias": True, "capacity_factor": 4.0}
-    # the published scale of the weights is 1: no other value has a path
-    with pytest.raises(mx.MXNetError, match="routed_scaling_factor"):
-        Lfm2MoeConfig(routed_scaling_factor=2.5)
+                            "select_bias": True, "scale": 1.0,
+                            "capacity_factor": 4.0}
+    # the published scale of the weights is 1; another reaches the operator
+    assert Lfm2MoeConfig(routed_scaling_factor=2.5).router()["scale"] == 2.5
     with pytest.raises(mx.MXNetError, match="layer_types"):
         Lfm2MoeConfig(num_hidden_layers=2)
     with pytest.raises(mx.MXNetError, match="layer_types"):
