@@ -203,7 +203,8 @@ class GatedAttentionMixer(HybridBlock):
         h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         rope = {"rotary_dim": int(d * cfg.partial_rotary_factor),
-                "base": cfg.rope_theta, **self._copies}
+                "base": cfg.rope_theta, **self._copies,
+                **({"after_norm": True} if self._qk_norm else {})}
         q = qg = self.q_proj(x)
         if self._gate:
             q = F.slice_axis(qg, axis=-1, begin=0, end=h * d)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .registry import register, REQUIRED
+from .rotary import rotary
 from ..base import MXNetError
 
 
@@ -50,30 +51,9 @@ def _rms_norm(params, x, gamma, *rest):
                     rest[0] if params.get("gated") else None)
 
 
-def rotary(x, rotary_dim, base, copies=1):
-    """x (B, T, H, D): positions 0..T-1 rotate the first `rotary_dim`
-    entries of every head, entry i paired with entry i + rotary_dim / 2.
-    `copies`: the time axis holds that many copies of one sequence, each at
-    positions 0..T/copies - 1 (the positions' period is T / copies)."""
-    t, half = x.shape[1], rotary_dim // 2
-    inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32)
-                               * 2.0 / rotary_dim))
-    position = jnp.arange(t, dtype=jnp.float32)
-    if copies != 1:
-        position = jnp.tile(position[:t // copies], copies)
-    angle = position[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :,
-                                                                None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2, rest = xf[..., :half], xf[..., half:rotary_dim], \
-        xf[..., rotary_dim:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                          axis=-1)
-    return out.astype(x.dtype)
-
-
 @register("RotaryEmbedding",
-          params={"rotary_dim": REQUIRED, "base": 10000.0, "copies": 1})
+          params={"rotary_dim": REQUIRED, "base": 10000.0, "copies": 1,
+                  "after_norm": False})
 def _rotary_embedding(params, x):
     """Rotary position embedding on (batch, time, heads, head size) data:
     the first `rotary_dim` entries of each head turn with the position
@@ -81,7 +61,15 @@ def _rotary_embedding(params, x):
     `copies` (1 unless told) gives the positions' period as a share of the
     time axis: time holds that many copies of one sequence, each at
     positions 0..time/copies - 1, as block-diffusion training's [noisy |
-    clean] rows do; given so, the symbol binds at any length."""
+    clean] rows do; given so, the symbol binds at any length.  On a TPU,
+    where a row of heads is whole lane tiles, one Pallas pass each way
+    (ops/rotary.py), the backward pass the rotation by -theta; XLA's form
+    elsewhere, under the same custom VJP.  `after_norm`: the data is a
+    per-head normalisation's output (q / k norms), which XLA fuses with its
+    own form of the rotation both ways; a kernel between the two costs the
+    norm's backward a relayout of the data in float32, more than the
+    kernel saves (PERF.md section 6), so XLA's form, differentiated by
+    JAX, runs there."""
     rd, copies = int(params["rotary_dim"]), int(params.get("copies") or 1)
     if x.ndim != 4 or rd % 2 or rd > x.shape[-1] or copies < 1 or \
             x.shape[1] % copies:
@@ -89,7 +77,8 @@ def _rotary_embedding(params, x):
             "RotaryEmbedding: data must be (batch, time, heads, head size), "
             "rotary_dim even and at most the head size and time a multiple "
             "of copies; got %s, %d and %d" % (tuple(x.shape), rd, copies))
-    return rotary(x, rd, float(params["base"]), copies)
+    return rotary(x, rd, float(params["base"]), copies,
+                  bool(params.get("after_norm")))
 
 
 def _causal_conv(xf, weight):

@@ -124,3 +124,42 @@ def test_gluon_block_on_tpu():
         assert out.context.device_type in ("tpu", "gpu")
     finally:
         set_default_context(mx.cpu())
+
+
+@pytest.mark.parametrize("shape,rd,copies", [
+    ((2, 8192, 32, 192), 64, 1),        # kanana2_30b_a3b's q: pe first
+    ((2, 4096, 16, 128), 128, 1),       # ouro_2_6b
+    ((2, 8192, 4, 128), 128, 2),        # sdar_30b_a3b_chat's key, copies 2
+    ((2, 4096, 2, 256), 64, 1),         # qwen3_next_80b_a3b's key
+    ((2, 8192, 32, 64), 64, 1),         # lfm2_24b_a2b: two heads a window
+])
+def test_rotary_kernel_lowered(shape, rd, copies):
+    """`RotaryEmbedding`'s Pallas pass (ops/rotary.py) lowered on the chip
+    at the cells' shapes in bfloat16, forward and backward, against XLA's
+    form and its `jax.vjp` on the same chip: both round the same float32
+    products and sums once, so an entry may round to its neighbour (one
+    rounding of the largest entry, 2^-8); the lanes past `rd` are equal."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import obs
+    from incubator_mxnet_tpu.ops import registry, rotary
+    op = registry.get("RotaryEmbedding")
+    params = op.canonicalize_params({"rotary_dim": rd, "base": 1e6,
+                                     "copies": copies})
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    x, g = (jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
+            for k in ks)
+    before = obs.counter("ops.rotary.lowered.kernel").value
+
+    def step(fn):
+        def value_and_grad(a, c):
+            y, vjp = jax.vjp(fn, a)
+            return y, vjp(c)[0]
+        return jax.jit(value_and_grad)(x, g)
+    got = step(lambda a: op.fn(dict(params), a))
+    assert obs.counter("ops.rotary.lowered.kernel").value == before + 1
+    want = step(lambda a: rotary.xla_form(a, rd, 1e6, copies))
+    for a, b in zip(got, want):
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        assert np.abs(a - b).max() <= 2.0 ** -8 * np.abs(b).max()
+        np.testing.assert_array_equal(a[..., rd:], b[..., rd:])
