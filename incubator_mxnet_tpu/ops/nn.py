@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .registry import register, REQUIRED
+from .registry import register, REQUIRED, scan_kept
 from ..base import MXNetError
 
 
@@ -226,7 +226,8 @@ def _bn_axis_bound(name):
                   "axis": 1, "cudnn_off": False, "sync": False,
                   "sync_axis": "dp"},
           aliases=("BatchNorm_v1",),
-          input_names=["data", "gamma", "beta", "moving_mean", "moving_var"])
+          input_names=["data", "gamma", "beta", "moving_mean", "moving_var"],
+          scan_remat=True)
 def _batch_norm(params, x, gamma, beta, moving_mean, moving_var):
     """Reference `src/operator/nn/batch_norm.cc`.  Aux states
     (moving_mean/var) are inputs 4-5 and returned as updates in train mode.
@@ -254,22 +255,28 @@ def _batch_norm(params, x, gamma, beta, moving_mean, moving_var):
     bshape = [1] * x.ndim
     bshape[axis] = x.shape[axis]
 
+    # a re-materialised scanned layer (`OpDef.scan_remat`) keeps the input
+    # as it arrives and, in training, the mean and the variance (rsqrt's
+    # derivative reads its operand): its backward pass computes `x - mean`,
+    # the output and what follows from them again, elementwise, and no
+    # reduction runs twice
+    x = scan_kept(x)
     # statistics in float32 even for low-precision activations (matches the
     # reference's cuDNN path which accumulates in fp32)
     xs = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
     if train:
         mean = jnp.mean(xs, axis=red_axes)
-        if sync and _bn_axis_bound(sync_axis):
-            # distributed BN: psum of moments over the dp axis — with
-            # equal per-device batches, pmean of local moments around
-            # the GLOBAL mean is exactly the big-batch statistics
+        # distributed BN: psum of moments over the dp axis — with equal
+        # per-device batches, pmean of local moments around the GLOBAL
+        # mean is exactly the big-batch statistics
+        synced = sync and _bn_axis_bound(sync_axis)
+        if synced:
             mean = jax.lax.pmean(mean, sync_axis)
-            var = jnp.mean(jnp.square(xs - mean.reshape(bshape)),
-                           axis=red_axes)
+        mean = scan_kept(mean)
+        var = jnp.mean(jnp.square(xs - mean.reshape(bshape)), axis=red_axes)
+        if synced:
             var = jax.lax.pmean(var, sync_axis)
-        else:
-            var = jnp.mean(jnp.square(xs - mean.reshape(bshape)),
-                           axis=red_axes)
+        var = scan_kept(var)
     else:
         mean, var = moving_mean, moving_var
 

@@ -44,8 +44,8 @@ REQUIRED = _Required()
 
 _REGISTRY: dict[str, "OpDef"] = {}
 
-# The one name under which an operator's custom-VJP forward rule declares
-# what a re-materialised scanned layer keeps for it (`OpDef.scan_remat`).
+# The one name under which an operator's forward rule declares what a
+# re-materialised scanned layer keeps for it (`OpDef.scan_remat`).
 SCAN_KEPT = "scan_kept"
 
 
@@ -55,7 +55,9 @@ def scan_kept(x):
     `jax.checkpoint` saves the values of this name and nothing else).  For
     a custom-VJP forward rule to pass its kernel's OUTPUTS through, where
     the written backward pass reads them: the kernel then runs once a
-    layer, not twice.  An identity anywhere else (an inlined layer, a
+    layer, not twice; for a plain forward, the values whose recomputation
+    would cost a reduction or an earlier operator (BatchNorm's input and
+    statistics).  An identity anywhere else (an inlined layer, a
     forward-only program: the name lowers to nothing)."""
     from jax.ad_checkpoint import checkpoint_name
     return checkpoint_name(x, SCAN_KEPT)
@@ -136,7 +138,9 @@ class OpDef:
         # layer's stacked activations have room for (a chunked recurrence,
         # whose written backward pass keeps a float32 state per chunk;
         # routed experts, whose written backward pass keeps the router's
-        # float32 probabilities, the plan and the rows' pre-activations),
+        # float32 probabilities, the plan and the rows' pre-activations;
+        # BatchNorm, whose linearisation keeps three float32 copies of its
+        # input, 12 bytes for each element of a bfloat16 one),
         # so a scanned layer holding it recomputes its activations in the
         # backward pass instead of stacking them (`symbol.graph_eval_fn`),
         # all but the values that an operator of the layer names

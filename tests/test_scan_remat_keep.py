@@ -15,6 +15,12 @@ program, not twice.  Held here, on the CPU, at sizes that tile:
 * a body whose operators name nothing, and a forward-only bind, lower to
   the text they lowered to before;
 * `scan.remat.kept` and `scan.remat.kept_bytes` read what the shapes give.
+
+`BatchNorm` is `scan_remat` too, for its float32 statistics: where the
+linearised body stacks three float32 copies of every normalised activation,
+the re-materialised ResNet body keeps each BatchNorm's input in the type it
+arrives in and its per-channel mean and variance, and no convolution runs
+twice (the ResNet cases below).
 """
 import re
 
@@ -23,12 +29,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from incubator_mxnet_tpu import obs
+from jax._src.ad_checkpoint import remat_p, saved_residuals
+
+from incubator_mxnet_tpu import obs, sym
 from incubator_mxnet_tpu.analysis.graph_passes import scan_plan
+from incubator_mxnet_tpu.gluon.model_zoo.vision.resnet import (
+    BottleneckV1, ResNetV1)
 from incubator_mxnet_tpu.llm import Qwen3NextConfig, qwen3_next_symbol
 from incubator_mxnet_tpu.llm.lfm2 import Lfm2MoeConfig, lfm2_moe_symbol
 from incubator_mxnet_tpu.llm.sdar import SdarMoeConfig, sdar_moe_symbol
-from incubator_mxnet_tpu.ops import attention, delta_rule
+from incubator_mxnet_tpu.ops import attention, delta_rule, registry
+from incubator_mxnet_tpu.ops import nn as nn_ops
 from incubator_mxnet_tpu.symbol import symbol as symbol_mod
 
 BATCH = 2
@@ -261,3 +272,219 @@ def test_forward_only_bind_lowers_as_it_did(graph, monkeypatch):
     monkeypatch.setattr(delta_rule, "scan_kept", lambda x: x)
     _bare(monkeypatch)
     assert texts() == kept
+
+
+# -- ResNet: BatchNorm keeps its input and its statistics ---------------------
+
+IMAGE, IMAGES = 32, 6     # a batch that is no width of the graph's
+
+
+def _resnet_graph():
+    """The model zoo's ResNet-50 v1 composition at widths the CPU holds: two
+    stages of three bottleneck blocks, whose last two form a scanned run
+    each, `Activation -> (Convolution -> BatchNorm -> Activation) x 2 ->
+    Convolution -> BatchNorm -> broadcast_add`."""
+    net = ResNetV1(BottleneckV1, [3, 3], [8, 16, 32], classes=10)
+    return sym.SoftmaxOutput(net(sym.Variable("data")), name="softmax")
+
+
+def _image_inputs(symbol, dtype=jnp.bfloat16, seed=0):
+    """Images and parameters in `dtype`, float32 labels and statistics: the
+    cell's types, as the default has it."""
+    shapes = {"data": (IMAGES, 3, IMAGE, IMAGE), "softmax_label": (IMAGES,)}
+    arg_shapes, _, aux_shapes = symbol.infer_shape(**shapes)
+    rng = np.random.default_rng(seed)
+    args = []
+    for name, shape in zip(symbol.list_arguments(), arg_shapes):
+        if name == "softmax_label":
+            args.append(jnp.asarray(rng.integers(0, 10, shape), jnp.float32))
+        elif name.endswith("_gamma"):
+            args.append(jnp.ones(shape, dtype))
+        else:
+            args.append(jnp.asarray(0.3 * rng.standard_normal(shape), dtype))
+    aux = [jnp.full(shape, float(name.endswith("_var")), jnp.float32)
+           for name, shape in zip(symbol.list_auxiliary_states(), aux_shapes)]
+    return tuple(args), tuple(aux), jax.random.PRNGKey(seed)
+
+
+@pytest.fixture
+def resnet_case():
+    symbol = _resnet_graph()
+    runs = scan_plan(symbol)["runs"]
+    assert [r["length"] for r in runs] == [2, 2]
+    for run in runs:
+        kinds = [n.op.name for n in run["segments"][0]]
+        assert kinds.count("BatchNorm") == 3
+        assert kinds.count("Convolution") == 3
+    return symbol, _image_inputs(symbol)
+
+
+def _bare_batch_norm(monkeypatch):
+    """The executor as it was for BatchNorm: its scanned layers not
+    re-materialised, the bare `lax.scan`."""
+    monkeypatch.setattr(registry.get("BatchNorm"), "scan_remat", False)
+
+
+def _scan_bodies(jaxpr):
+    """The bodies of every `scan` in `jaxpr`, at any depth."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn.params["jaxpr"].jaxpr
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scan_bodies(sub)
+
+
+def test_resnet_body_is_under_the_checkpoint(resnet_case, monkeypatch):
+    """(a) every scanned body of the training program is a checkpoint, and
+    none of the bare scan's is."""
+    symbol, (args, aux, key) = resnet_case
+    fn = _step(symbol, grad=False)
+
+    def checkpointed():
+        jaxpr = jax.make_jaxpr(fn)(args, aux, key).jaxpr
+        return [any(e.primitive is remat_p for e in body.eqns)
+                for body in _scan_bodies(jaxpr)]
+    assert checkpointed() == [True, True]
+    _bare_batch_norm(monkeypatch)
+    fn = _step(symbol, grad=False)
+    assert checkpointed() == [False, False]
+
+
+def test_resnet_runs_no_convolution_twice(resnet_case, monkeypatch):
+    """(b) the gradient program holds as many convolutions as the bare
+    scan's: what the backward pass computes again starts from each
+    BatchNorm's kept input, not from the convolution that made it."""
+    symbol, inputs = resnet_case
+    text = jax.jit(_step(symbol)).lower(*inputs).as_text()
+    _bare_batch_norm(monkeypatch)
+    bare = jax.jit(_step(symbol)).lower(*inputs).as_text()
+    assert text.count("stablehlo.convolution") == \
+        bare.count("stablehlo.convolution") > 0
+
+
+def _stacked(symbol, inputs):
+    """The scans' saved residuals of the training program: (dtype, shape)."""
+    args, aux, key = inputs
+    fn = _step(symbol, grad=False)
+
+    def loss(a):
+        return fn(a, aux, key)[0][0].astype(jnp.float32).sum()
+    return [(a.dtype, a.shape) for a, why in saved_residuals(loss, args)
+            if "scan" in why]
+
+
+def test_resnet_stacks_no_float32_activation(resnet_case, monkeypatch):
+    """(c) the bare scan stacks float32 arrays of an activation's size (a
+    layer axis in front of N, C, H, W) for every BatchNorm; the
+    re-materialised body stacks bfloat16 activations and float32 vectors
+    of C alone."""
+    symbol, inputs = resnet_case
+
+    def activations(stacked, dtype):
+        return sum(dt == dtype and len(shape) == 5 and shape[1] == IMAGES
+                   for dt, shape in stacked)
+    kept = _stacked(symbol, inputs)
+    # a layer's three BatchNorm inputs and its carry, in each run
+    assert activations(kept, jnp.bfloat16) == 2 * 4
+    assert activations(kept, jnp.float32) == 0
+    assert {len(shape) for dt, shape in kept if dt == jnp.float32} == {2}
+    _bare_batch_norm(monkeypatch)
+    bare = _stacked(symbol, inputs)
+    assert activations(bare, jnp.float32) == 2 * 3 * 3
+
+
+def test_resnet_matches_the_bare_scan(monkeypatch):
+    """(d) outputs, running statistics and gradients of the bare scan, to
+    float rounding: the same operations in the same types, only what is
+    stored and what is computed again differs.  In float32: in bfloat16 the
+    compiler's excess precision (a convolution's float32 result read by
+    BatchNorm without the round trip through bfloat16, where nothing stores
+    it) moves an output by an ulp, and the gradients of the biases in front
+    of a BatchNorm, zero but for rounding, by their whole size.  Each leaf
+    is held to the rounding of the largest of its kind: those biases' are
+    noise around zero in both programs."""
+    symbol = _resnet_graph()
+    inputs = _image_inputs(symbol, jnp.float32)
+    result = jax.jit(_step(symbol))(*inputs)
+    _bare_batch_norm(monkeypatch)
+    bare = jax.jit(_step(symbol))(*inputs)
+    for got, want in zip(result, bare):
+        got, want = (np.asarray(x, np.float64) for x in got), \
+            [np.asarray(x, np.float64) for x in want]
+        scale = max(np.abs(w).max() for w in want)
+        for g, w in zip(got, want):
+            assert np.abs(g - w).max() <= 2e-5 * scale
+    grads = jax.tree_util.tree_leaves(result[2])
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+    assert sum(float(jnp.abs(g).sum()) > 0 for g in grads) > len(grads) // 2
+
+
+def _bn_bytes(layers, batch, size, mid, itemsize):
+    """A run of bottleneck layers of width `mid` at size x size: each layer's
+    three BatchNorm inputs (mid, mid and 4 mid channels) in the activations'
+    type, and their float32 mean and variance."""
+    return layers * 6 * mid * (batch * size * size * itemsize + 2 * 4)
+
+
+def test_resnet_counters_read_what_the_shapes_give(resnet_case):
+    """(e) nine named values a layer (three BatchNorms' input, mean and
+    variance), their bytes over each run; the arithmetic held to the
+    counters at the test's size and read at the cell's."""
+    symbol, inputs = resnet_case
+    # stage 1: width 4 at 8 x 8 (the stem halves 32 twice); stage 2: width
+    # 8 at 4 x 4
+    size = IMAGE // 4
+    want = _bn_bytes(2, IMAGES, size, 4, 2) + \
+        _bn_bytes(2, IMAGES, size // 2, 8, 2)
+    assert _lowered(symbol, inputs)[1] == (2 * 9, want)
+    assert _lowered(symbol, inputs, train=False, grad=False)[1] == (0, 0)
+    # resnet50_train_hostfed: batch 128 at 224 x 224, runs of 2, 3, 5 and 2
+    # layers of width 64, 128, 256 and 512 at 56, 28, 14 and 7, bfloat16
+    cell = sum(_bn_bytes(n, 128, s, mid, 2) for n, s, mid in
+               [(2, 56, 64), (3, 28, 128), (5, 14, 256), (2, 7, 512)])
+    assert cell == 1541541888
+    assert sum(_bn_bytes(n, 0, 1, mid, 2) for n, mid in
+               [(2, 64), (3, 128), (5, 256), (2, 512)]) == 135168
+
+
+def _numbered(text):
+    """`text` with the lowering's private functions unnumbered: the suffixes
+    that keep a module's symbols apart count the equations traced before,
+    the named values' among them."""
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+
+@pytest.mark.parametrize(
+    "how", [dict(train=True, grad=False), dict(scan=False)],
+    ids=["forward-only", "inlined"])
+def test_resnet_lowers_as_it_did(how, monkeypatch):
+    """(f) a forward-only training bind, and the inlined graph's gradient
+    program, lower to the text they lowered to before BatchNorm named
+    anything, but for the numbering of private functions."""
+    symbol = _resnet_graph()
+    inputs = _image_inputs(symbol)
+    text = _lowered(symbol, inputs, **how)[0]
+    monkeypatch.setattr(nn_ops, "scan_kept", lambda x: x)
+    _bare_batch_norm(monkeypatch)
+    bare = _lowered(symbol, inputs, **how)[0]
+    assert text != bare
+    assert _numbered(text) == _numbered(bare)
+
+
+def test_resnet_predict_bind_computes_as_it_did(monkeypatch):
+    """(f) a predict bind: the same convolutions and reductions, and the
+    same outputs to the last bit.  Its text differs in one thing: the bare
+    scan hands the running statistics, which a predicting BatchNorm returns
+    as it read them, from its inputs to its outputs; through the checkpoint
+    they come out as stacks of their own, (layers, channels) a statistic."""
+    symbol = _resnet_graph()
+    inputs = _image_inputs(symbol)
+    fn = jax.jit(_step(symbol, train=False, grad=False))
+    text, result = fn.lower(*inputs).as_text(), fn(*inputs)
+    _bare_batch_norm(monkeypatch)
+    fn = jax.jit(_step(symbol, train=False, grad=False))
+    bare, bare_result = fn.lower(*inputs).as_text(), fn(*inputs)
+    for op in ("convolution", "reduce(", "while"):
+        op = "stablehlo." + op
+        assert text.count(op) == bare.count(op) > 0
+    _equal(result, bare_result)
